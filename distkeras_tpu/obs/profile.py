@@ -239,6 +239,18 @@ def observe_memory(registry: Optional[Registry] = None) -> dict:
 # step-time split
 # ---------------------------------------------------------------------------
 
+def fence(tree):
+    """THE timing fence: wait until every array in ``tree`` has been
+    computed, and return it.  ``jax.block_until_ready`` is honest on the
+    machine this repo runs on — ``chip_smoke.py``'s fence phase times one
+    matmul chain three ways, and on the v5e (JAX 0.9.0, PR 21) it waited
+    189.5 ms against 189.8 ms for a device->host readback of the result,
+    with 0.3 ms to enqueue — so a timed region that ends here spans the
+    device work, and no readback is needed just to stop a clock."""
+    import jax
+    return jax.block_until_ready(tree)
+
+
 def step_split(fn: Callable, registry=None, prefix: str = "step") -> Callable:
     """Wrap a step/window function with the host/device time split: the
     call itself is host work (trace + dispatch — jit returns at enqueue
@@ -252,15 +264,13 @@ def step_split(fn: Callable, registry=None, prefix: str = "step") -> Callable:
     step_split``), not a default."""
     import time
 
-    import jax
-
     def wrapped(*args):
         reg = registry() if callable(registry) else registry
         reg = reg if reg is not None else default_registry()
         t0 = time.perf_counter()
         out = fn(*args)
         t1 = time.perf_counter()
-        jax.block_until_ready(out)
+        fence(out)
         t2 = time.perf_counter()
         reg.histogram(f"{prefix}.host_seconds", TIME_BUCKETS).observe(t1 - t0)
         reg.histogram(f"{prefix}.device_seconds",

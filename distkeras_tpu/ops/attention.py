@@ -93,42 +93,31 @@ def apply_rope(x, positions, base: float = 10000.0):
                             x2 * cos + x1 * sin], axis=-1)
 
 
-_MIN_FLASH_BLOCK = 32  # below this the kernel grid degenerates (perf cliff)
-
-
-def _largest_divisor_block(t: int, cap: int = 128) -> int:
-    """Largest block size ≤ cap dividing t (flash kernels need whole
-    blocks; T=200 → 100, T=256 → 128, prime T → 1)."""
-    for b in range(min(cap, t), 0, -1):
-        if t % b == 0:
-            return b
-    return 1
-
-
 def _flash_with_blocking(q, k, v, causal: bool, t: int):
-    """Run the Pallas flash kernel with a sane block size.
+    """Run the Pallas flash kernel with a block the TPU can tile.
 
-    Awkward sequence lengths (e.g. prime T) have no block-sized divisor;
-    silently falling back to block=1 is a severe perf cliff on real TPU.
-    For causal attention, end-padding T to a multiple of 128 is exact:
-    padded KEY positions sit strictly after every real query (never
-    attended), and padded QUERY rows are sliced off (their zero cotangent
-    keeps gradients exact too).  Non-causal attention would attend the
-    padded keys, so there we refuse loudly instead of degrading.
+    The kernels take whole blocks that are a multiple of 128 or the
+    whole (short) sequence (``pallas_attention._tileable``); a longer T
+    that no multiple of 128 divides (T=200, T=544, prime T) has no such
+    block, and Mosaic refuses anything else.  For causal
+    attention, end-padding T to a multiple of 128 is exact: padded KEY
+    positions sit strictly after every real query (never attended), and
+    padded QUERY rows are sliced off (their zero cotangent keeps
+    gradients exact too).  Non-causal attention would attend the padded
+    keys, so there we refuse loudly instead.
     """
-    from .pallas_attention import flash_attention
-    blk = _largest_divisor_block(t)
-    if blk >= _MIN_FLASH_BLOCK or t <= _MIN_FLASH_BLOCK:
+    from .pallas_attention import _tileable, flash_attention
+    if _tileable(t):
         # block sizes auto-tune inside the kernel (largest VMEM-fitting
         # divisor of T — the big-block regime is where flash beats dense)
         return flash_attention(q, k, v, causal)
     if not causal:
         raise ValueError(
             f"impl='flash' needs a sequence length with a block-sized "
-            f"divisor; T={t}'s largest block is {blk} (< "
-            f"{_MIN_FLASH_BLOCK}), which would run the kernel grid "
-            f"degenerately slowly.  Pad T to a multiple of 128 (with key "
-            f"masking) or use impl='dense'.")
+            f"divisor; T={t} is neither a multiple of 128 nor at most "
+            f"128 (one block), and no other block tiles on the TPU.  Pad "
+            f"T to a multiple of 128 (with key masking) or use "
+            f"impl='dense'.")
     pad = -t % 128
     padded = [jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v)]
     return flash_attention(*padded, True)[:, :t]
@@ -262,13 +251,9 @@ class MultiHeadAttention(Layer):
         v = self._expand_kv(v)
         if self.mesh is not None:
             from ..parallel.ring import ring_attention_sharded
-            from ..ops.pallas_attention import _HAS_PLTPU
-            # flash layers ring with the fused kernel per hop; fall back
-            # to the einsum hops on builds without the pallas TPU module
-            # (the ring itself runs anywhere)
+            # flash layers ring with the fused kernel per hop
             ring_impl = self.ring_impl or (
-                "flash" if self.impl == "flash" and _HAS_PLTPU
-                else "blockwise")
+                "flash" if self.impl == "flash" else "blockwise")
             layout = self.ring_layout
             if self.ring_pre_shuffled:
                 layout = "zigzag"

@@ -35,7 +35,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..parallel.mesh import shard_map
-from ..parallel.sync import _shard_map_kw
 
 Tree = Any
 
@@ -165,7 +164,7 @@ def switch_moe_sharded(mesh: Mesh, params: Tree, x, *, axis: str = "ep",
         mesh=mesh,
         in_specs=(specs, P(axis)),
         out_specs=(P(axis), P()),
-        **_shard_map_kw())
+        check_vma=False)
     return fn(params, x)
 
 

@@ -29,19 +29,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend may be absent on pure-CPU builds
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu" or not _HAS_PLTPU
+    return jax.default_backend() != "tpu"
 
 
 def _dot(a, b):
@@ -294,21 +288,37 @@ def _from_bh(x, b, h):
     return x.reshape(b, h, t, dh).transpose(0, 2, 1, 3)
 
 
+def _tileable(t: int) -> bool:
+    """Whether a sequence length has a block Mosaic can tile: the
+    ``lse``/``dvec`` specs map the block onto lanes, so it is a multiple
+    of 128 — or the whole sequence, since a block equal to the array
+    dimension needs no alignment (kept to T <= 128: one block).  Interpret
+    mode accepts anything; T=200 -> 100 and T=544 -> 68 passed every CPU
+    test and were refused on the chip."""
+    return t % 128 == 0 or t <= 128
+
+
 def _auto_block(t: int, dh: int) -> int:
     """Default block size: as LARGE as VMEM allows (measured r4 at
     T=8192/dh=64: 1024² blocks run the fused bwd 3.4× faster than the old
     128² default and 2.4× faster than XLA dense — the per-grid-step
     overhead and small-K matmuls dominated at 128).  The score block is
     b²·4 bytes of VMEM (f32), with 2-3 alive in the backward, so the cap
-    shrinks as the head dim's tiles grow."""
+    shrinks as the head dim's tiles grow.
+
+    Only blocks Mosaic can tile come back (see :func:`_tileable`); any
+    other T is refused — ``ops.attention`` pads such causal lengths to a
+    multiple of 128 before they get here."""
+    if not _tileable(t):
+        raise ValueError(
+            f"flash attention has no tileable block for sequence length "
+            f"{t}: it is neither a multiple of 128 nor at most 128 (one "
+            f"block); pad it to a multiple of 128")
     cap = 1024 if dh <= 64 else 512 if dh <= 128 else 256
     for b in (1024, 512, 256, 128):
         if b <= cap and t % b == 0:
             return b
-    for b in range(min(128, t), 0, -1):  # awkward T: largest divisor
-        if t % b == 0:
-            return b
-    return 1
+    return t
 
 
 def _blocks(tq, tk, block_q, block_k, dh):
@@ -344,9 +354,6 @@ def flash_attention(q, k, v, causal: bool = False, block_q=None,
 
 
 def _vjp_fwd(q, k, v, causal, block_q, block_k):
-    if not _HAS_PLTPU:  # pragma: no cover
-        raise RuntimeError("pallas TPU module unavailable; use "
-                           "dot_product_attention")
     b, t, h, dh = q.shape
     bq, bk = _blocks(t, k.shape[1], block_q, block_k, dh)
     scale = 1.0 / math.sqrt(dh)

@@ -1,4 +1,4 @@
-"""Mesh construction and shard_map compatibility helpers.
+"""Mesh construction and placement helpers.
 
 The reference's "cluster" is a Spark app: N executor JVMs plus a driver
 (reference ``distkeras/trainers.py:DistributedTrainer``).  Ours is a
@@ -16,10 +16,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.4.35 promotes shard_map to the top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
+shard_map = jax.shard_map
 
 
 def make_mesh(num_workers: Optional[int] = None,

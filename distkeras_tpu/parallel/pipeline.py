@@ -37,7 +37,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .mesh import shard_map
-from .sync import _shard_map_kw
 
 Tree = Any
 
@@ -219,6 +218,6 @@ def pipeline_apply_sharded(mesh: Mesh, stage_fn: Callable,
         mesh=mesh,
         in_specs=(param_specs, data_spec),
         out_specs=data_spec,
-        **_shard_map_kw())
+        check_vma=False)
     out = fn(stacked_params, x_mb)
     return out.reshape(batch, *out.shape[2:])

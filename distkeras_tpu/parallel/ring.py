@@ -25,7 +25,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .mesh import shard_map
-from .sync import _shard_map_kw
 
 _NEG = -1e30  # finite -inf stand-in: keeps the online-softmax exp() NaN-free
 
@@ -346,7 +345,7 @@ def ring_schedule_flops(p_size: int, t_loc: int, *, causal: bool,
 
 
 def ulysses_attention(q, k, v, axis_name: str, *, causal: bool = False,
-                      impl: str = "auto"):
+                      impl: str = "flash"):
     """All-to-all sequence parallelism (the DeepSpeed-Ulysses shape);
     call INSIDE ``shard_map``.
 
@@ -371,9 +370,6 @@ def ulysses_attention(q, k, v, axis_name: str, *, causal: bool = False,
     # (B, T/P, H, D) -> (B, T, H/P, D): split heads, concat sequence
     qh, kh, vh = (lax.all_to_all(x, axis_name, split_axis=2, concat_axis=1,
                                  tiled=True) for x in (q, k, v))
-    if impl == "auto":
-        from ..ops.pallas_attention import _HAS_PLTPU
-        impl = "flash" if _HAS_PLTPU else "dense"
     if impl == "flash":
         from ..ops.pallas_attention import flash_attention
         o = flash_attention(qh, kh, vh, causal)
@@ -381,7 +377,7 @@ def ulysses_attention(q, k, v, axis_name: str, *, causal: bool = False,
         from ..ops.attention import dot_product_attention
         o = dot_product_attention(qh, kh, vh, causal=causal)
     else:
-        raise ValueError(f"impl must be auto|flash|dense, got {impl!r}")
+        raise ValueError(f"impl must be flash|dense, got {impl!r}")
     # (B, T, H/P, D) -> (B, T/P, H, D)
     return lax.all_to_all(o, axis_name, split_axis=1, concat_axis=2,
                           tiled=True)
@@ -432,7 +428,7 @@ def ring_attention_sharded(mesh: Mesh, q, k, v, *, axis: str = "sp",
             inner = partial(zigzag_ring_attention, axis_name=axis,
                             impl=impl)
             fn = shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
-                           out_specs=spec, **_shard_map_kw())
+                           out_specs=spec, check_vma=False)
             out = fn(q, k, v)
             return out if pre_shuffled else zigzag_unshuffle(out, p_size)
         # non-causal attention is permutation-invariant over keys and has
@@ -450,5 +446,5 @@ def ring_attention_sharded(mesh: Mesh, q, k, v, *, axis: str = "sp",
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        **_shard_map_kw())
+        check_vma=False)
     return fn(q, k, v)

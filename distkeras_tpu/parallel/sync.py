@@ -24,7 +24,6 @@ pull/commit — includes BatchNorm running statistics.
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -80,14 +79,6 @@ def _squeeze0(tree):
 
 def _expand0(tree):
     return tmap(lambda x: x[None], tree)
-
-
-def _shard_map_kw():
-    """jax renamed check_rep -> check_vma; pick whichever exists."""
-    params = inspect.signature(shard_map).parameters
-    if "check_vma" in params:
-        return {"check_vma": False}
-    return {"check_rep": False}
 
 
 # ---------------------------------------------------------------------------
@@ -360,7 +351,7 @@ class SyncEngine:
             per_device, mesh=self.mesh,
             in_specs=(P(), P(axis), P(axis), P(axis), P(axis), P(axis)),
             out_specs=(P(), P(axis), P(axis), P(axis), P(axis)),
-            **_shard_map_kw())
+            check_vma=False)
 
         @jax.jit
         def run(center, local, opt_state, rngs, xs, ys):
@@ -394,7 +385,7 @@ class SyncEngine:
             per_device, mesh=self.mesh,
             in_specs=(P(), P(axis), P(axis), P(axis), P(axis), P(axis)),
             out_specs=(P(), P(axis), P(axis), P(axis), P(axis)),
-            **_shard_map_kw())
+            check_vma=False)
 
         @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
         def run(center, local, opt_state, rngs, wx, wy):

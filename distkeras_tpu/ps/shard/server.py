@@ -321,7 +321,10 @@ class ProcessShardFleet:
         self._td = tempfile.TemporaryDirectory(prefix="dktpu-shards-")
         blob = serde.tree_to_bytes(center)
         env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"  # shard hosts never grab a device
+        # shard servers are host-only (numpy update rules behind a
+        # socket): pinned to the CPU, so these children never need the
+        # chip the parent process may hold
+        env["JAX_PLATFORMS"] = "cpu"
         self.procs: List[subprocess.Popen] = []
         port_files = []
         for i in range(num_shards):

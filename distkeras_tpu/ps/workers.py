@@ -141,6 +141,7 @@ class AsyncWorker(threading.Thread):
         #: instead of N workers each shipping the same deltas.
         self.telemetry_s = telemetry_s
         self._shipper = None
+        self._platform_stated = False
 
     def set_data(self, xs, ys):
         self.xs, self.ys = xs, ys
@@ -298,6 +299,28 @@ class AsyncWorker(threading.Thread):
                 if hasattr(it, "close"):
                     it.close()
 
+    def _state_platform(self) -> None:
+        """Say ONCE where this worker trains, read off the carry itself
+        after its first window: process workers default to the host CPU
+        because the parent holds the chip, and a run that believed it was
+        on the accelerator must be able to see that it was not.  A carry
+        with no device array in it (a host-only window function) sits on
+        no device and says nothing."""
+        self._platform_stated = True
+        on_device = [leaf for leaf in jax.tree_util.tree_leaves(
+            (self.rng, self.opt_state, self.variables))
+            if isinstance(leaf, jax.Array)]
+        if not on_device:
+            return
+        dev = next(iter(on_device[0].devices()))
+        get_logger("ps.worker").info(
+            "worker %d trains on %s (%s)", self.worker_id, dev,
+            dev.device_kind)
+        if self.metrics is not None:
+            self.metrics.log("worker_platform", worker_id=self.worker_id,
+                             platform=dev.platform,
+                             device_kind=dev.device_kind, device=str(dev))
+
     def _heartbeat(self, gw: int, n_windows: int) -> None:
         """One liveness record per committed window into the shared sink.
         The latest window's mean loss rides along so a live tail of the
@@ -306,6 +329,8 @@ class AsyncWorker(threading.Thread):
         straggler detector and obsview (ISSUE 5 — no wall-clock-diff
         reconstruction downstream; readers fall back to the pre-PR-5
         ``worker`` key on old streams)."""
+        if not self._platform_stated:
+            self._state_platform()
         if self._shipper is not None:
             # window-boundary hook, BEFORE the metrics-sink guard: push
             # telemetry is independent of the JSONL heartbeat stream

@@ -1,6 +1,8 @@
 """ctypes bindings for the native host data plane (``native/dknative.cpp``).
 
-Loads (building on first use, g++) ``libdknative.so`` and exposes:
+Loads ``libdknative.so`` — always through ``make -C native``, a no-op
+when the library is newer than ``dknative.cpp``, so a stale binary is
+never what runs (the library is not committed) — and exposes:
 
 * ``fused_add(a, b, scale)``   — ``a + scale·b`` in one multithreaded pass
   (the PS commit rule; ctypes releases the GIL for the duration).
@@ -39,9 +41,8 @@ def _load() -> Optional[ctypes.CDLL]:
             return _lib
         _tried = True
         try:
-            if not os.path.exists(_LIB_PATH):
-                subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                               capture_output=True, timeout=120)
+            subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
+                           capture_output=True, timeout=120)
             lib = ctypes.CDLL(_LIB_PATH)
             lib.dk_fused_add_f32.argtypes = [
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,

@@ -7,11 +7,12 @@ Usage: ``python scripts/bench_all.py [--quick]``.
 The trainer configs live as DATA in ``configs/bench_all.yaml``
 (SURVEY.md §5.6: one checked-in file reproduces the whole table); that
 part is a thin alias for ``python -m distkeras_tpu.config
-configs/bench_all.yaml``.  The scenario smoke is a subprocess running
-``bench.py --scenario smoke`` — the yaml schema is trainer-only, and
-the smoke wants the same one-JSON-row contract ``bench.py`` already
-keeps — appended so the nightly table also proves the open-loop serve
-path end to end.  The dklint gate runs ``dklint --format json``
+configs/bench_all.yaml``.  The scenario smoke is ``bench.py``'s
+``bench_scenario(("smoke",))`` called IN THIS PROCESS — the yaml schema
+is trainer-only, and the trainers above already hold the accelerator,
+which belongs to one process at a time: a child that needed it would
+fail or hang — appended so the nightly table also proves the open-loop
+serve path end to end.  The dklint gate runs ``dklint --format json``
 repo-wide and fails the nightly on findings or IO errors, and
 round-trips the committed ``dklint_baseline.json`` in the same run so
 serializer drift surfaces the night it lands.  ``--job`` (a packaging
@@ -32,23 +33,12 @@ from distkeras_tpu.obs.logging import emit  # noqa: E402
 
 
 def run_scenario_smoke() -> int:
-    """``bench.py --scenario smoke`` in a subprocess (its fleet binds
-    sockets and warms a serving model — keep the trainer process
-    clean); renders the row's headline as one more table-ish line."""
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "bench.py"),
-         "--scenario", "smoke"],
-        capture_output=True, text=True, timeout=600)
-    if proc.returncode != 0:
-        emit(f"scenario smoke FAILED (rc={proc.returncode}):\n"
-             f"{proc.stderr.strip()[-2000:]}", err=True)
-        return proc.returncode
-    try:
-        row = json.loads(proc.stdout)
-        s = row["scenarios"]["smoke"]
-    except (ValueError, KeyError) as e:
-        emit(f"scenario smoke: unparseable bench row ({e})", err=True)
-        return 1
+    """``bench.py``'s scenario smoke, in this process (the one that
+    holds the device — see the module docstring); renders the row's
+    headline as one more table-ish line."""
+    import bench
+    row = bench.bench_scenario(names=("smoke",))
+    s = row["scenarios"]["smoke"]
     counts = s.get("counts", {})
     alerts = s.get("alerts") or {}
     emit(f"| scenario smoke | {counts.get('dispatched', 0)} dispatched "

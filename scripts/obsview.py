@@ -41,7 +41,7 @@ heartbeats as instants, ``live_bytes`` watermarks as counter tracks.
 
 The file mode also accepts a persisted registry-snapshot JSON (the
 ``BENCH_PS_OBS.json`` / ``BENCH_TRAINER_OBS.json`` that ``bench.py``
-writes beside BENCH_r*.json): per-registry instrument tables plus the
+writes at the repo root): per-registry instrument tables plus the
 commit-codec accounting (compression ratio, bytes saved — ISSUE 4).
 
 Everything renders through pure functions over plain records
@@ -606,7 +606,7 @@ _is_registry_snapshot = drift.is_registry_snapshot
 
 def summarize_snapshot(doc: dict) -> str:
     """Summary of a persisted registry-snapshot file (the
-    ``BENCH_PS_OBS.json`` bench_ps writes beside BENCH_r*.json): one
+    ``BENCH_PS_OBS.json`` bench_ps writes at the repo root): one
     section per component registry, codec accounting surfaced."""
     sections = []
     if isinstance(doc.get("config"), dict):

@@ -40,6 +40,7 @@ def main():
     import numpy as np
     import jax.numpy as jnp
 
+    from distkeras_tpu.obs.profile import fence
     from distkeras_tpu.parallel.mesh import make_mesh
     from distkeras_tpu.parallel.pipeline import (pipeline_apply_sharded,
                                                  stack_stage_params)
@@ -58,13 +59,13 @@ def main():
 
     def timeit(fn, x, reps=3, inner=3):
         jfn = jax.jit(fn)
-        jfn(x).block_until_ready()
+        fence(jfn(x))
         best = 1e9
         for _ in range(reps):
             t0 = time.perf_counter()
             for _ in range(inner):
                 out = jfn(x)
-            out.block_until_ready()
+            fence(out)
             best = min(best, (time.perf_counter() - t0) / inner)
         return best
 

@@ -120,3 +120,10 @@ def test_async_trainers_converge(ds, cls, kw, floor):
     assert acc > floor, acc
     assert len(t.get_history()) == COMMON["num_epoch"]
     assert t.get_history()[0].shape[0] == 4
+    # every worker states ONCE where its carry sits: thread workers are
+    # placed one per device (8 virtual CPU devices here)
+    placed = [r for r in t.metrics.records
+              if r.get("event") == "worker_platform"]
+    assert sorted(p["worker_id"] for p in placed) == [0, 1, 2, 3]
+    assert len({p["device"] for p in placed}) == 4
+    assert {p["platform"] for p in placed} == {"cpu"}

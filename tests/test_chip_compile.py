@@ -1,0 +1,171 @@
+"""The main path's kernels compile for the chip — checked without one.
+
+The TPU compiler is installed here and compiles for a DESCRIBED
+``v5e:2x2`` topology (nothing is attached, nothing runs): it raises what
+the real chip's compiler would raise — block shapes Mosaic cannot tile,
+kernels over the VMEM budget, programs over HBM — which Pallas interpret
+mode, the rest of this suite, cannot see.  A compile that passes is not
+a chip run; ``chip_smoke.py`` is.
+
+This is the ONLY test file that describes a chip.  Only one process may
+load the TPU library, and it keeps it until it exits, so the topology is
+described inside a module-scoped fixture (never at import: every xdist
+worker imports every test file) and every compile happens in the test's
+own process.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distkeras_tpu.ops import pallas_attention
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever the describe raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Sharding on the first described chip.  The persistent compile
+    cache is off while this module compiles: an entry written for a
+    described device cannot be read back without the chip, and the next
+    compile would warn about it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def mosaic(monkeypatch):
+    """The kernels as the chip gets them: the backend here is the CPU, so
+    ``_interpret()`` would pick interpret mode and no kernel would reach
+    the TPU compiler."""
+    monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
+
+
+def _compile(fn, *shapes):
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+def _qkv(one_chip, b, t, h, dh, dtype):
+    return (jax.ShapeDtypeStruct((b, t, h, dh), jnp.dtype(dtype),
+                                 sharding=one_chip),) * 3
+
+
+def _sq_loss(attn):
+    return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) ** 2)
+
+
+#: (B, T, H, Dh), dtype — GPT-2-small's attention (the smoke's model) in
+#: both dtypes, the 1024² big-block regime, head_dim 128, and a small f32
+SHAPES = [
+    ((2, 1024, 12, 64), "bfloat16"),
+    ((2, 1024, 12, 64), "float32"),
+    ((1, 8192, 4, 64), "bfloat16"),
+    ((1, 2048, 8, 128), "bfloat16"),
+    ((4, 256, 4, 32), "float32"),
+]
+
+
+@pytest.mark.parametrize("mode", ["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("shape,dtype", SHAPES,
+                         ids=[f"{'x'.join(map(str, s))}-{d}"
+                              for s, d in SHAPES])
+def test_flash_attention_compiles(one_chip, mosaic, shape, dtype, mode):
+    def attn(q, k, v):
+        return pallas_attention.flash_attention(q, k, v, True)
+
+    fn = attn if mode == "fwd" else jax.grad(_sq_loss(attn),
+                                             argnums=(0, 1, 2))
+    _compile(fn, *_qkv(one_chip, *shape, dtype))
+
+
+def test_flash_lse_rectangular_hop_compiles(one_chip, mosaic):
+    """Tq != Tk, non-causal: the zigzag ring's half-block hop."""
+    q = jax.ShapeDtypeStruct((2, 1024, 4, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 512, 4, 64), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        o, lse = pallas_attention.flash_attention_lse(q, k, v, False)
+        return jnp.sum(o.astype(jnp.float32) ** 2) + jnp.sum(lse)
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+
+
+@pytest.mark.parametrize("t", [200, 544])
+def test_causal_awkward_length_compiles(one_chip, mosaic, t):
+    """T=200 / T=544 (``bench.py``'s SERVE_FABRIC_PHASE seq_len) have no
+    128-aligned divisor; Mosaic refused the 100- and 68-wide blocks the
+    old rule handed it.  The causal route pads to a multiple of 128."""
+    from distkeras_tpu.ops.attention import _flash_with_blocking
+
+    def attn(q, k, v):
+        return _flash_with_blocking(q, k, v, True, t)
+
+    _compile(jax.grad(_sq_loss(attn), argnums=(0, 1, 2)),
+             *_qkv(one_chip, 2, t, 4, 64, "bfloat16"))
+
+
+def test_noncausal_awkward_length_refused(one_chip, mosaic):
+    """Padding would let real queries attend the padded keys: the
+    non-causal route refuses before anything reaches the compiler."""
+    from distkeras_tpu.ops.attention import _flash_with_blocking
+
+    def attn(q, k, v):
+        return _flash_with_blocking(q, k, v, False, 200)
+
+    with pytest.raises(ValueError, match="block-sized divisor"):
+        jax.jit(attn).lower(*_qkv(one_chip, 2, 200, 4, 64, "bfloat16"))
+
+
+def test_gpt_train_step_compiles_with_kernel(one_chip, mosaic):
+    """One whole ``make_local_step`` at GPT-2-small widths (depth cut to
+    2 blocks), bf16 compute: the program the trainers run carries the
+    Mosaic kernel and fits the chip."""
+    import optax
+
+    from distkeras_tpu.models import zoo
+    from distkeras_tpu.ops.losses import sparse_categorical_crossentropy
+    from distkeras_tpu.parallel.sync import make_local_step
+
+    batch, seq, vocab = 8, 1024, 50257
+    model = zoo.gpt_lm(vocab_size=vocab, dim=768, num_heads=12,
+                       num_blocks=2, seq_len=seq, attention_impl="flash")
+    optimizer = optax.adam(1e-3)
+    step = make_local_step(model, sparse_categorical_crossentropy,
+                           optimizer, compute_dtype=jnp.bfloat16)
+
+    def carry_shapes():
+        variables = model.init(0)
+        return (variables, optimizer.init(variables["params"]),
+                jax.random.PRNGKey(0))
+
+    def on_chip(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    carry = jax.tree_util.tree_map(on_chip, jax.eval_shape(carry_shapes))
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                                  sharding=one_chip)
+    compiled = jax.jit(step, donate_argnums=0).lower(
+        carry, (tokens, tokens)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 16e9, f"{total / 1e9:.1f} GB does not fit 16 GB of HBM"

@@ -13,6 +13,25 @@ def test_native_builds_and_loads():
                                 "never fail here")
 
 
+def test_native_load_always_goes_through_make(monkeypatch):
+    """The library is not committed, so whatever file sits in native/ may
+    be stale: every load runs ``make -C native`` (a no-op when the
+    library is newer than dknative.cpp) before it opens the file."""
+    import subprocess
+    calls = []
+    real_run = subprocess.run
+
+    def run(cmd, **kw):
+        calls.append(cmd)
+        return real_run(cmd, **kw)
+
+    monkeypatch.setattr(native.subprocess, "run", run)
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    assert native.available()
+    assert calls == [["make", "-C", native._NATIVE_DIR]]
+
+
 @pytest.mark.parametrize("dtype", [np.float32, np.float64])
 @pytest.mark.parametrize("n", [7, 1 << 10, (1 << 20) + 3])
 def test_fused_add_matches_numpy(dtype, n):

@@ -149,3 +149,22 @@ def test_flash_rectangular_lengths():
                                    rtol=5e-4, atol=5e-5)
     with pytest.raises(ValueError, match="equal q/k"):
         flash_attention_lse(q, k, v, True)
+
+
+@pytest.mark.parametrize("dh", [32, 64, 128, 256])
+def test_auto_block_only_returns_tileable_blocks(dh):
+    """Mosaic tiles a block that is a multiple of 128 (it maps onto lanes
+    in the logsumexp spec) or the whole sequence; interpret mode accepts
+    anything, so the rule is pinned here: T=200 -> 100 and T=544 -> 68
+    passed every CPU test and were refused by the TPU compiler."""
+    from distkeras_tpu.ops.pallas_attention import _auto_block
+    for t in range(1, 2049):
+        if t % 128 == 0 or t <= 128:
+            b = _auto_block(t, dh)
+            assert t % b == 0 and (b == t or b % 128 == 0), (t, b)
+        else:
+            with pytest.raises(ValueError, match="no tileable block"):
+                _auto_block(t, dh)
+    # the big-block regime stays, capped by the head dim's VMEM share
+    assert _auto_block(8192, dh) == (1024 if dh <= 64 else
+                                     512 if dh <= 128 else 256)

@@ -69,3 +69,48 @@ def test_synthetic_datasets_learnable_shapes():
     assert tr["features"].shape == (64, 50) and tr["features"].dtype == np.int32
     tr, te, meta = datasets.load_imagenet_subset(n_train=8, image_size=32)
     assert tr["features"].shape == (8, 32, 32, 3)
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set the helper sets nothing in code
+    (JAX reads the variable itself) and reports that directory."""
+    from distkeras_tpu.utils.compile_cache import enable_compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
+    monkeypatch.setattr(jax.config, "update", lambda *a: pytest.fail(
+        f"set {a} in code although the cache was placed from outside"))
+    assert enable_compile_cache() == "/placed/from/outside"
+
+
+def test_compile_cache_default_is_one_fixed_path(monkeypatch):
+    """Unset, the cache goes to <checkout>/.jax_cache — the path is part
+    of the cache key, so it is the same on every call and every run."""
+    import os
+
+    from distkeras_tpu.utils.compile_cache import enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    set_to = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: set_to.append((name, value)))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert enable_compile_cache() == enable_compile_cache() \
+        == os.path.join(root, ".jax_cache")
+    assert set_to == [("jax_compilation_cache_dir",
+                       os.path.join(root, ".jax_cache"))] * 2
+
+
+def test_mfu_refuses_a_device_without_a_published_peak():
+    """An MFU against a guessed peak is not a measurement: an unlisted
+    device_kind raises (the old table matched substrings and fell back to
+    a 0.1 TFLOP/s "cpu" peak); --peak-tflops is the only way around."""
+    import importlib.util
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "mfu", os.path.join(root, "scripts", "mfu.py"))
+    mfu = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mfu)
+    assert mfu.peak_flops("TPU v5 lite") == 197e12
+    assert mfu.peak_flops("cpu", 0.5) == 0.5e12
+    for kind in ("cpu", "TPU v5 lite pod", "tpu v5 lite", "TPU v4"):
+        with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+            mfu.peak_flops(kind)
