@@ -104,6 +104,15 @@ def activation_config(name_or_fn):
 # base
 # ---------------------------------------------------------------------------
 
+def _scope(layer):
+    """``jax.named_scope`` a container holds while it applies ``layer``:
+    the child's class name in lower case, so a profiler trace and the
+    compiled program's ``op_name``s say which layer an operation belongs
+    to.  Metadata only, and never a layer INDEX: twelve blocks share one
+    path, so a trace still sums each kernel and fusion kind in one row."""
+    return jax.named_scope(type(layer).__name__.lower())
+
+
 class Layer:
     """Base layer: pure-functional init/apply with explicit shapes.
 
@@ -639,13 +648,15 @@ class Residual(Layer):
         r1 = r2 = None
         if rng is not None:
             r1, r2 = jax.random.split(rng)
-        y, new_inner = self.inner.apply(params["inner"], state["inner"], x,
-                                        train=train, rng=r1)
+        with _scope(self.inner):
+            y, new_inner = self.inner.apply(params["inner"], state["inner"],
+                                            x, train=train, rng=r1)
         new_state = {"inner": new_inner}
         if self.shortcut is not None:
-            sc, new_sc = self.shortcut.apply(params["shortcut"],
-                                             state["shortcut"], x,
-                                             train=train, rng=r2)
+            with _scope(self.shortcut):
+                sc, new_sc = self.shortcut.apply(params["shortcut"],
+                                                 state["shortcut"], x,
+                                                 train=train, rng=r2)
             new_state["shortcut"] = new_sc
         else:
             sc = x
@@ -733,7 +744,9 @@ class Sequential(Layer):
             sub = None
             if rng is not None:
                 rng, sub = jax.random.split(rng)
-            x, s = lyr.apply(params[i], state[i], x, train=train, rng=sub)
+            with _scope(lyr):
+                x, s = lyr.apply(params[i], state[i], x, train=train,
+                                 rng=sub)
             new_state.append(s)
         return x, new_state
 

@@ -27,8 +27,10 @@ hysteresis — the live half of the drift gate's contract.
 The profiling layer (ISSUE 6): ``profile`` adds the recompilation
 sentinel (``jit.compiles``/``jit.retraces``, drift-gated), memory
 watermarks (``mem.*`` gauges sampled at the heartbeat points), the
-opt-in ``block_until_ready`` host/device step-time split, and the one
-sanctioned ``jax.profiler`` capture seam; ``export`` renders the
+compile ledger (what a ``jit_compile`` span spent on tracing, lowering
+and the backend, and whether the persistent cache was hit), and the one
+sanctioned ``jax.profiler`` capture seam, in whose host plane every span
+appears (``spans`` holds a ``TraceAnnotation``); ``export`` renders the
 span/heartbeat JSONL as a Chrome/Perfetto trace
 (``obsview --export-trace``) with the PR 5 cross-process links drawn as
 flow arrows.
@@ -60,7 +62,6 @@ from .profile import (  # noqa: F401
     device_trace,
     memory_snapshot,
     observe_memory,
-    step_split,
     tree_signature,
 )
 from .export import records_to_chrome_trace, write_chrome_trace  # noqa: F401

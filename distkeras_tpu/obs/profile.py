@@ -13,30 +13,42 @@ so ``obs.drift`` gates them like any other metric:
   throughput killer SURVEY.md §7 names — logged once per signature with
   the offending shape/dtype hash, and drift-gated by the committed
   ``OBS_BASELINE.json`` (any increase fails ``obsview --diff``).
+* **Compile ledger** (``compile_totals`` / ``compile_spent``, ISSUE 26)
+  — what a compile was spent on, from inside JAX: ``jax.monitoring``
+  listeners, registered once per process, add up per thread the seconds
+  of Python tracing (``trace_s``), of lowering to MLIR (``lower_s``) and
+  of the backend (``backend_s``: XLA's compile, or at a persistent-cache
+  hit the entry's read and the executable's load), and count the cache's
+  hits and misses.  The trainers take the difference around a cold call
+  and put it on the ``jit_compile`` span's record, so a slow set-up says
+  which of the three it was and what the cache did: ``cache_misses`` 1
+  is a compile that was written to the cache, ``cache_hits`` 1 a load
+  from it, both 0 a compile the cache never saw (no cache directory, or
+  a program under ``jax_persistent_cache_min_compile_time_secs``).
 * **Memory watermarks** (``memory_snapshot`` / ``observe_memory``) —
   live device-array bytes (``jax.live_arrays()``), array count, a
   max-tracked ``mem.peak_live_bytes`` gauge, and the backend allocator's
   ``peak_bytes_in_use`` where the platform reports it (TPU/GPU; CPU
   returns none).  Sampled at the existing heartbeat points: trainer
   epoch records and async-worker window heartbeats.
-* **Step-time split** (``step_split``) — wraps a step/window function so
-  every call observes host dispatch time (call → return, i.e. trace +
-  enqueue) and device execution time (return → ``block_until_ready``)
-  into separate ``step.host_seconds`` / ``step.device_seconds``
-  histograms.  Opt-in via ``ProfileConfig.step_split``: the hard sync
-  per call defeats the epoch pipelining the trainers use for honest
-  headline timing, so it is a profiling mode, not a default.
 * **Device trace seam** (``device_trace``) — the one sanctioned
   ``jax.profiler`` start/stop wrapper: announces the output dir once via
   ``obs.logging``, and never leaks an open trace session on exception
   paths (a failing ``stop_trace`` is logged, not allowed to mask the
   body's error).  ``utils.metrics.profile_trace`` delegates here, and
   ``ProfileConfig.trace_dir`` requests per-epoch captures from trainer
-  config.
+  config.  Every ``obs.spans`` span holds a ``TraceAnnotation``, so the
+  capture's host plane carries the program's own spans (``train``,
+  ``train.stage``, ``train.init``, ``train.dispatch``, ``jit_compile``,
+  ``train.readback``, ``train.to_host``) beside PjRt's events and on the
+  clock of the device's planes: the host/device split of a step is read
+  off the trace, with no fence added to the run.
 
 ``ProfileConfig`` is the trainer-facing knob bundle
 (``Trainer(..., profile=...)`` accepts a ``ProfileConfig``, a dict of
-its fields, or a bare path string meaning ``trace_dir``).
+its fields, or a bare path string meaning ``trace_dir``: the operator's
+way to such a trace, ``Trainer(profile="<dir>")``, then TensorBoard or
+ui.perfetto.dev on ``<dir>/epoch0``).
 """
 
 from __future__ import annotations
@@ -48,7 +60,7 @@ import threading
 from typing import Any, Callable, Optional, Sequence, Tuple, Union
 
 from .logging import get_logger
-from .registry import Registry, TIME_BUCKETS, default_registry
+from .registry import Registry, default_registry
 
 #: live-byte buckets for the optional watermark histogramming — gauges are
 #: the primary surface (levels), these exist for callers that want a
@@ -173,6 +185,93 @@ class RetraceSentinel:
 
 
 # ---------------------------------------------------------------------------
+# compile ledger (jax.monitoring)
+# ---------------------------------------------------------------------------
+
+#: JAX's own timers around the three stages of a jit cold call -> the
+#: field each feeds.  ``backend_s`` spans ``compile_or_get_cached``: XLA's
+#: compile at a miss, the cache entry's read and the executable's load at
+#: a hit.
+_STAGE_FIELD = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+}
+#: persistent-cache events -> counts (a miss is recorded where the fresh
+#: executable is written to the cache)
+_CACHE_FIELD = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+
+
+class _CompileLedger(threading.local):
+    """One thread's running totals (a jit cold call traces, lowers and
+    compiles on the thread that made it)."""
+
+    def __init__(self):
+        self.totals = dict.fromkeys(_STAGE_FIELD.values(), 0.0)
+        self.totals.update(dict.fromkeys(_CACHE_FIELD.values(), 0))
+        #: the stage timers counted since the last reading, as
+        #: ``(start, field, seconds)`` in the order they closed.  JAX's
+        #: timers nest (tracing a program traces the jitted functions it
+        #: calls, each under a timer of its own) and a timer reports as
+        #: it closes, inner before outer: one that closes later and
+        #: started no later holds those counted before it, which are
+        #: taken out again, so the three fields never count a second
+        #: twice
+        self.counted = []
+
+
+_LEDGER = _CompileLedger()
+_LISTENING = False
+_LISTEN_LOCK = threading.Lock()
+
+
+def _stage_closed(event: str, start: float, end: float, **kw) -> None:
+    field = _STAGE_FIELD.get(event)
+    if field is None:
+        return
+    totals, counted = _LEDGER.totals, _LEDGER.counted
+    while counted and counted[-1][0] >= start:
+        _, inner, seconds = counted.pop()
+        totals[inner] -= seconds
+    counted.append((start, field, end - start))
+    totals[field] += end - start
+
+
+def _cache_event(event: str, **kw) -> None:
+    field = _CACHE_FIELD.get(event)
+    if field is not None:
+        _LEDGER.totals[field] += 1
+
+
+def compile_totals() -> dict:
+    """The calling thread's running totals since the listeners were
+    registered (they are, once per process, by the first call here):
+    ``trace_s`` / ``lower_s`` / ``backend_s`` seconds and ``cache_hits``
+    / ``cache_misses`` counts.  Take one before a cold call and hand it
+    to :func:`compile_spent` after: a reading is taken between compiles,
+    never inside one."""
+    global _LISTENING
+    with _LISTEN_LOCK:  # once per cold call: no need to dodge the lock
+        if not _LISTENING:
+            import jax.monitoring as monitoring
+            monitoring.register_event_time_span_listener(_stage_closed)
+            monitoring.register_event_listener(_cache_event)
+            _LISTENING = True
+    _LEDGER.counted.clear()  # no timer is open: nothing can hold these
+    return dict(_LEDGER.totals)
+
+
+def compile_spent(before: dict) -> dict:
+    """What this thread spent compiling since ``before``
+    (a :func:`compile_totals` reading): the five fields of a
+    ``jit_compile`` record."""
+    return {k: v - before[k] for k, v in compile_totals().items()}
+
+
+# ---------------------------------------------------------------------------
 # memory watermarks
 # ---------------------------------------------------------------------------
 
@@ -236,7 +335,7 @@ def observe_memory(registry: Optional[Registry] = None) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# step-time split
+# the timing fence
 # ---------------------------------------------------------------------------
 
 def fence(tree):
@@ -249,34 +348,6 @@ def fence(tree):
     device work, and no readback is needed just to stop a clock."""
     import jax
     return jax.block_until_ready(tree)
-
-
-def step_split(fn: Callable, registry=None, prefix: str = "step") -> Callable:
-    """Wrap a step/window function with the host/device time split: the
-    call itself is host work (trace + dispatch — jit returns at enqueue
-    time), the ``block_until_ready`` that follows is device execution.
-    Observations land in ``<prefix>.host_seconds`` /
-    ``<prefix>.device_seconds`` histograms in ``registry`` (instance,
-    zero-arg callable, or None for the default registry).
-
-    The hard sync per call is exactly what the trainers' epoch pipelining
-    exists to avoid — this is a profiling mode (``ProfileConfig.
-    step_split``), not a default."""
-    import time
-
-    def wrapped(*args):
-        reg = registry() if callable(registry) else registry
-        reg = reg if reg is not None else default_registry()
-        t0 = time.perf_counter()
-        out = fn(*args)
-        t1 = time.perf_counter()
-        fence(out)
-        t2 = time.perf_counter()
-        reg.histogram(f"{prefix}.host_seconds", TIME_BUCKETS).observe(t1 - t0)
-        reg.histogram(f"{prefix}.device_seconds",
-                      TIME_BUCKETS).observe(t2 - t1)
-        return out
-    return wrapped
 
 
 # ---------------------------------------------------------------------------
@@ -334,15 +405,11 @@ class ProfileConfig:
       (None = no device capture).
     * ``trace_epochs`` — which epochs to capture (default: epoch 0, the
       compile-heavy one); None means every epoch.
-    * ``step_split`` — wrap the step/window programs in the
-      ``block_until_ready`` host/device split (defeats epoch pipelining;
-      profiling runs only).
     * ``memory`` — sample memory watermarks at the existing heartbeat
       points (per-epoch records, per-window worker heartbeats)."""
 
     trace_dir: Optional[str] = None
     trace_epochs: Optional[Sequence[int]] = (0,)
-    step_split: bool = False
     memory: bool = True
 
     def trace_epoch(self, epoch: int) -> bool:

@@ -22,6 +22,16 @@ span CROSS a process boundary: the PS client ships its open commit span's
 them as its ``trace_id``/``parent_span`` — ``scripts/obsview.py`` then
 links server applies back to the worker windows that caused them.
 
+One clock with the device (ISSUE 26): for its life every span also holds
+a ``jax.profiler.TraceAnnotation`` of its own name, so whenever a
+profiler trace is running (``Trainer(profile=<dir>)``,
+``obs.profile.device_trace``, ``benchmark/run.py --trace 1``) the
+program's spans lie in the trace's host plane beside PjRt's own events,
+on the profiler's clock — an idle gap on the device can then be named by
+the span the host was in (``train.readback``, ``train.dispatch``, ...).
+With no trace running the annotation is a flag check; a process that
+never imported JAX (a PS shard server) is not made to.
+
 Optionally a ``Registry`` accumulates per-name duration histograms
 (``span.<name>.seconds``) so cumulative span time shows up in ``STATS``
 snapshots too.  A process-wide default tracer (``obs.span``) serves ad-hoc
@@ -32,12 +42,16 @@ from __future__ import annotations
 
 import contextlib
 import itertools
+import sys
 import threading
 import time
 import uuid
 from typing import Optional, Tuple
 
+from .logging import get_logger
 from .registry import Registry, TIME_BUCKETS
+
+_LOG = "obs.spans"
 
 #: span ids are ``<trace_id>.<salt><seq>``: a process-wide monotone
 #: counter plus a per-process random salt.  The salt is what keeps ids
@@ -51,6 +65,32 @@ _SPAN_SEQ = itertools.count(1)
 #: would collide ~50% by ~256 runs, and colliding runs collide id-for-id
 #: because the sequence restarts at 1)
 _SPAN_SALT = uuid.uuid4().hex[:8]
+
+
+def _annotate(name: str):
+    """An entered ``jax.profiler.TraceAnnotation(name)``, or None: where
+    JAX was never imported by this process (the tracer must not be what
+    imports it), or where the profiler refuses — a span's record and
+    timing never depend on the profiler."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        annotation = jax.profiler.TraceAnnotation(name)
+        annotation.__enter__()
+    except Exception as e:  # noqa: BLE001 — the profiler is best effort
+        get_logger(_LOG).debug("span %s: no trace annotation: %r", name, e)
+        return None
+    return annotation
+
+
+def _end_annotation(annotation) -> None:
+    if annotation is None:
+        return
+    try:
+        annotation.__exit__(None, None, None)
+    except Exception as e:  # noqa: BLE001 — see _annotate
+        get_logger(_LOG).debug("trace annotation did not close: %r", e)
 
 
 class SpanTracer:
@@ -103,7 +143,11 @@ class SpanTracer:
         span still records its duration, flagged ``error=True``).
         ``trace_id``/``parent_span`` keyword fields override the automatic
         thread-local ones — the server-side hook for adopting a REMOTE
-        caller's trace context."""
+        caller's trace context.  The ``with`` target is the record's
+        own fields: what a scope learns only once it has run goes there
+        (the trainers put a cold call's compile split on its
+        ``jit_compile`` span); the structural keys stay authoritative
+        (``_emit``)."""
         stack = self._stack()
         # a span adopting a REMOTE trace (explicit trace_id field — the
         # server-side hook) mints its id under THAT trace, so span-id
@@ -114,16 +158,20 @@ class SpanTracer:
         stack.append((name, span_id))
         path = "/".join(n for n, _ in stack)
         depth = len(stack) - 1
+        annotation = _annotate(name)
         t0 = time.perf_counter()
         try:
-            yield self
+            yield fields
         except BaseException:
-            self._emit(name, path, depth, time.perf_counter() - t0,
-                       span_id, parent, dict(fields, error=True))
+            seconds = time.perf_counter() - t0
+            _end_annotation(annotation)
+            self._emit(name, path, depth, seconds, span_id, parent,
+                       dict(fields, error=True))
             raise
         else:
-            self._emit(name, path, depth, time.perf_counter() - t0,
-                       span_id, parent, fields)
+            seconds = time.perf_counter() - t0
+            _end_annotation(annotation)
+            self._emit(name, path, depth, seconds, span_id, parent, fields)
         finally:
             stack.pop()
 

@@ -236,7 +236,8 @@ class MultiHeadAttention(Layer):
 
     def apply(self, params, state, x, *, train=False, rng=None):
         b, t, d = x.shape
-        q, k, v = self._project(params, x)
+        with jax.named_scope("qkv"):
+            q, k, v = self._project(params, x)
         if self.rope:
             if self.mesh is not None:
                 raise ValueError(
@@ -278,8 +279,9 @@ class MultiHeadAttention(Layer):
             o = _flash_with_blocking(q, k, v, self.causal, t)
         else:
             o = dot_product_attention(q, k, v, causal=self.causal)
-        o = o.reshape(b, t, d)
-        return o @ params["out"].astype(x.dtype), state
+        with jax.named_scope("out_proj"):
+            o = o.reshape(b, t, d)
+            return o @ params["out"].astype(x.dtype), state
 
     def init_cache(self, batch, in_shape):
         t, d = in_shape

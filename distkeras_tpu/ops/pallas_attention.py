@@ -152,6 +152,7 @@ def _flash_fwd_raw(qr, kr, vr, *, causal, bq, bk, scale):
                         pltpu.VMEM((bq, 128), jnp.float32),
                         pltpu.VMEM((bq, 128), jnp.float32)],
         interpret=_interpret(),
+        name="flash_fwd",
     )(qr, kr, vr)
     return out, lse
 
@@ -247,6 +248,7 @@ def _flash_bwd_raw(qr, kr, vr, do, lse, dvec, *, causal, bq, bk, scale):
         out_shape=jax.ShapeDtypeStruct((bh, tq, dh), qr.dtype),
         scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(qr, kr, vr, do, lse, dvec)
 
     dk, dv = pl.pallas_call(
@@ -270,6 +272,7 @@ def _flash_bwd_raw(qr, kr, vr, do, lse, dvec, *, causal, bq, bk, scale):
         scratch_shapes=[pltpu.VMEM((bk, dh), jnp.float32),
                         pltpu.VMEM((bk, dh), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(kr, vr, qr, do, lse, dvec)
     return dq, dk, dv
 
@@ -357,9 +360,15 @@ def _vjp_fwd(q, k, v, causal, block_q, block_k):
     b, t, h, dh = q.shape
     bq, bk = _blocks(t, k.shape[1], block_q, block_k, dh)
     scale = 1.0 / math.sqrt(dh)
-    out, lse = _flash_fwd_raw(_to_bh(q), _to_bh(k), _to_bh(v),
-                              causal=causal, bq=bq, bk=bk, scale=scale)
-    return _from_bh(out, b, h), (q, k, v, out, lse)
+    # "layout": the (B, T, H, Dh) <-> (BH, T, Dh) transposes around the
+    # kernels, named so a trace can charge their copies to attention
+    with jax.named_scope("layout"):
+        qr, kr, vr = _to_bh(q), _to_bh(k), _to_bh(v)
+    out, lse = _flash_fwd_raw(qr, kr, vr, causal=causal, bq=bq, bk=bk,
+                              scale=scale)
+    with jax.named_scope("layout"):
+        out_bthd = _from_bh(out, b, h)
+    return out_bthd, (q, k, v, out, lse)
 
 
 def _bwd_impl(causal, block_q, block_k, res, g_out, g_lse=None):
@@ -371,18 +380,21 @@ def _bwd_impl(causal, block_q, block_k, res, g_out, g_lse=None):
     b, t, h, dh = q.shape
     bq, bk = _blocks(t, k.shape[1], block_q, block_k, dh)
     scale = 1.0 / math.sqrt(dh)
-    do = _to_bh(g_out.astype(q.dtype))
+    with jax.named_scope("layout"):
+        do = _to_bh(g_out.astype(q.dtype))
     # D_i = rowsum(dO_i ∘ O_i) — the softmax-grad correction term (f32)
     dvec = jnp.sum(do.astype(jnp.float32) * out_bh.astype(jnp.float32),
                    axis=-1)[:, None, :]
     if g_lse is not None:
         dvec = dvec - g_lse.astype(jnp.float32).reshape(b * h, 1, t)
-    dq, dk, dv = _flash_bwd_raw(_to_bh(q), _to_bh(k), _to_bh(v), do, lse,
-                                dvec, causal=causal, bq=bq, bk=bk,
-                                scale=scale)
-    return (_from_bh(dq, b, h).astype(q.dtype),
-            _from_bh(dk, b, h).astype(k.dtype),
-            _from_bh(dv, b, h).astype(v.dtype))
+    with jax.named_scope("layout"):
+        qr, kr, vr = _to_bh(q), _to_bh(k), _to_bh(v)
+    dq, dk, dv = _flash_bwd_raw(qr, kr, vr, do, lse, dvec, causal=causal,
+                                bq=bq, bk=bk, scale=scale)
+    with jax.named_scope("layout"):
+        return (_from_bh(dq, b, h).astype(q.dtype),
+                _from_bh(dk, b, h).astype(k.dtype),
+                _from_bh(dv, b, h).astype(v.dtype))
 
 
 def _vjp_bwd(causal, block_q, block_k, res, g):

@@ -140,10 +140,14 @@ def make_local_step(model, loss_fn: Callable,
             # the forward sees compute_dtype copies (covers token-input
             # models too, where no float x exists to derive dtype from —
             # layers cast their weights to the activation dtype)
-            fwd_params = cast_floats(params) if compute_dtype is not None \
-                else params
+            if compute_dtype is not None:
+                with jax.named_scope("cast_params"):
+                    fwd_params = cast_floats(params)
+            else:
+                fwd_params = params
             out, new_state = forward(fwd_params, variables["state"], x, sub)
-            loss_val = loss_fn(out, y)
+            with jax.named_scope("loss"):
+                loss_val = loss_fn(out, y)
             if aux_weight:
                 aux = aux_losses(new_state)
                 if aux:
@@ -152,9 +156,10 @@ def make_local_step(model, loss_fn: Callable,
 
         (loss_val, new_state), grads = jax.value_and_grad(
             loss_of, has_aux=True)(variables["params"])
-        updates, opt_state = optimizer.update(
-            grads, opt_state, variables["params"])
-        params = optax.apply_updates(variables["params"], updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, opt_state, variables["params"])
+            params = optax.apply_updates(variables["params"], updates)
         return ({"params": params, "state": new_state}, opt_state, rng), loss_val
 
     return step

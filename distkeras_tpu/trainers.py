@@ -22,6 +22,7 @@ Under the hood nothing resembles the reference's Spark + socket-PS stack:
 
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 import warnings
@@ -83,7 +84,9 @@ class _EpochPipeline:
         if item is None:
             return
         epoch, dev_losses = item
-        losses = _to_host(dev_losses)  # waits for that epoch's compute
+        # the host waiting for that epoch's compute
+        with self.trainer.tracer.span("train.readback", epoch=epoch):
+            losses = _to_host(dev_losses)
         if self.reshape is not None:
             losses = losses.reshape(self.reshape)
         now = time.time()
@@ -185,9 +188,10 @@ class Trainer:
         #: per-epoch records interleave in one JSONL stream (ISSUE 2),
         #: readable by ``scripts/obsview.py``
         self.tracer = SpanTracer(self.metrics)
-        #: profiling knobs (ISSUE 6): per-epoch ``jax.profiler`` captures,
-        #: the block_until_ready step-time split, memory watermarks —
-        #: ``obs.ProfileConfig`` | dict of its fields | trace-dir string
+        #: profiling knobs (ISSUE 6): per-epoch ``jax.profiler`` captures
+        #: (the spans below appear in their host plane), memory
+        #: watermarks — ``obs.ProfileConfig`` | dict of its fields |
+        #: trace-dir string
         self.profile = obs_profile.ProfileConfig.resolve(profile)
         #: per-(kind, config) retrace sentinels behind ``_instrumented``:
         #: the cold/warm split for the ``jit_compile`` span AND the
@@ -255,31 +259,35 @@ class Trainer:
         ISSUE 6: every call additionally feeds the recompilation sentinel
         — a NEW arg signature (shape/dtype tree) after the cold compile
         is a retrace, counted into ``jit.retraces`` (drift-gated) and
-        recorded as a ``jit_compile`` span flagged ``retrace=True``; with
-        ``profile.step_split`` the program also runs under the
-        host-dispatch / device-execution timing split."""
+        recorded as a ``jit_compile`` span flagged ``retrace=True``.
+
+        ISSUE 26: the ``jit_compile`` record says what the call spent —
+        ``trace_s`` (Python tracing), ``lower_s`` (to MLIR), ``backend_s``
+        (XLA's compile, or the persistent cache's read and load at a
+        hit), ``cache_hits`` / ``cache_misses`` — from JAX's own timers
+        (``obs.profile.compile_totals``); what is left of ``seconds`` is
+        the dispatch."""
         key = (kind, self._config_key())
         sentinel = self._sentinels.get(key)
         if sentinel is None:
             sentinel = self._sentinels[key] = obs_profile.RetraceSentinel(
                 f"{type(self).__name__}.{kind}",
                 registry=self._obs_registry, sink=self.metrics)
-        step = obs_profile.step_split(run, registry=self._obs_registry) \
-            if self.profile.step_split else run
 
         def wrapped(*args):
             state = sentinel.observe(args)
             if state == "warm":
-                return step(*args)
-            # compile calls bypass the step split: the seconds-long trace
-            # + XLA compile would land as one step.host_seconds sample
-            # and dominate a short profiling run — the jit_compile span
-            # already accounts for compile time separately
+                return run(*args)
             with self.tracer.span("jit_compile", kind=kind,
                                   trainer=type(self).__name__,
                                   **({"retrace": True}
-                                     if state == "retrace" else {})):
-                return run(*args)
+                                     if state == "retrace" else {})
+                                  ) as record:
+                before = obs_profile.compile_totals()
+                try:
+                    return run(*args)
+                finally:
+                    record.update(obs_profile.compile_spent(before))
         return wrapped
 
     def _profiled_run(self, run, epoch: int, *args):
@@ -287,13 +295,17 @@ class Trainer:
         ``jax.profiler`` capture (``profile.trace_dir`` /
         ``trace_epochs`` — ISSUE 6).  The capture blocks on the outputs
         before stopping so the trace holds THIS epoch's device work; the
-        pipelined (uncaptured) epochs keep their no-sync dispatch."""
-        if not self.profile.trace_epoch(epoch):
-            return run(*args)
+        pipelined (uncaptured) epochs keep their no-sync dispatch.  The
+        call itself is the ``train.dispatch`` span (a cold call's
+        ``jit_compile`` nests in it)."""
+        capture = self.profile.trace_epoch(epoch)
         with obs_profile.device_trace(
-                os.path.join(self.profile.trace_dir, f"epoch{epoch}")):
-            out = run(*args)
-            jax.block_until_ready(out)
+                os.path.join(self.profile.trace_dir, f"epoch{epoch}")) \
+                if capture else contextlib.nullcontext():
+            with self.tracer.span("train.dispatch", epoch=epoch):
+                out = run(*args)
+            if capture:
+                jax.block_until_ready(out)
         return out
 
     def _window_run(self):
@@ -313,7 +325,9 @@ class Trainer:
         return self._instrumented(run), optimizer
 
     def _finish(self, variables) -> Model:
-        self.trained_variables = jax.tree_util.tree_map(_to_host, variables)
+        with self.tracer.span("train.to_host"):  # the variables' way back
+            self.trained_variables = jax.tree_util.tree_map(_to_host,
+                                                            variables)
         self.model.variables = self.trained_variables
         return self.model
 
@@ -391,15 +405,17 @@ class SingleTrainer(Trainer):
             dataset = dataset.shuffle(self.seed)
         run, optimizer = self._window_run()
 
-        ds = dataset.coalesce(1)
-        stacked, steps = ds.stacked([self.features_col, self.label_col],
-                                    self.batch_size)
-        xs = jnp.asarray(stacked[self.features_col][0])
-        ys = jnp.asarray(stacked[self.label_col][0])
+        with self.tracer.span("train.stage"):
+            ds = dataset.coalesce(1)
+            stacked, steps = ds.stacked(
+                [self.features_col, self.label_col], self.batch_size)
+            xs = jnp.asarray(stacked[self.features_col][0])
+            ys = jnp.asarray(stacked[self.label_col][0])
 
-        variables = self.model.init(self.seed)
-        opt_state = optimizer.init(variables["params"])
-        rng = jax.random.PRNGKey(self.seed + 1)
+        with self.tracer.span("train.init"):
+            variables = self.model.init(self.seed)
+            opt_state = optimizer.init(variables["params"])
+            rng = jax.random.PRNGKey(self.seed + 1)
 
         ckpt = self._ckpt_manager()
         (variables, opt_state, rng), start_epoch = self._maybe_restore(
@@ -674,18 +690,21 @@ class DistributedTrainer(Trainer):
         run, mesh, optimizer = self._engine_run()
         P = self.num_workers
 
-        xs, ys, _ = self._stage_data(dataset, self.communication_window)
-        xs = mesh_lib.host_to_mesh(mesh, xs)
-        ys = mesh_lib.host_to_mesh(mesh, ys)
+        with self.tracer.span("train.stage"):
+            xs, ys, _ = self._stage_data(dataset, self.communication_window)
+            xs = mesh_lib.host_to_mesh(mesh, xs)
+            ys = mesh_lib.host_to_mesh(mesh, ys)
 
-        center = self.model.init(self.seed)
-        center = mesh_lib.broadcast_to_mesh(mesh, center)
-        local = tmap(lambda x: np.broadcast_to(np.asarray(x)[None],
-                                               (P, *np.shape(x))), center)
-        local = mesh_lib.host_to_mesh(mesh, local)
-        opt_state = jax.vmap(optimizer.init)(local["params"])
-        rngs = jax.random.split(jax.random.PRNGKey(self.seed + 1), P)
-        rngs = mesh_lib.host_to_mesh(mesh, rngs)
+        with self.tracer.span("train.init"):
+            center = self.model.init(self.seed)
+            center = mesh_lib.broadcast_to_mesh(mesh, center)
+            local = tmap(lambda x: np.broadcast_to(np.asarray(x)[None],
+                                                   (P, *np.shape(x))),
+                         center)
+            local = mesh_lib.host_to_mesh(mesh, local)
+            opt_state = jax.vmap(optimizer.init)(local["params"])
+            rngs = jax.random.split(jax.random.PRNGKey(self.seed + 1), P)
+            rngs = mesh_lib.host_to_mesh(mesh, rngs)
 
         ckpt = self._ckpt_manager()
         (center, local, opt_state, rngs), start_epoch = self._maybe_restore(

@@ -3,9 +3,9 @@
 Covers the retrace sentinel (signature semantics, cold/warm/retrace
 states, exactly-once logging, the trainer wiring: zero retraces on warm
 steady state, exactly one on a deliberate shape change), memory
-watermarks at the heartbeat points, the opt-in ``block_until_ready``
-step-time split, the ``jax.profiler`` capture seam (one-time announce,
-exception-safe stop, per-epoch capture from trainer config), the
+watermarks at the heartbeat points, the ``jax.profiler`` capture seam
+(one-time announce, exception-safe stop, per-epoch capture from trainer
+config), the
 ``obsview --export-trace`` Chrome Trace Event export (synthetic
 two-process round-trip + the acceptance scenario: a real 2-worker async
 DynSGD run whose server ``ps.apply`` events re-parse as children of the
@@ -239,31 +239,6 @@ def test_profile_memory_off_disables_worker_sampling(ds, tmp_path):
     assert hbs and all("live_bytes" not in h for h in hbs)
 
 
-# -- step-time split ---------------------------------------------------------
-
-def test_step_split_host_device_histograms(ds):
-    reg = Registry()
-    t = dk.SingleTrainer(make_model(), "sgd",
-                         profile=ProfileConfig(step_split=True), **COMMON)
-    t.tracer.registry = reg
-    t.train(ds)
-    host = reg.get("step.host_seconds")
-    dev = reg.get("step.device_seconds")
-    # one observation per WARM epoch call: the cold (compile) call
-    # bypasses the split so compile time can't pollute the histograms
-    assert host.count == COMMON["num_epoch"] - 1
-    assert dev.count == COMMON["num_epoch"] - 1
-    assert host.sum > 0
-
-
-def test_step_split_off_by_default(ds):
-    reg = Registry()
-    t = dk.SingleTrainer(make_model(), "sgd", **COMMON)
-    t.tracer.registry = reg
-    t.train(ds)
-    assert reg.get("step.host_seconds") is None  # no per-call hard sync
-
-
 # -- device trace seam -------------------------------------------------------
 
 def test_device_trace_announces_once_and_writes(tmp_path, caplog):
@@ -311,8 +286,10 @@ def test_per_epoch_capture_from_trainer_config(ds, tmp_path):
 def test_profile_config_resolve():
     assert ProfileConfig.resolve(None).trace_dir is None
     assert ProfileConfig.resolve("/tmp/x").trace_dir == "/tmp/x"
-    pc = ProfileConfig.resolve({"step_split": True, "memory": False})
-    assert pc.step_split and not pc.memory
+    pc = ProfileConfig.resolve({"trace_epochs": None, "memory": False})
+    assert pc.trace_epochs is None and not pc.memory
+    with pytest.raises(TypeError):  # the fence-per-call split is gone
+        ProfileConfig.resolve({"step_split": True})
     assert ProfileConfig.resolve(pc) is pc
     with pytest.raises(TypeError):
         ProfileConfig.resolve(3)
