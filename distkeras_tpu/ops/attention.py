@@ -108,8 +108,10 @@ def _flash_with_blocking(q, k, v, causal: bool, t: int):
     """
     from .pallas_attention import _tileable, flash_attention
     if _tileable(t):
-        # block sizes auto-tune inside the kernel (largest VMEM-fitting
-        # divisor of T — the big-block regime is where flash beats dense)
+        # the kernels pick their own walk (pallas_attention._blocks):
+        # causal lengths that fit VMEM whole run only the tiles at or
+        # before the diagonal, inside the kernel; the rest walk the grid
+        # in the largest VMEM-fitting blocks dividing T
         return flash_attention(q, k, v, causal)
     if not causal:
         raise ValueError(

@@ -71,13 +71,19 @@ def _sq_loss(attn):
 
 
 #: (B, T, H, Dh), dtype — GPT-2-small's attention (the smoke's model) in
-#: both dtypes, the 1024² big-block regime, head_dim 128, and a small f32
+#: both dtypes, the 1024² big-block regime, head_dim 128, a small f32;
+#: and where the causal rule switches path (``_causal_tile``): T = 640
+#: walks 5 tiles of 128 inside the kernel (a count that is no power of
+#: two), T = 4,096 is the first power of two past the VMEM budget and
+#: keeps the grid walk
 SHAPES = [
     ((2, 1024, 12, 64), "bfloat16"),
     ((2, 1024, 12, 64), "float32"),
     ((1, 8192, 4, 64), "bfloat16"),
     ((1, 2048, 8, 128), "bfloat16"),
     ((4, 256, 4, 32), "float32"),
+    ((2, 640, 4, 64), "bfloat16"),
+    ((1, 4096, 4, 64), "bfloat16"),
 ]
 
 
@@ -137,7 +143,8 @@ def test_noncausal_awkward_length_refused(one_chip, mosaic):
 def test_gpt_train_step_compiles_with_kernel(one_chip, mosaic):
     """One whole ``make_local_step`` at GPT-2-small widths (depth cut to
     2 blocks), bf16 compute: the program the trainers run carries the
-    Mosaic kernel and fits the chip."""
+    Mosaic kernel, fits the chip, and its flash kernels run the causal
+    triangle only."""
     import optax
 
     from distkeras_tpu.models import zoo
@@ -162,9 +169,16 @@ def test_gpt_train_step_compiles_with_kernel(one_chip, mosaic):
     carry = jax.tree_util.tree_map(on_chip, jax.eval_shape(carry_shapes))
     tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
                                   sharding=one_chip)
+    from distkeras_tpu.obs.registry import default_registry
+    tiles = [default_registry().counter(f"flash.causal_tiles_{which}")
+             for which in ("executed", "total")]
+    before = [c.value for c in tiles]
     compiled = jax.jit(step, donate_argnums=0).lower(
         carry, (tokens, tokens)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # one trace of the step: 2 blocks x 3 kernels, each 10 of 16 tile
+    # pairs (T = 1,024 in 256 tiles: the in-kernel causal walk engaged)
+    assert [c.value - b for c, b in zip(tiles, before)] == [60, 96]
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
