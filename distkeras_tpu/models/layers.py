@@ -21,6 +21,7 @@ contract (reference ``distkeras/utils.py:serialize_keras_model``).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Callable, Optional, Sequence
 
@@ -234,6 +235,60 @@ class Dense(Layer):
             "activation": activation_config(self.activation),
             "use_bias": self.use_bias,
         }
+
+
+@register
+class RMSNorm(Layer):
+    """Root-mean-square norm (Zhang & Sennrich 2019): ``x / sqrt(mean(x²)
+    + epsilon) * scale``, no mean subtracted and no bias; the statistics
+    are taken in float32 whatever the activations' dtype."""
+
+    def __init__(self, epsilon: float = 1e-6):
+        self.epsilon = float(epsilon)
+
+    def init(self, rng, in_shape):
+        return {"scale": jnp.ones((in_shape[-1],))}, {}, in_shape
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        xf = x.astype(jnp.float32)
+        y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                           + self.epsilon)
+        return (y * params["scale"].astype(jnp.float32)).astype(x.dtype), \
+            state
+
+    def get_config(self):
+        return {"epsilon": self.epsilon}
+
+
+def swiglu(x, gate_up, down):
+    """``(silu(x W_gate) * (x W_up)) W_down`` with W_gate and W_up side
+    by side in ``gate_up`` (D, 2F): one matmul, split in the middle."""
+    h = x @ gate_up.astype(x.dtype)
+    f = h.shape[-1] // 2
+    return (jax.nn.silu(h[..., :f]) * h[..., f:]) @ down.astype(x.dtype)
+
+
+@register
+class SwiGLU(Layer):
+    """Gated feed-forward (Shazeer 2020): D -> ``units`` twice (gate and
+    up, one fused (D, 2·units) matrix), silu(gate) * up, back to D.  No
+    bias."""
+
+    def __init__(self, units: int):
+        self.units = int(units)
+
+    def init(self, rng, in_shape):
+        d = in_shape[-1]
+        k1, k2 = jax.random.split(rng)
+        return {"gate_up": glorot_uniform(k1, (d, 2 * self.units),
+                                          fan_in=d, fan_out=self.units),
+                "down": glorot_uniform(k2, (self.units, d))}, {}, in_shape
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        return swiglu(x, params["gate_up"], params["down"]), state
+
+    def get_config(self):
+        return {"units": self.units}
 
 
 @register
@@ -738,15 +793,21 @@ class Sequential(Layer):
             shape = lyr.out_shape(shape)
         return shape
 
-    def apply(self, params, state, x, *, train=False, rng=None):
+    def apply(self, params, state, x, *, train=False, rng=None,
+              remat: bool = False):
+        """``remat``: ``jax.checkpoint`` around each child, so a backward
+        pass keeps one activation a child and recomputes a child's own
+        while it differentiates it (a decoder's block at a time)."""
         new_state = []
         for i, lyr in enumerate(self.layers):
             sub = None
             if rng is not None:
                 rng, sub = jax.random.split(rng)
+            call = functools.partial(lyr.apply, train=train)
+            if remat:
+                call = jax.checkpoint(call)
             with _scope(lyr):
-                x, s = lyr.apply(params[i], state[i], x, train=train,
-                                 rng=sub)
+                x, s = call(params[i], state[i], x, rng=sub)
             new_state.append(s)
         return x, new_state
 

@@ -20,6 +20,8 @@ convs/matmuls with static shapes.
 
 from __future__ import annotations
 
+from typing import Optional
+
 from .layers import (Activation, AvgPool2D, BatchNorm, Conv2D, Dense, Dropout,
                      Embedding, Flatten, GlobalAvgPool2D, LSTM, MaxPool2D,
                      Residual, Sequential)
@@ -263,6 +265,83 @@ def gpt_lm(vocab_size: int = 256, dim: int = 128, num_heads: int = 4,
         layers.append(_ff_block(dim, ff_mult, moe_experts))
     layers += [LayerNorm(), Dense(vocab_size)]
     return Model(Sequential(layers), input_shape=(seq_len,), name="gpt_lm")
+
+
+def decoder_lm(vocab_size: int, hidden_size: int, num_hidden_layers: int,
+               layer_types, num_attention_heads_per_layer,
+               num_key_value_heads: int, head_dim: int,
+               intermediate_size: int, mlp_layer_types, seq_len: int,
+               sliding_window: Optional[int] = None,
+               rope_parameters: Optional[dict] = None,
+               gating: bool = False, rms_norm_eps: float = 1e-6,
+               num_experts: int = 0, num_experts_per_tok: int = 1,
+               moe_intermediate_size: int = 0,
+               shared_expert_intermediate_size: int = 0,
+               moe_routed_scaling_factor: float = 1.0,
+               norm_topk_prob: bool = True,
+               experts_held: Optional[int] = None, first_expert: int = 0,
+               attention_impl: str = "dense") -> Model:
+    """Decoder-only language model of the current kind, built from the
+    per-layer lists a published ``config.json`` gives (the keyword names
+    are that file's): pre-RMSNorm blocks ``h = x + Attn(norm(x))``,
+    ``y = h + FF(norm(h))``, a final RMSNorm and an untied head without
+    bias.  Logits, no softmax (``loss='sparse_categorical_crossentropy'``).
+
+    Layer ``l`` (the first ``num_hidden_layers`` entries of each list):
+    ``layer_types[l]`` is ``"full_attention"`` or ``"sliding_attention"``
+    (causal, a window of ``sliding_window``), with
+    ``num_attention_heads_per_layer[l]`` query heads of ``head_dim`` over
+    ``num_key_value_heads`` K/V heads, rotary as ``rope_parameters[kind]``
+    says (``rope_theta``, ``partial_rotary_factor``, and for
+    ``rope_type: "yarn"`` the YaRN keys) and, with ``gating``, a per-head
+    sigmoid gate; ``mlp_layer_types[l]`` is ``"dense"`` (SwiGLU of width
+    ``intermediate_size``) or ``"sparse"`` (``ops.moe.SparseMoE``: top
+    ``num_experts_per_tok`` of ``num_experts`` SwiGLU experts of width
+    ``moe_intermediate_size``, weights normalised if ``norm_topk_prob``
+    and times ``moe_routed_scaling_factor``, plus a shared expert of
+    width ``shared_expert_intermediate_size``).
+
+    ``experts_held`` / ``first_expert``: one chip's share of an
+    expert-parallel deployment — every sparse layer routes over all
+    ``num_experts`` and holds (has parameters for, computes) only the
+    ``experts_held`` from ``first_expert``; default all."""
+    from ..ops.attention import MultiHeadAttention
+    from ..ops.moe import SparseMoE
+    from .layers import RMSNorm, SwiGLU
+    rope_parameters = rope_parameters or {}
+    layers = [Embedding(vocab_size, hidden_size)]
+    for kind, heads, mlp in list(zip(layer_types,
+                                     num_attention_heads_per_layer,
+                                     mlp_layer_types))[:num_hidden_layers]:
+        if kind not in ("full_attention", "sliding_attention"):
+            raise ValueError(f"unknown layer type {kind!r}")
+        rope = dict(rope_parameters.get(kind, {}))
+        attention = MultiHeadAttention(
+            heads, causal=True, impl=attention_impl,
+            num_kv_heads=num_key_value_heads, head_dim=head_dim, rope=True,
+            window=sliding_window if kind == "sliding_attention" else None,
+            rope_theta=rope.get("rope_theta", 10000.0),
+            rope_fraction=rope.get("partial_rotary_factor", 1.0),
+            rope_scaling=rope or None,
+            gate=gating)
+        if mlp == "dense":
+            ff = SwiGLU(intermediate_size)
+        elif mlp == "sparse":
+            ff = SparseMoE(num_experts, num_experts_per_tok,
+                           moe_intermediate_size,
+                           shared_hidden=shared_expert_intermediate_size,
+                           routed_scale=moe_routed_scaling_factor,
+                           normalise=norm_topk_prob,
+                           experts_held=experts_held,
+                           first_expert=first_expert)
+        else:
+            raise ValueError(f"unknown mlp layer type {mlp!r}")
+        layers.append(Residual(Sequential([RMSNorm(rms_norm_eps),
+                                           attention])))
+        layers.append(Residual(Sequential([RMSNorm(rms_norm_eps), ff])))
+    layers += [RMSNorm(rms_norm_eps), Dense(vocab_size, use_bias=False)]
+    return Model(Sequential(layers), input_shape=(seq_len,),
+                 name="decoder_lm")
 
 
 def draft_lm(target: Model, dim: int = 32, num_heads: int = 2,

@@ -53,22 +53,59 @@ def _log_layout_choice(layout: str, t: int, sp: int) -> None:
         layout, why)
 
 
-def dot_product_attention(q, k, v, *, causal: bool = False):
+def dot_product_attention(q, k, v, *, causal: bool = False, window=None):
     """Scaled dot-product attention.
 
     q: (B, Tq, H, Dh); k/v: (B, Tk, H, Dh) → (B, Tq, H, Dh).
+    ``window`` (causal only): a query sees itself and the ``window - 1``
+    keys before it.
     """
     dh = q.shape[-1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(dh)
     if causal:
         qi = jnp.arange(q.shape[1])[:, None]
         ki = jnp.arange(k.shape[1])[None, :]
-        scores = jnp.where(ki <= qi, scores, jnp.finfo(scores.dtype).min)
+        seen = ki <= qi
+        if window is not None:
+            seen = seen & (qi - ki < window)
+        scores = jnp.where(seen, scores, jnp.finfo(scores.dtype).min)
     weights = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
 
-def apply_rope(x, positions, base: float = 10000.0):
+def rope_frequencies(rotary_dim: int, base: float = 10000.0,
+                     scaling: Optional[dict] = None):
+    """``(inverse frequencies (rotary_dim / 2,) float32, scale)`` of a
+    rotary embedding: plain ``base ** (-2i / rotary_dim)`` and 1.0, or
+    with ``scaling = {"rope_type": "yarn", "factor",
+    "original_max_position_embeddings", "beta_fast", "beta_slow",
+    "attention_factor"}`` the YaRN blend (Peng et al. 2023): pair i keeps
+    its frequency while it turns more than ``beta_fast`` times inside
+    the original context, takes frequency / factor once it turns fewer
+    than ``beta_slow`` times, and a linear ramp between the two pair
+    indices; cos and sin are then multiplied by ``attention_factor``."""
+    import numpy as np
+    half = rotary_dim // 2
+    freqs = base ** (-np.arange(half, dtype=np.float64) / half)
+    if not scaling or scaling.get("rope_type", "default") == "default":
+        return freqs.astype(np.float32), 1.0
+    if scaling["rope_type"] != "yarn":
+        raise ValueError(f"unknown rope_type {scaling['rope_type']!r}")
+    original = scaling["original_max_position_embeddings"]
+
+    def pair_turning(turns):  # the pair that turns this often in `original`
+        return rotary_dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    lo = max(math.floor(pair_turning(scaling["beta_fast"])), 0)
+    hi = min(math.ceil(pair_turning(scaling["beta_slow"])), rotary_dim - 1)
+    ramp = np.clip((np.arange(half) - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    blended = freqs / scaling["factor"] * ramp + freqs * (1.0 - ramp)
+    return blended.astype(np.float32), float(scaling["attention_factor"])
+
+
+def apply_rope(x, positions, base: float = 10000.0, *, inv_freq=None,
+               scale: float = 1.0):
     """Rotary position embedding (RoPE, Su et al. 2021), HALF-SPLIT
     (GPT-NeoX-style) convention: dim i pairs with dim i + Dh/2 — NOT the
     interleaved (2i, 2i+1) layout some implementations use; weights are
@@ -79,21 +116,33 @@ def apply_rope(x, positions, base: float = 10000.0):
     positions (ragged cached decode: each row sits at its own absolute
     position).  Attention scores between RoPE'd q/k depend only on
     RELATIVE position, which is what lets a cached decode
-    rotate-then-store."""
+    rotate-then-store.
+    ``inv_freq`` (n,) (``rope_frequencies``; default: the plain ones of
+    ``base`` over the whole head): rotate the first 2n dims of each head
+    with these frequencies, pair i with i + n, and pass the other dims
+    through (a partial rotary factor); cos and sin are multiplied by
+    ``scale``."""
     dh = x.shape[-1]
-    half = dh // 2
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        inv_freq, _ = rope_frequencies(dh, base)
+    freqs = jnp.asarray(inv_freq, jnp.float32)
+    half = freqs.shape[0]
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (…, T, half)
     if ang.ndim == 2:  # shared positions: broadcast over the batch
         ang = ang[None]
-    cos = jnp.cos(ang)[:, :, None, :].astype(x.dtype)
-    sin = jnp.sin(ang)[:, :, None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin,
-                            x2 * cos + x1 * sin], axis=-1)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    cos = cos[:, :, None, :].astype(x.dtype)
+    sin = sin[:, :, None, :].astype(x.dtype)
+    x1, x2 = x[..., :half], x[..., half:2 * half]
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if 2 * half < dh:  # a partial rotary factor: the rest passes through
+        parts.append(x[..., 2 * half:])
+    return jnp.concatenate(parts, axis=-1)
 
 
-def _flash_with_blocking(q, k, v, causal: bool, t: int):
+def _flash_with_blocking(q, k, v, causal: bool, t: int, window=None):
     """Run the Pallas flash kernel with a block the TPU can tile.
 
     The kernels take whole blocks that are a multiple of 128 or the
@@ -112,7 +161,7 @@ def _flash_with_blocking(q, k, v, causal: bool, t: int):
         # causal lengths that fit VMEM whole run only the tiles at or
         # before the diagonal, inside the kernel; the rest walk the grid
         # in the largest VMEM-fitting blocks dividing T
-        return flash_attention(q, k, v, causal)
+        return flash_attention(q, k, v, causal, None, None, window)
     if not causal:
         raise ValueError(
             f"impl='flash' needs a sequence length with a block-sized "
@@ -122,7 +171,7 @@ def _flash_with_blocking(q, k, v, causal: bool, t: int):
             f"impl='dense'.")
     pad = -t % 128
     padded = [jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v)]
-    return flash_attention(*padded, True)[:, :t]
+    return flash_attention(*padded, True, None, None, window)[:, :t]
 
 
 @register
@@ -142,16 +191,41 @@ class MultiHeadAttention(Layer):
     K/V rotate via ppermute.  Like ``MoEDense.mesh`` this is TRACE-time
     runtime placement: attach before jitting, and it is not part of the
     serialized config.
+
+    What a current decoder's attention adds, each off by default (the
+    defaults build the classic layer, parameter for parameter):
+    ``head_dim`` — a head size that is not dim / heads, so q is
+    ``num_heads * head_dim`` wide and the output projection maps that
+    back to dim; ``window`` — causal sliding-window attention, a query
+    sees itself and the ``window - 1`` keys before it (``impl="flash"``
+    walks the band's blocks only); ``rope_theta``, ``rope_fraction``,
+    ``rope_scaling`` — the rotary base, the leading share of each head
+    that is rotated (the rest passes through), and a YaRN description
+    (``rope_frequencies``); ``gate`` — a per-head sigmoid gate computed
+    from the layer's input, ``(D, H)`` more parameters, on each head's
+    attention output before the output projection.  Window and gate are
+    training-path features: the decode cache does not know them yet.
     """
 
     time_mixing = True  # has its own apply_decode/apply_prefill rules
 
     def __init__(self, num_heads: int, causal: bool = False,
                  impl: str = "dense", num_kv_heads: Optional[int] = None,
-                 rope: bool = False):
+                 rope: bool = False, head_dim: Optional[int] = None,
+                 window: Optional[int] = None, rope_theta: float = 10000.0,
+                 rope_fraction: float = 1.0,
+                 rope_scaling: Optional[dict] = None, gate: bool = False):
         if impl not in ("dense", "flash"):
             raise ValueError(f"impl must be 'dense' or 'flash', got {impl!r}")
         self.num_heads = int(num_heads)
+        self.head_dim = None if head_dim is None else int(head_dim)
+        self.window = None if window is None else int(window)
+        if self.window is not None and not causal:
+            raise ValueError("a sliding window needs causal=True")
+        self.rope_theta = float(rope_theta)
+        self.rope_fraction = float(rope_fraction)
+        self.rope_scaling = dict(rope_scaling) if rope_scaling else None
+        self.gate = bool(gate)
         #: rotary position embeddings applied to q/k inside the layer
         #: (``apply_rope``) — pairs with ``zoo.gpt_lm(positional="rope")``,
         #: which then drops the learned PositionalEmbedding table
@@ -195,39 +269,66 @@ class MultiHeadAttention(Layer):
         return self.num_kv_heads if self.num_kv_heads is not None \
             else self.num_heads
 
+    def _dh(self, d: int) -> int:
+        return self.head_dim if self.head_dim is not None \
+            else d // self.num_heads
+
     def init(self, rng, in_shape):
         t, d = in_shape
-        if d % self.num_heads:
+        if self.head_dim is None and d % self.num_heads:
             raise ValueError(f"model dim {d} not divisible by "
                              f"{self.num_heads} heads")
-        if self.rope and (d // self.num_heads) % 2:
+        dh = self._dh(d)
+        if self.rope and self._rotary_dim(dh) % 2:
             raise ValueError(
-                f"rope=True needs an even head dim, got Dh = "
-                f"{d // self.num_heads} (dim {d} / {self.num_heads} heads)")
+                f"rope=True needs an even rotated head dim, got "
+                f"{self._rotary_dim(dh)} of Dh = {dh}")
         k1, k2 = jax.random.split(rng)
-        dh = d // self.num_heads
+        wide = self.num_heads * dh  # == d in the classic layout
         params = {
-            # one fused projection for ALL head layouts: (D, D + 2·KV·Dh)
-            # degenerates to the classic (D, 3D) when KV == H, so
-            # pre-GQA checkpoints load unchanged and the single
-            # MXU-shaped GEMM is kept under grouping too
-            "qkv": glorot_uniform(k1, (d, d + 2 * self._kv * dh)),
-            "out": glorot_uniform(k2, (d, d)),
+            # one fused projection for ALL head layouts: (D, H·Dh +
+            # 2·KV·Dh) degenerates to the classic (D, 3D) when KV == H
+            # and Dh = D / H, so pre-GQA checkpoints load unchanged and
+            # the single MXU-shaped GEMM is kept under grouping too
+            "qkv": glorot_uniform(k1, (d, wide + 2 * self._kv * dh)),
+            "out": glorot_uniform(k2, (wide, d)),
         }
+        if self.gate:
+            params["gate"] = glorot_uniform(jax.random.fold_in(rng, 2),
+                                            (d, self.num_heads))
         return params, {}, in_shape
 
     def _project(self, params, x):
         """x (B, T, D) → q (B, T, H, Dh), k/v (B, T, KV, Dh) — one fused
-        GEMM, split at [D, D + KV·Dh]."""
+        GEMM, split at [H·Dh, H·Dh + KV·Dh]."""
         b, t, d = x.shape
         h = self.num_heads
         kv = self._kv
-        dh = d // h
-        qkv = x @ params["qkv"].astype(x.dtype)   # (B, T, D + 2·KV·Dh)
-        q = qkv[..., :d].reshape(b, t, h, dh)
-        k = qkv[..., d:d + kv * dh].reshape(b, t, kv, dh)
-        v = qkv[..., d + kv * dh:].reshape(b, t, kv, dh)
+        dh = self._dh(d)
+        wide = h * dh
+        qkv = x @ params["qkv"].astype(x.dtype)   # (B, T, (H + 2·KV)·Dh)
+        q = qkv[..., :wide].reshape(b, t, h, dh)
+        k = qkv[..., wide:wide + kv * dh].reshape(b, t, kv, dh)
+        v = qkv[..., wide + kv * dh:].reshape(b, t, kv, dh)
         return q, k, v
+
+    def _rotary_dim(self, dh: int) -> int:
+        return int(round(dh * self.rope_fraction))
+
+    def _rotate(self, q, k, positions):
+        """q and k with the layer's rotary embedding at ``positions``."""
+        inv_freq, scale = rope_frequencies(
+            self._rotary_dim(q.shape[-1]), self.rope_theta,
+            self.rope_scaling)
+        return (apply_rope(q, positions, inv_freq=inv_freq, scale=scale),
+                apply_rope(k, positions, inv_freq=inv_freq, scale=scale))
+
+    def _no_cache_yet(self):
+        if self.window is not None or self.gate:
+            raise ValueError(
+                "cached decode does not know sliding-window or gated "
+                "attention yet (ROADMAP: window layers in the serving "
+                "cache); generate by full-context recompute")
 
     def _expand_kv(self, k):
         """(B, T, KV, Dh) → (B, T, H, Dh): query groups share K/V heads
@@ -247,12 +348,14 @@ class MultiHeadAttention(Layer):
                     "layer is not supported: per-shard positions need "
                     "global offsets; detach the mesh or use the learned "
                     "PositionalEmbedding")
-            pos = jnp.arange(t)
-            q = apply_rope(q, pos)
-            k = apply_rope(k, pos)
+            with jax.named_scope("rope"):
+                q, k = self._rotate(q, k, jnp.arange(t))
         k = self._expand_kv(k)
         v = self._expand_kv(v)
         if self.mesh is not None:
+            if self.window is not None:
+                raise ValueError("a sliding window over a sequence-sharded "
+                                 "(mesh-attached) layer is not supported")
             from ..parallel.ring import ring_attention_sharded
             # flash layers ring with the fused kernel per hop
             ring_impl = self.ring_impl or (
@@ -278,16 +381,21 @@ class MultiHeadAttention(Layer):
                                        layout=layout or "contiguous",
                                        pre_shuffled=self.ring_pre_shuffled)
         elif self.impl == "flash":
-            o = _flash_with_blocking(q, k, v, self.causal, t)
+            o = _flash_with_blocking(q, k, v, self.causal, t, self.window)
         else:
-            o = dot_product_attention(q, k, v, causal=self.causal)
+            o = dot_product_attention(q, k, v, causal=self.causal,
+                                      window=self.window)
+        if self.gate:
+            with jax.named_scope("gate"):
+                g = jax.nn.sigmoid(x @ params["gate"].astype(x.dtype))
+                o = o * g[..., None]
         with jax.named_scope("out_proj"):
-            o = o.reshape(b, t, d)
+            o = o.reshape(b, t, o.shape[2] * o.shape[3])
             return o @ params["out"].astype(x.dtype), state
 
     def init_cache(self, batch, in_shape):
         t, d = in_shape
-        dh = d // self.num_heads
+        dh = self._dh(d)
         # KV-head-sized: THE GQA memory win — H/kv× smaller than the
         # activations' head count
         shape = (batch, t, self._kv, dh)
@@ -305,20 +413,19 @@ class MultiHeadAttention(Layer):
         meaningful for ``causal=True`` layers."""
         if not self.causal:
             raise ValueError("cached decode requires causal=True attention")
+        self._no_cache_yet()
         b, d = x.shape
         h = self.num_heads
         kv = self._kv
         g = h // kv
-        dh = d // h
+        dh = self._dh(d)
         pos = jnp.asarray(pos)
         per_row = pos.ndim == 1
         q, k, v = self._project(params, x[:, None, :])
         if self.rope:
             # rotate-then-cache: scores depend on relative position only,
             # so rotated keys compose with rotated queries at any later pos
-            p1 = pos[:, None] if per_row else pos[None]
-            q = apply_rope(q, p1)
-            k = apply_rope(k, p1)
+            q, k = self._rotate(q, k, pos[:, None] if per_row else pos[None])
         if per_row:
             # indexed scatter (one (KV, Dh) row per batch element) — the
             # one-hot blend formulation costs a full-buffer
@@ -344,7 +451,7 @@ class MultiHeadAttention(Layer):
         w = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bkgt,btkd->bkgd", w,
                        vc.astype(jnp.float32)).astype(x.dtype)
-        return o.reshape(b, d) @ params["out"].astype(x.dtype), \
+        return o.reshape(b, h * dh) @ params["out"].astype(x.dtype), \
             {"k": kc, "v": vc}
 
     def apply_prefill(self, params, state, x, cache):
@@ -355,12 +462,11 @@ class MultiHeadAttention(Layer):
         overwritten position-by-position as tokens are generated."""
         if not self.causal:
             raise ValueError("cached decode requires causal=True attention")
+        self._no_cache_yet()
         b, t, d = x.shape
         q, k, v = self._project(params, x)
         if self.rope:
-            pos = jnp.arange(t)
-            q = apply_rope(q, pos)
-            k = apply_rope(k, pos)
+            q, k = self._rotate(q, k, jnp.arange(t))
         cache = {"k": k.astype(cache["k"].dtype),
                  "v": v.astype(cache["v"].dtype)}
         k = self._expand_kv(k)
@@ -369,12 +475,16 @@ class MultiHeadAttention(Layer):
             o = _flash_with_blocking(q, k, v, True, t)
         else:
             o = dot_product_attention(q, k, v, causal=True)
-        return o.reshape(b, t, d) @ params["out"].astype(x.dtype), cache
+        o = o.reshape(b, t, o.shape[2] * o.shape[3])
+        return o @ params["out"].astype(x.dtype), cache
 
     def get_config(self):
         return {"num_heads": self.num_heads, "causal": self.causal,
                 "impl": self.impl, "num_kv_heads": self.num_kv_heads,
-                "rope": self.rope}
+                "rope": self.rope, "head_dim": self.head_dim,
+                "window": self.window, "rope_theta": self.rope_theta,
+                "rope_fraction": self.rope_fraction,
+                "rope_scaling": self.rope_scaling, "gate": self.gate}
 
 
 @register

@@ -81,10 +81,20 @@ def _dot_t(a, b):  # a @ b.T, same precision policy as _dot
                            preferred_element_type=jnp.float32)
 
 
-def _causal_mask(qi, kb, block_q, block_k, shape):
+def _causal_mask(qi, kb, block_q, block_k, shape, window=None):
+    """key <= query, and with a ``window`` also query - key < window."""
     q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32, shape, 0)
     k_pos = kb * block_k + lax.broadcasted_iota(jnp.int32, shape, 1)
-    return k_pos <= q_pos
+    if window is None:
+        return k_pos <= q_pos
+    return (k_pos <= q_pos) & (q_pos - k_pos < window)
+
+
+def _band_blocks(window: int, block: int) -> int:
+    """Key blocks a query block of a sliding-window call can see: its
+    own and those the ``window - 1`` keys before its first query reach
+    into (2 at window = block = 512)."""
+    return 1 + -(-(window - 1) // block)
 
 
 # ---------------------------------------------------------------------------
@@ -92,14 +102,19 @@ def _causal_mask(qi, kb, block_q, block_k, shape):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc, *,
-                causal: bool, scale: float, block_q: int, block_k: int):
+                causal: bool, scale: float, block_q: int, block_k: int,
+                window=None):
     """Grid (bh, qi, kb): one K/V block per step; accumulators persist
-    across kb (TPU executes the grid sequentially, minor-most last)."""
+    across kb (TPU executes the grid sequentially, minor-most last).
+    With a ``window`` the last grid axis walks the BAND alone: step j is
+    key block qi - (band - 1) + j (the launcher's index map clamps it),
+    and a step before the sequence's first block does nothing."""
     qi = pl.program_id(1)
-    kb = pl.program_id(2)
+    step = pl.program_id(2)
     n_kb = pl.num_programs(2)
+    kb = step if window is None else qi - (n_kb - 1) + step
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         o_acc[:] = jnp.zeros_like(o_acc)
         m_acc[:] = jnp.full_like(m_acc, _NEG)
@@ -110,7 +125,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc, *,
         # rate with f32 accumulation); softmax statistics always f32
         s = _dot_t(q_ref[0], k_ref[0]) * scale
         if causal:
-            mask = _causal_mask(qi, kb, block_q, block_k, s.shape)
+            mask = _causal_mask(qi, kb, block_q, block_k, s.shape, window)
             s = jnp.where(mask, s, _NEG)
         m_prev = m_acc[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -123,13 +138,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc, *,
             p.astype(v_ref.dtype), v_ref[0])
         m_acc[:, 0] = m_new
 
-    if causal:
+    if window is not None:
+        pl.when(kb >= 0)(_compute)
+    elif causal:
         # skip K/V blocks entirely in the future of this q block
         pl.when(kb * block_k <= qi * block_q + block_q - 1)(_compute)
     else:
         _compute()
 
-    @pl.when(kb == n_kb - 1)
+    @pl.when(step == n_kb - 1)
     def _finalize():
         l = l_acc[:, 0]
         o_ref[0] = (o_acc[:] / l[:, None]).astype(o_ref.dtype)
@@ -142,12 +159,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc, *,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref, dq_ref,
                    dq_acc, *, causal: bool, scale: float, block_q: int,
-                   block_k: int):
+                   block_k: int, window=None):
     qi = pl.program_id(1)
-    kb = pl.program_id(2)
+    step = pl.program_id(2)
     n_kb = pl.num_programs(2)
+    kb = step if window is None else qi - (n_kb - 1) + step  # the band
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
@@ -155,30 +173,37 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref, dq_ref,
         s = _dot_t(q_ref[0], k_ref[0]) * scale
         p = jnp.exp(s - lse_ref[0, 0][:, None])
         if causal:
-            mask = _causal_mask(qi, kb, block_q, block_k, s.shape)
+            mask = _causal_mask(qi, kb, block_q, block_k, s.shape, window)
             p = jnp.where(mask, p, 0.0)
         dp = _dot_t(do_ref[0], v_ref[0])
         ds = p * (dp - dvec_ref[0, 0][:, None]) * scale
         dq_acc[:] = dq_acc[:] + _dot(ds.astype(k_ref.dtype), k_ref[0])
 
-    if causal:
+    if window is not None:
+        pl.when(kb >= 0)(_compute)
+    elif causal:
         pl.when(kb * block_k <= qi * block_q + block_q - 1)(_compute)
     else:
         _compute()
 
-    @pl.when(kb == n_kb - 1)
+    @pl.when(step == n_kb - 1)
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, causal: bool,
-                    scale: float, block_q: int, block_k: int):
+                    scale: float, block_q: int, block_k: int, window=None,
+                    n_q=None):
+    """With a ``window`` the last grid axis walks the band: step j is
+    query block kb + j, and a step past the last of the ``n_q`` query
+    blocks does nothing."""
     kb = pl.program_id(1)
-    qj = pl.program_id(2)
+    step = pl.program_id(2)
     n_qb = pl.num_programs(2)
+    qj = step if window is None else kb + step
 
-    @pl.when(qj == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -187,7 +212,7 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref,
         s = _dot_t(q_ref[0], k_ref[0]) * scale        # (BQ, BK)
         p = jnp.exp(s - lse_ref[0, 0][:, None])
         if causal:
-            mask = _causal_mask(qj, kb, block_q, block_k, s.shape)
+            mask = _causal_mask(qj, kb, block_q, block_k, s.shape, window)
             p = jnp.where(mask, p, 0.0)
         # dV += P^T dO ; dS = P∘(dO V^T − D) ; dK += dS^T Q
         dv_acc[:] = dv_acc[:] + _dot(p.T.astype(do_ref.dtype), do_ref[0])
@@ -195,13 +220,15 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref,
         ds = p * (dp - dvec_ref[0, 0][:, None]) * scale
         dk_acc[:] = dk_acc[:] + _dot(ds.T.astype(q_ref.dtype), q_ref[0])
 
-    if causal:
+    if window is not None:
+        pl.when(qj < n_q)(_compute)
+    elif causal:
         # skip q blocks entirely ABOVE this k block's diagonal
         pl.when(qj * block_q + block_q - 1 >= kb * block_k)(_compute)
     else:
         _compute()
 
-    @pl.when(qj == n_qb - 1)
+    @pl.when(step == n_qb - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -320,13 +347,24 @@ def _bwd_dkv_causal_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref,
 # launchers
 # ---------------------------------------------------------------------------
 
-def _count_tiles(causal, tq, tk, bq, bk, tile, kernels: int) -> None:
+def _count_tiles(causal, tq, tk, bq, bk, tile, kernels: int,
+                 window=None) -> None:
     """The schedule is static, so it is counted where it is built: a
     causal call adds the tile pairs its kernels execute and the tile
     pairs in all, once for each of the ``kernels`` it puts into the
     program, to the default registry's ``flash.causal_tiles_executed`` /
-    ``flash.causal_tiles_total`` (10 and 16 a kernel at T = 1,024)."""
+    ``flash.causal_tiles_total`` (10 and 16 a kernel at T = 1,024); a
+    sliding-window call to ``flash.window_tiles_executed`` /
+    ``flash.window_tiles_total`` instead (31 of 256 at T = 8,192,
+    window 512)."""
     if not causal:
+        return
+    if window is not None:
+        band, n = _band_blocks(window, bq), tq // bq
+        registry = default_registry()
+        registry.counter("flash.window_tiles_executed").inc(
+            kernels * sum(min(band, qi + 1) for qi in range(n)))
+        registry.counter("flash.window_tiles_total").inc(kernels * n * n)
         return
     if tile is not None:
         executed = sum((hi - lo) // tile
@@ -345,6 +383,16 @@ def _whole(t, dh):
     return pl.BlockSpec((1, t, dh), lambda b: (b, 0, 0))
 
 
+def _kv_index(window, band):
+    """Index map of the operand the last grid axis walks in the forward
+    and dQ kernels: step j is block j, or with a ``window`` the band's
+    block i - (band - 1) + j, held at 0 where that falls before the
+    sequence (the kernel skips the step; the block is already there)."""
+    if window is None:
+        return lambda b, i, j: (b, j, 0)
+    return lambda b, i, j: (b, jnp.maximum(i - (band - 1) + j, 0), 0)
+
+
 def _whole_row(t):
     return pl.BlockSpec((1, 1, t), lambda b: (b, 0, 0))
 
@@ -360,11 +408,13 @@ def _whole_row(t):
 #: ``slice-done`` in a 4-block GPT-2-medium step) and the matmul fusions
 #: beside them slow down: 48.28 against 49.88 samples/s on
 #: ``gpt2m-train``, 126.13 against 126.32 on ``gpt2s-train`` (v5e, PR 27)
-_LAUNCHER_STATICS = ("causal", "bq", "bk", "scale", "tile", "interpret")
+_LAUNCHER_STATICS = ("causal", "bq", "bk", "scale", "tile", "interpret",
+                     "window")
 
 
 @functools.partial(jax.jit, static_argnames=_LAUNCHER_STATICS)
-def _flash_fwd_raw(qr, kr, vr, *, causal, bq, bk, scale, tile, interpret):
+def _flash_fwd_raw(qr, kr, vr, *, causal, bq, bk, scale, tile, interpret,
+                   window=None):
     """(BH, Tq, D) + (BH, Tk, D) in → (out (BH,Tq,D), lse (BH,Tq)) via the
     fused kernel.  Rectangular Tq ≠ Tk is the ring's half-block hop shape
     (zigzag schedule); causal requires Tq == Tk (diagonal alignment).
@@ -391,14 +441,16 @@ def _flash_fwd_raw(qr, kr, vr, *, causal, bq, bk, scale, tile, interpret):
             name="flash_fwd",
         )(qr, kr, vr)
     kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale,
-                               block_q=bq, block_k=bk)
+                               block_q=bq, block_k=bk, window=window)
+    band = tk // bk if window is None else _band_blocks(window, bk)
+    kv_spec = pl.BlockSpec((1, bk, dh), _kv_index(window, band))
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, tq // bq, tk // bk),
+        grid=(bh, tq // bq, band),
         in_specs=[
             pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0)),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
@@ -409,14 +461,14 @@ def _flash_fwd_raw(qr, kr, vr, *, causal, bq, bk, scale, tile, interpret):
                         pltpu.VMEM((bq, 128), jnp.float32),
                         pltpu.VMEM((bq, 128), jnp.float32)],
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "window_attn_fwd",
     )(qr, kr, vr)
     return out, lse
 
 
 @functools.partial(jax.jit, static_argnames=_LAUNCHER_STATICS)
 def _flash_bwd_raw(qr, kr, vr, do, lse, dvec, *, causal, bq, bk, scale,
-                   tile, interpret):
+                   tile, interpret, window=None):
     bh, tq, dh = qr.shape
     tk = kr.shape[1]
     dq_shape = jax.ShapeDtypeStruct((bh, tq, dh), qr.dtype)
@@ -446,14 +498,23 @@ def _flash_bwd_raw(qr, kr, vr, do, lse, dvec, *, causal, bq, bk, scale,
         )(kr, vr, qr, do, lse, dvec)
         return dq, dk, dv
 
+    n_q = tq // bq
+    band = None if window is None else _band_blocks(window, bk)
+    kv_spec = pl.BlockSpec((1, bk, dh), _kv_index(window, band))
+    if window is None:
+        def q_walk(b, i, j):  # the dK/dV kernel's q, dO, lse, dvec blocks
+            return j
+    else:
+        def q_walk(b, i, j):  # band: query block i + j, held at the last
+            return jnp.minimum(i + j, n_q - 1)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
-                          block_q=bq, block_k=bk),
-        grid=(bh, tq // bq, tk // bk),
+                          block_q=bq, block_k=bk, window=window),
+        grid=(bh, n_q, band or tk // bk),
         in_specs=[
             pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),  # q
-            pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0)),  # k
-            pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0)),  # v
+            kv_spec,                                               # k
+            kv_spec,                                               # v
             pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),  # do
             pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),   # lse
             pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),   # dvec
@@ -462,20 +523,24 @@ def _flash_bwd_raw(qr, kr, vr, do, lse, dvec, *, causal, bq, bk, scale,
         out_shape=dq_shape,
         scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
         interpret=interpret,
-        name="flash_bwd_dq",
+        name="flash_bwd_dq" if window is None else "window_attn_bwd_dq",
     )(*operands)
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
-                          block_q=bq, block_k=bk),
-        grid=(bh, tk // bk, tq // bq),
+                          block_q=bq, block_k=bk, window=window, n_q=n_q),
+        grid=(bh, tk // bk, band or n_q),
         in_specs=[
             pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, i, 0)),  # k
             pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, i, 0)),  # v
-            pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, j, 0)),  # q
-            pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, j, 0)),  # do
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, j)),   # lse
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, j)),   # dvec
+            pl.BlockSpec((1, bq, dh),
+                         lambda b, i, j: (b, q_walk(b, i, j), 0)),  # q
+            pl.BlockSpec((1, bq, dh),
+                         lambda b, i, j: (b, q_walk(b, i, j), 0)),  # do
+            pl.BlockSpec((1, 1, bq),
+                         lambda b, i, j: (b, 0, q_walk(b, i, j))),  # lse
+            pl.BlockSpec((1, 1, bq),
+                         lambda b, i, j: (b, 0, q_walk(b, i, j))),  # dvec
         ],
         out_specs=[
             pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, i, 0)),
@@ -485,7 +550,7 @@ def _flash_bwd_raw(qr, kr, vr, do, lse, dvec, *, causal, bq, bk, scale,
         scratch_shapes=[pltpu.VMEM((bk, dh), jnp.float32),
                         pltpu.VMEM((bk, dh), jnp.float32)],
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_bwd_dkv" if window is None else "window_attn_bwd_dkv",
     )(kr, vr, qr, do, lse, dvec)
     return dq, dk, dv
 
@@ -540,14 +605,21 @@ def _auto_block(t: int, dh: int) -> int:
     return t
 
 
-def _blocks(q, k, causal, block_q, block_k):
+def _blocks(q, k, causal, block_q, block_k, window=None):
     """(block_q, block_k, tile) for (B, T, H, Dh) operands: the grid
     walk's blocks, and the in-kernel causal walk's tile where it engages
-    (default blocks, causal, one length; see ``_causal_tile``) — else
-    None and the blocks decide."""
+    (default blocks, causal, one length, no window; see
+    ``_causal_tile``) — else None and the blocks decide.  A sliding
+    window keeps the grid walk, over the band's blocks alone."""
     tq, tk, dh = q.shape[1], k.shape[1], q.shape[3]
+    if window is not None and not (causal and tq == tk and window >= 1):
+        raise ValueError(
+            f"a sliding window needs causal self-attention and window >= "
+            f"1, got causal={causal}, lengths ({tq}, {tk}), window="
+            f"{window}")
     tile = None
-    if causal and block_q is None and block_k is None and tq == tk:
+    if causal and block_q is None and block_k is None and tq == tk \
+            and window is None:
         tile = _causal_tile(tq, dh, q.dtype.itemsize)
     if block_q is None:
         block_q = _auto_block(tq, dh)
@@ -557,12 +629,15 @@ def _blocks(q, k, causal, block_q, block_k):
     if tq % bq or tk % bk:
         raise ValueError(f"sequence lengths ({tq}, {tk}) must divide "
                          f"block sizes ({bq}, {bk})")
+    if window is not None and bq != bk:
+        raise ValueError(f"a sliding window walks square blocks, got "
+                         f"({bq}, {bk})")
     return bq, bk, tile
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = False, block_q=None,
-                    block_k=None):
+                    block_k=None, window=None):
     """Pallas flash attention; q/k/v (B, T, H, Dh) → (B, T, H, Dh).
 
     Numerically equal to ``dot_product_attention`` (tested, gradients
@@ -579,15 +654,21 @@ def flash_attention(q, k, v, causal: bool = False, block_q=None,
     dividing T — large blocks are where that walk beats XLA dense (see
     BASELINE.md flash-vs-dense ladder).  Interpret mode is selected
     automatically off TPU.
+    ``window`` (static, causal self-attention only): a query sees the
+    keys at most ``window - 1`` positions before it and itself.  The
+    grid then walks only the key blocks that band touches (2 of 16 at
+    T = 8,192, window 512, 512-blocks), forward, dQ and dK/dV alike,
+    in kernels named ``window_attn_*``.
     """
-    out, _ = _vjp_fwd(q, k, v, causal, block_q, block_k)
+    out, _ = _vjp_fwd(q, k, v, causal, block_q, block_k, window)
     return out
 
 
-def _vjp_fwd(q, k, v, causal, block_q, block_k):
+def _vjp_fwd(q, k, v, causal, block_q, block_k, window=None):
     b, t, h, dh = q.shape
-    bq, bk, tile = _blocks(q, k, causal, block_q, block_k)
-    _count_tiles(causal, t, k.shape[1], bq, bk, tile, kernels=1)
+    bq, bk, tile = _blocks(q, k, causal, block_q, block_k, window)
+    _count_tiles(causal, t, k.shape[1], bq, bk, tile, kernels=1,
+                 window=window)
     scale = 1.0 / math.sqrt(dh)
     # "layout": the (B, T, H, Dh) <-> (BH, T, Dh) transposes around the
     # kernels, named so a trace can charge their copies to attention
@@ -595,21 +676,23 @@ def _vjp_fwd(q, k, v, causal, block_q, block_k):
         qr, kr, vr = _to_bh(q), _to_bh(k), _to_bh(v)
     out, lse = _flash_fwd_raw(qr, kr, vr, causal=causal, bq=bq, bk=bk,
                               scale=scale, tile=tile,
-                              interpret=_interpret())
+                              interpret=_interpret(), window=window)
     with jax.named_scope("layout"):
         out_bthd = _from_bh(out, b, h)
     return out_bthd, (q, k, v, out, lse)
 
 
-def _bwd_impl(causal, block_q, block_k, res, g_out, g_lse=None):
+def _bwd_impl(causal, block_q, block_k, res, g_out, g_lse=None,
+              window=None):
     """Shared backward: ``g_lse`` (the lse cotangent, (B, H, T)) folds
     into the softmax-grad correction term — ∂lse_i/∂s_ij = P_ij lands
     exactly where D_i enters dS = P∘(dP − D), so ``dvec − g_lse`` covers
     it with the kernels unchanged."""
     q, k, v, out_bh, lse = res
     b, t, h, dh = q.shape
-    bq, bk, tile = _blocks(q, k, causal, block_q, block_k)
-    _count_tiles(causal, t, k.shape[1], bq, bk, tile, kernels=2)
+    bq, bk, tile = _blocks(q, k, causal, block_q, block_k, window)
+    _count_tiles(causal, t, k.shape[1], bq, bk, tile, kernels=2,
+                 window=window)
     scale = 1.0 / math.sqrt(dh)
     with jax.named_scope("layout"):
         do = _to_bh(g_out.astype(q.dtype))
@@ -622,15 +705,15 @@ def _bwd_impl(causal, block_q, block_k, res, g_out, g_lse=None):
         qr, kr, vr = _to_bh(q), _to_bh(k), _to_bh(v)
     dq, dk, dv = _flash_bwd_raw(qr, kr, vr, do, lse, dvec, causal=causal,
                                 bq=bq, bk=bk, scale=scale, tile=tile,
-                                interpret=_interpret())
+                                interpret=_interpret(), window=window)
     with jax.named_scope("layout"):
         return (_from_bh(dq, b, h).astype(q.dtype),
                 _from_bh(dk, b, h).astype(k.dtype),
                 _from_bh(dv, b, h).astype(v.dtype))
 
 
-def _vjp_bwd(causal, block_q, block_k, res, g):
-    return _bwd_impl(causal, block_q, block_k, res, g)
+def _vjp_bwd(causal, block_q, block_k, window, res, g):
+    return _bwd_impl(causal, block_q, block_k, res, g, window=window)
 
 
 flash_attention.defvjp(_vjp_fwd, _vjp_bwd)

@@ -32,6 +32,7 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..models.layers import Sequential
 from .mesh import make_mesh, shard_map
 
 Tree = Any
@@ -105,10 +106,13 @@ def make_local_step(model, loss_fn: Callable,
     ``distkeras/workers.py``) as a jit-compiled value_and_grad + optax
     update — the MXU hot loop.
 
-    ``remat=True`` wraps the forward in ``jax.checkpoint``: activations
-    are recomputed during the backward pass instead of living in HBM for
-    the whole step — the standard FLOPs-for-memory trade for models whose
-    activation footprint, not weights, is what OOMs.
+    ``remat=True`` recomputes activations during the backward pass
+    instead of keeping them in HBM for the whole step — the standard
+    FLOPs-for-memory trade for models whose activation footprint, not
+    weights, is what OOMs.  A ``Sequential`` model is checkpointed child
+    by child (``Sequential.apply(remat=True)``: a decoder keeps one
+    activation a block and recomputes one block at a time, which is what
+    lowers the peak); any other layer is wrapped whole.
 
     ``aux_weight > 0`` folds ``aux_weight * Σ state['aux_loss']`` (the
     MoE router load-balance losses) into the objective — the opt-in
@@ -116,16 +120,27 @@ def make_local_step(model, loss_fn: Callable,
     the default keeps the reference-parity task-loss-only behavior.
     """
 
+    by_child = remat and isinstance(model.layer, Sequential)
+
     def forward(params, state, x, rng):
+        if by_child:
+            return model.layer.apply(params, state, x, train=True, rng=rng,
+                                     remat=True)
         return model.layer.apply(params, state, x, train=True, rng=rng)
 
-    if remat:
+    if remat and not by_child:
         forward = jax.checkpoint(forward)
 
     def cast_floats(tree):
-        return jax.tree_util.tree_map(
-            lambda a: a.astype(compute_dtype)
-            if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
+        # leaves under a "router" key stay as they are: a routed layer
+        # scores its experts in float32 (``ops.moe.route_top_k``)
+        def cast(path, a):
+            if not jnp.issubdtype(a.dtype, jnp.floating) or any(
+                    getattr(p, "key", None) == "router" for p in path):
+                return a
+            return a.astype(compute_dtype)
+
+        return jax.tree_util.tree_map_with_path(cast, tree)
 
     def step(carry, batch):
         variables, opt_state, rng = carry

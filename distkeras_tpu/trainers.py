@@ -40,6 +40,7 @@ from .obs import SpanTracer
 from .obs import profile as obs_profile
 from .obs.registry import default_registry
 from .ops.losses import get_loss, probs_loss_variant
+from .ops.moe import routing_stats
 from .ops.optimizers import get_optimizer
 from .parallel import mesh as mesh_lib
 from .parallel.sync import (AdagSync, DownpourSync, DynSgdSync, EasgdSync,
@@ -329,6 +330,14 @@ class Trainer:
             self.trained_variables = jax.tree_util.tree_map(_to_host,
                                                             variables)
         self.model.variables = self.trained_variables
+        # the routed layers' last step, now that their state is on the host
+        stats = routing_stats(self.trained_variables["state"])
+        if stats is not None:
+            registry = default_registry()
+            registry.counter("moe.rows_needed").inc(stats["rows_needed"])
+            registry.counter("moe.rows_run").inc(stats["rows_run"])
+            registry.gauge("moe.expert_load_max_over_mean").set(
+                stats["load_max_over_mean"])
         return self.model
 
     def train(self, dataset: Dataset, shuffle: bool = False,

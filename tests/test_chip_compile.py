@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from distkeras_tpu.ops import pallas_attention
+from distkeras_tpu.ops import pallas_attention, pallas_moe
 
 
 @pytest.fixture(scope="module")
@@ -53,6 +53,7 @@ def mosaic(monkeypatch):
     ``_interpret()`` would pick interpret mode and no kernel would reach
     the TPU compiler."""
     monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_moe, "_interpret", lambda: False)
 
 
 def _compile(fn, *shapes):
@@ -98,6 +99,49 @@ def test_flash_attention_compiles(one_chip, mosaic, shape, dtype, mode):
     fn = attn if mode == "fwd" else jax.grad(_sq_loss(attn),
                                              argnums=(0, 1, 2))
     _compile(fn, *_qkv(one_chip, *shape, dtype))
+
+
+@pytest.mark.parametrize("shape,dtype,mode", [
+    ((1, 8192, 64, 128), "bfloat16", "fwd_bwd"),  # a training step's
+    ((2, 8192, 64, 128), "float32", "fwd"),       # the reference check's
+], ids=["train-bf16", "check-f32"])
+def test_window_attention_compiles(one_chip, mosaic, shape, dtype, mode):
+    """The sliding-window walk at the 8k cell's shape: 64 heads of 128,
+    window 512, 512-blocks, 2 of 16 key blocks a query block."""
+    def attn(q, k, v):
+        return pallas_attention.flash_attention(q, k, v, True, None, None,
+                                                512)
+
+    fn = attn if mode == "fwd" else jax.grad(_sq_loss(attn),
+                                             argnums=(0, 1, 2))
+    text = _compile(fn, *_qkv(one_chip, *shape, dtype))
+    # the instruction's name is what a trace row reads
+    assert "%window_attn_fwd" in text and "%flash_fwd" not in text
+
+
+@pytest.mark.parametrize("dtype,tokens,mode", [
+    ("bfloat16", 8192, "fwd_bwd"), ("float32", 16384, "fwd")],
+    ids=["train-bf16", "check-f32"])
+def test_grouped_matmuls_compile(one_chip, mosaic, dtype, tokens, mode):
+    """The routed experts' row buffer at the 8k cell's shape: 8 choices a
+    token, 32 experts of (2048 x 2 x 512) and (512 x 2048) held here."""
+    rows = tokens * 8 + 32 * pallas_moe.TILE_ROWS
+
+    def shape(*dims, dt=dtype):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dt), sharding=one_chip)
+
+    def experts(x, gate_up, down, tile_expert, num_tiles):
+        h = pallas_moe.grouped_matmul(x, gate_up, tile_expert, num_tiles)
+        a = jax.nn.silu(h[:, :512]) * h[:, 512:]
+        return jnp.sum(pallas_moe.grouped_matmul(
+            a, down, tile_expert, num_tiles).astype(jnp.float32) ** 2)
+
+    fn = experts if mode == "fwd" else jax.grad(experts, argnums=(0, 1, 2))
+    text = _compile(fn, shape(rows, 2048), shape(32, 2048, 1024),
+                    shape(32, 512, 2048),
+                    shape(rows // pallas_moe.TILE_ROWS, dt="int32"),
+                    shape(1, dt="int32"))
+    assert "moe_gmm" in text and ("moe_tgmm" in text) == (mode != "fwd")
 
 
 def test_flash_lse_rectangular_hop_compiles(one_chip, mosaic):

@@ -168,6 +168,14 @@ def test_moe_dense_layer_in_transformer(mesh):
     c, _ = m.layer.apply(m.variables["params"], m.variables["state"], xin)
     np.testing.assert_allclose(np.asarray(c), np.asarray(a), rtol=1e-5,
                                atol=1e-6)
+    # a bf16 step hands the router its float32 master weights beside
+    # bf16 experts: the sharded path still answers in the tokens' dtype
+    ml = moe_layers[0]
+    params, state, _ = ml.init(jax.random.PRNGKey(0), (N, D))
+    half = jax.tree_util.tree_map(lambda w: w.astype(jnp.bfloat16), params)
+    out, _ = ml.apply(dict(half, router=params["router"]), state,
+                      jnp.ones((N, D), jnp.bfloat16))
+    assert out.dtype == jnp.bfloat16
     for ml in moe_layers:
         ml.mesh = None
 
