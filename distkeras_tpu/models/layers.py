@@ -794,20 +794,31 @@ class Sequential(Layer):
         return shape
 
     def apply(self, params, state, x, *, train=False, rng=None,
-              remat: bool = False):
-        """``remat``: ``jax.checkpoint`` around each child, so a backward
-        pass keeps one activation a child and recomputes a child's own
-        while it differentiates it (a decoder's block at a time)."""
+              remat=False):
+        """``remat``: the backward may recompute children to fit.  True,
+        or the step's ``remat.Plan``: each child before the plan's first
+        kept one runs under ``remat.checkpoint`` (its input and its
+        attention kernels' outputs are held, the rest is run again while
+        the child is differentiated); the last child never is, and a plan
+        with a budget keeps whole children from the end backward while
+        their estimate fits (none where the budget is unknown: True
+        alone, or a device that reports no limit)."""
+        calls = [functools.partial(lyr.apply, train=train)
+                 for lyr in self.layers]
+        if remat:
+            from . import remat as plans
+            plan = remat if isinstance(remat, plans.Plan) else plans.Plan()
+            # every child's key has ``rng``'s shape: it stands in for them
+            first_kept = plan.first_kept_of(calls, params, state, x, rng)
+            calls = [plans.checkpoint(call) if i < first_kept else call
+                     for i, call in enumerate(calls)]
         new_state = []
         for i, lyr in enumerate(self.layers):
             sub = None
             if rng is not None:
                 rng, sub = jax.random.split(rng)
-            call = functools.partial(lyr.apply, train=train)
-            if remat:
-                call = jax.checkpoint(call)
             with _scope(lyr):
-                x, s = call(params[i], state[i], x, rng=sub)
+                x, s = calls[i](params[i], state[i], x, rng=sub)
             new_state.append(s)
         return x, new_state
 

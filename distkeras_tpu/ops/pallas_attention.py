@@ -46,6 +46,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..models.remat import name_kernel_outputs, sizing
 from ..obs.registry import default_registry
 
 _NEG = -1e30
@@ -357,7 +358,7 @@ def _count_tiles(causal, tq, tk, bq, bk, tile, kernels: int,
     sliding-window call to ``flash.window_tiles_executed`` /
     ``flash.window_tiles_total`` instead (31 of 256 at T = 8,192,
     window 512)."""
-    if not causal:
+    if not causal or sizing():  # the recompute plan's own trace of a child
         return
     if window is not None:
         band, n = _band_blocks(window, bq), tq // bq
@@ -677,6 +678,9 @@ def _vjp_fwd(q, k, v, causal, block_q, block_k, window=None):
     out, lse = _flash_fwd_raw(qr, kr, vr, causal=causal, bq=bq, bk=bk,
                               scale=scale, tile=tile,
                               interpret=_interpret(), window=window)
+    # what a checkpoint around this call keeps (``models.remat``): the
+    # recomputed forward then needs no kernel; outside one, nothing
+    out, lse = name_kernel_outputs(out, lse)
     with jax.named_scope("layout"):
         out_bthd = _from_bh(out, b, h)
     return out_bthd, (q, k, v, out, lse)

@@ -32,6 +32,7 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..models import remat as remat_plans
 from ..models.layers import Sequential
 from .mesh import make_mesh, shard_map
 
@@ -106,13 +107,18 @@ def make_local_step(model, loss_fn: Callable,
     ``distkeras/workers.py``) as a jit-compiled value_and_grad + optax
     update — the MXU hot loop.
 
-    ``remat=True`` recomputes activations during the backward pass
-    instead of keeping them in HBM for the whole step — the standard
-    FLOPs-for-memory trade for models whose activation footprint, not
-    weights, is what OOMs.  A ``Sequential`` model is checkpointed child
-    by child (``Sequential.apply(remat=True)``: a decoder keeps one
-    activation a block and recomputes one block at a time, which is what
-    lowers the peak); any other layer is wrapped whole.
+    ``remat=True``: the step may recompute activations in the backward
+    pass to fit the device — the FLOPs-for-memory trade for models whose
+    activation footprint, not weights, is what OOMs.  How much is the
+    step's ``models.remat.Plan`` (``step.remat_plan``), decided at trace
+    time from the shapes and the device's memory limit: a ``Sequential``
+    model is checkpointed child by child, the attention kernels' outputs
+    are kept, the last child is not wrapped, and whole children are kept
+    from the end backward while the backward's estimated peak (residuals
+    and gradients) fits the limit less the step's arguments and cast
+    copies (none where the device reports no limit); any other layer is
+    wrapped whole under the same policy.  ``remat=False``: the forward is
+    called as it is.
 
     ``aux_weight > 0`` folds ``aux_weight * Σ state['aux_loss']`` (the
     MoE router load-balance losses) into the objective — the opt-in
@@ -120,16 +126,16 @@ def make_local_step(model, loss_fn: Callable,
     the default keeps the reference-parity task-loss-only behavior.
     """
 
-    by_child = remat and isinstance(model.layer, Sequential)
+    plan = remat_plans.Plan() if remat else None
 
     def forward(params, state, x, rng):
-        if by_child:
-            return model.layer.apply(params, state, x, train=True, rng=rng,
-                                     remat=True)
-        return model.layer.apply(params, state, x, train=True, rng=rng)
-
-    if remat and not by_child:
-        forward = jax.checkpoint(forward)
+        apply = functools.partial(model.layer.apply, train=True)
+        if plan is None:
+            return apply(params, state, x, rng=rng)
+        if isinstance(model.layer, Sequential):
+            return apply(params, state, x, rng=rng, remat=plan)
+        plan.whole_forward()
+        return remat_plans.checkpoint(apply)(params, state, x, rng=rng)
 
     def cast_floats(tree):
         # leaves under a "router" key stay as they are: a routed layer
@@ -155,11 +161,18 @@ def make_local_step(model, loss_fn: Callable,
             # the forward sees compute_dtype copies (covers token-input
             # models too, where no float x exists to derive dtype from —
             # layers cast their weights to the activation dtype)
+            fwd_params = params
             if compute_dtype is not None:
                 with jax.named_scope("cast_params"):
                     fwd_params = cast_floats(params)
-            else:
-                fwd_params = params
+            if plan is not None:
+                # what the step holds from end to end: its arguments
+                # and the cast copies
+                copies = [c for c, p in zip(
+                    jax.tree_util.tree_leaves(fwd_params),
+                    jax.tree_util.tree_leaves(params)) if c is not p]
+                plan.fit(remat_plans.tree_bytes((carry, batch, copies)),
+                         remat_plans.device_limit())
             out, new_state = forward(fwd_params, variables["state"], x, sub)
             with jax.named_scope("loss"):
                 loss_val = loss_fn(out, y)
@@ -177,6 +190,7 @@ def make_local_step(model, loss_fn: Callable,
             params = optax.apply_updates(variables["params"], updates)
         return ({"params": params, "state": new_state}, opt_state, rng), loss_val
 
+    step.remat_plan = plan
     return step
 
 
@@ -198,6 +212,7 @@ def make_window_fn(model, loss_fn, optimizer, compute_dtype=None,
             step, (variables, opt_state, rng), (xs, ys))
         return variables, opt_state, rng, losses
 
+    run.remat_plan = step.remat_plan
     return run
 
 
@@ -377,6 +392,7 @@ class SyncEngine:
         def run(center, local, opt_state, rngs, xs, ys):
             return EpochResult(*mapped(center, local, opt_state, rngs, xs, ys))
 
+        run.remat_plan = self._local_step.remat_plan
         return run
 
     # -- streaming window ---------------------------------------------------
@@ -411,5 +427,6 @@ class SyncEngine:
         def run(center, local, opt_state, rngs, wx, wy):
             return EpochResult(*mapped(center, local, opt_state, rngs, wx, wy))
 
+        run.remat_plan = self._local_step.remat_plan
         return run
 

@@ -36,7 +36,7 @@ import optax
 from .data.dataset import Dataset
 from .models.layers import Activation, Dense, Sequential
 from .models.model import Model
-from .obs import SpanTracer
+from .obs import SpanTracer, get_logger
 from .obs import profile as obs_profile
 from .obs.registry import default_registry
 from .ops.losses import get_loss, probs_loss_variant
@@ -171,10 +171,15 @@ class Trainer:
         #: the activation dtype at use, so matmuls/convs hit the MXU in
         #: e.g. bfloat16 while the master copy keeps full precision).
         self.compute_dtype = _resolve_dtype(compute_dtype)
-        #: rematerialization (jax.checkpoint around the forward): trade
-        #: recompute FLOPs for activation HBM — for deep models whose
+        #: rematerialization: the step MAY recompute activations in its
+        #: backward pass to fit the device — for deep models whose
         #: activations, not weights, are what OOMs (SURVEY.md §7 /
-        #: scaling-book memory recipe)
+        #: scaling-book memory recipe).  How much is not the user's to
+        #: say: ``models.remat.Plan`` decides it where the step is traced,
+        #: from the shapes and the device's memory limit (the attention
+        #: kernels' outputs are kept, the last child is not wrapped, whole
+        #: children are kept from the end backward while they fit), and
+        #: ``_fit_remat`` holds the compiled program against that limit
         self.remat = bool(remat)
         #: opt-in MoE router load-balance weight: folds
         #: ``aux_weight * Σ state['aux_loss']`` into the objective
@@ -286,10 +291,47 @@ class Trainer:
                                   ) as record:
                 before = obs_profile.compile_totals()
                 try:
+                    self._fit_remat(run, args, record)
                     return run(*args)
                 finally:
                     record.update(obs_profile.compile_spent(before))
         return wrapped
+
+    def _fit_remat(self, run, args, record: dict) -> None:
+        """The judge of a program that may recompute (``run.remat_plan``,
+        ``models.remat``): the cold call compiles it before it runs it
+        (the call then finds trace, lowering and executable cached: no
+        second of any), puts the program's own size (arguments + outputs
+        − aliased + temporaries, as the compiler counts them) beside the
+        plan's estimate on the ``jit_compile`` record, and where that
+        passes ``remat.REFUSE`` of the device's limit, or XLA refuses the
+        program for memory, has the plan recompute one child more and
+        compiles again."""
+        plan = getattr(run, "remat_plan", None)
+        if plan is None:
+            return
+        plan.tracer = self.tracer
+        while True:
+            try:
+                mem = run.lower(*args).compile().memory_analysis()
+            except jax.errors.JaxRuntimeError as e:
+                if "RESOURCE_EXHAUSTED" not in str(e) \
+                        or not plan.step_back():
+                    raise
+                why = f"XLA refused the program: {str(e)[:200]}"
+            else:
+                size = 0 if mem is None else (
+                    mem.argument_size_in_bytes + mem.output_size_in_bytes
+                    - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+                if not plan.judge(size):
+                    record.update(plan.record())
+                    return
+                why = f"the program is {size} B of the device's {plan.limit}"
+            get_logger("trainers").warning(
+                "remat: %s; compiling again with %d of %d children "
+                "recomputed", why, plan.first_kept, plan.children)
+            # every trace, the scanned step's too, is made again
+            jax.clear_caches()
 
     def _profiled_run(self, run, epoch: int, *args):
         """One epoch-program call, optionally under a per-epoch

@@ -153,8 +153,10 @@ def test_window_schedule_is_counted_and_named():
     # 8 query blocks, a band of 3 blocks: 1 + 2 + 6 x 3 = 21 of 64, x 3
     assert [c.value - b for c, b in zip(counters, before)] == [63, 192]
     import re
+    from distkeras_tpu.models.remat import KERNEL_OUTPUTS
     kernels = {n for n in re.findall(r"name=(\w+)", text)
-               if not n.startswith("_")}  # the pallas_calls, not the jits
+               if not n.startswith("_")  # the pallas_calls, not the jits
+               and n not in KERNEL_OUTPUTS}  # nor their outputs' tags
     assert kernels == {"window_attn_fwd", "window_attn_bwd_dq",
                        "window_attn_bwd_dkv"}
     # the readers of the full-attention kernels go by these names
@@ -301,7 +303,9 @@ def test_remat_checkpoints_a_sequential_child_by_child():
                     jax.tree_util.tree_leaves(jax.grad(loss(False))(params))):
         np.testing.assert_allclose(a, b, rtol=1e-6)
     text = str(jax.make_jaxpr(jax.grad(loss(True)))(params))
-    assert text.count("remat2") >= 3  # jax.checkpoint's primitive
+    # jax.checkpoint's primitive around the first two children: the last
+    # child is never wrapped
+    assert text.count("remat2") == 2
     assert "remat2" not in str(jax.make_jaxpr(jax.grad(loss(False)))(
         params))
 
