@@ -130,7 +130,7 @@ def epoch_records(trainer) -> list:
 
 def scoped_registry(trainer):
     """A registry of this trainer's own, with the retrace sentinel's
-    counters pre-created (bench.py's recipe)."""
+    counters pre-created (0 is present, not missing)."""
     from distkeras_tpu.obs import Registry
     reg = Registry()
     reg.counter("jit.compiles")

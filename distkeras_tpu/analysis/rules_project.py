@@ -15,8 +15,8 @@ call, inheritance and configuration edges:
 * ``metric-contract`` — cross-checks the three places a metric name
   lives: creation sites in code (``registry.counter/gauge/histogram``
   literals and f-strings, span names), the drift-gate config
-  (``OBS_BASELINE.json`` per-metric thresholds / ignore list /
-  snapshot files) and the ``scripts/obsview.py`` renderers.  A
+  (``OBS_BASELINE.json`` per-metric thresholds / ignore list) and the
+  ``scripts/obsview.py`` renderers.  A
   threshold that matches no creation site gates nothing; a renderer
   read nobody emits renders a permanent blank; an exactly-gated counter
   created on first use violates the "0 is present, not missing"
@@ -327,7 +327,7 @@ class MetricContractRule(ProjectRule):
     #: The package itself is listed so a partial scan (``--changed``, a
     #: subdirectory) still sees every creation site — otherwise metrics
     #: created outside the scanned subset would all read as "dead".
-    _AUX = ("distkeras_tpu", "bench.py", "scripts")
+    _AUX = ("distkeras_tpu", "scripts")
 
     def check_project(self, graph: ProjectGraph) -> List[Finding]:
         root = self._repo_root(graph)
@@ -526,12 +526,6 @@ class MetricContractRule(ProjectRule):
                 rel, baseline_lines, f'"{pattern}"',
                 f"dead ignore entry: '{pattern}' matches no metric "
                 f"creation site — it hides nothing"))
-        for mode, fname in baseline.get("snapshots", {}).items():
-            if not os.path.isfile(os.path.join(root, fname)):
-                findings.append(self._file_finding(
-                    rel, baseline_lines, f'"{fname}"',
-                    f"snapshot file '{fname}' (mode '{mode}') does not "
-                    f"exist — the drift gate for that bench is vacuous"))
 
     def _check_alerts(self, root, baseline_path, baseline,
                       baseline_lines, sites, findings) -> None:

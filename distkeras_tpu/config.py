@@ -2,11 +2,11 @@
 
 The reference configures everything through trainer constructor kwargs
 (``distkeras/trainers.py`` — no config files, no flags); that stays our
-API.  This module is the one layer on top the survey prescribes for the
-benchmark harness: a ``RunConfig`` dataclass, YAML loading, and a CLI so a
-single checked-in file reproduces a whole benchmark table
-(``configs/bench_all.yaml`` ↔ ``scripts/bench_all.py``) or packages the
-same run as a deployable ``Job``.
+API.  This module is the one layer on top the survey prescribes: a
+``RunConfig`` dataclass, YAML loading, and a CLI so a single checked-in
+file runs a whole table of configurations (``python -m
+distkeras_tpu.config configs/bench_all.yaml``) or packages the same run
+as a deployable ``Job``.
 
 YAML shape (one mapping per run; a top-level ``configs:`` list holds
 several)::

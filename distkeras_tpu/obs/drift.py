@@ -1,9 +1,8 @@
 """Cross-run drift detection over registry snapshots (ISSUE 5 tentpole).
 
-The benchmark harness persists obs registry snapshots beside its wall-clock
-rows (``BENCH_PS_OBS.json`` / ``BENCH_TRAINER_OBS.json``) precisely so runs
-can be compared as *distributions*, not single numbers (the BASELINE
-round-5 host-contention bias).  This module is the comparator:
+A run that persists its obs registry snapshots (``Registry.snapshot()``
+documents, one named registry a part) can be compared with another as
+*distributions*, not single numbers.  This module is the comparator:
 
 * **counters** — relative delta ``|cand − base| / base`` against a
   ``counter_rel`` threshold (commit/pull/byte counts are deterministic for
@@ -24,13 +23,11 @@ The baseline file schema (``dktpu-obs-baseline/v1``)::
      "thresholds": {"counter_rel": 0.25, "psi": 0.25, ...},
      "metrics":   {"*rtt_seconds": {"psi": 1.5, "p50_factor": 10}},
      "ignore":    ["*encode_seconds"],
-     "snapshots": {"ps_bench": "BENCH_PS_OBS.json",
-                   "trainer_bench": "BENCH_TRAINER_OBS.json"}}
+     "alerts":    [...]}
 
-``snapshots`` names the committed baseline file per bench mode —
-``bench.py`` diffs a fresh run against it before overwriting, and
-``scripts/obsview.py --diff A B`` exposes the same comparison as a CLI
-(exit 0 clean / 1 drift / 2 usage error) for CI.
+``scripts/obsview.py --diff A B`` exposes the comparison as a CLI (exit 0
+clean / 1 drift / 2 usage error) for CI; ``alerts`` holds the live rules
+of ``obs/alerts.py``.
 
 ISSUE 8 adds the **windowed diff** over a rolling window of snapshots
 from ONE live run (the continual-training deploy gate): cumulative
@@ -143,7 +140,7 @@ class _Thresholds:
         # overrides an earlier one, so specificity is expressed by
         # writing broad patterns first (JSON object order is preserved).
         # Lexical sorting could never let a part-scoped pattern like
-        # "scenario_*/serve.*" override an exact "serve.*" name.
+        # "fleet_*/serve.*" override an exact "serve.*" name.
         for pat in self.per_metric:
             if any(fnmatch.fnmatch(n, pat) for n in names):
                 th.update(self.per_metric[pat])

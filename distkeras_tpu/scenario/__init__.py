@@ -7,8 +7,7 @@ shared-prefix mix) or replays recorded JSONL traces; ``runner`` fires
 them open-loop at the serve fleet with exact three-way accounting;
 ``slo`` turns the fleet's own ``serve.*`` histograms into per-phase
 attainment/shed/goodput verdicts; ``autoscale`` grows and shrinks
-engines behind the router from those same signals.  One entry point:
-``bench.py --scenario NAME``.
+engines behind the router from those same signals.
 """
 
 from .autoscale import AutoscalePolicy, AutoScaler, Signals

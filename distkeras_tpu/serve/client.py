@@ -10,8 +10,8 @@ admission controller load-shed — an OPERATIONAL outcome the caller
 handles, not an exception) or ``error`` (a malformed request).  The
 client observes its own SLO view: ``serve.client.e2e_seconds`` per
 generate round-trip, ``serve.client.requests`` / ``serve.client.rejected``
-counters — the load-generator side of ``bench.py --serve`` merges these
-per-thread registries into the persisted snapshot.
+counters — a load generator merges its threads' registries
+(``Registry.merge_snapshots``).
 
 ``stats()`` transparently reconnects-and-retries once (idempotent read);
 ``generate`` does NOT auto-retry — the server may have admitted (and be

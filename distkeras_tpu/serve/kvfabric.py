@@ -46,8 +46,8 @@ Metrics land in the ROUTER registry: counters
 ``serve.router.kv_replications`` / ``kv_migrations`` /
 ``kv_push_bytes`` / ``kv_refused_stale``, plus the spill TTFT split
 (``serve.router.ttft_spill_warm_seconds`` / ``ttft_spill_cold_seconds``)
-the router's forward path attributes — the proof pair ``bench.py
---serve`` and the ``obsview`` COLD-SPILL alarm read.
+the router's forward path attributes — the pair the ``obsview``
+COLD-SPILL alarm reads.
 """
 
 from __future__ import annotations

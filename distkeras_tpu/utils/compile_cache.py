@@ -4,8 +4,9 @@ The cache directory is part of the cache key, so it must be the same
 path on every run.  ``JAX_COMPILATION_CACHE_DIR`` places it from outside
 (JAX reads the variable itself — nothing is set in code then); otherwise
 it is ``<checkout>/.jax_cache`` (git-ignored).  Called by the entry
-points that compile for the device (``chip_smoke.py``, ``bench.py``, the
-``scripts/`` benches, ``ps.worker_main``), never at package import.
+points that compile for the device (``benchmark/run.py``,
+``chip_smoke.py``, the ``scripts/`` tools, ``ps.worker_main``), never at
+package import.
 """
 
 from __future__ import annotations

@@ -161,7 +161,8 @@ def profile_trace(log_dir: str):
 class StepTimer:
     """Honest step timing: ``mark()`` between steps; ``rate(samples)``
     reports samples/sec.  Callers are responsible for a hard sync (e.g. a
-    scalar readback) before ``mark`` — see bench.py's methodology note."""
+    scalar readback) before ``mark``: JAX returns before the device is
+    done, so a lap without one times the enqueue."""
 
     def __init__(self):
         self.t0 = time.perf_counter()

@@ -39,10 +39,10 @@ per worker, server applies nested under the worker commits that caused
 them (the PR 5 wire-carried trace context drawn as flow arrows),
 heartbeats as instants, ``live_bytes`` watermarks as counter tracks.
 
-The file mode also accepts a persisted registry-snapshot JSON (the
-``BENCH_PS_OBS.json`` / ``BENCH_TRAINER_OBS.json`` that ``bench.py``
-writes at the repo root): per-registry instrument tables plus the
-commit-codec accounting (compression ratio, bytes saved — ISSUE 4).
+The file mode also accepts a persisted registry-snapshot JSON (named
+``Registry.snapshot()`` parts beside an optional ``config``):
+per-registry instrument tables plus the commit-codec accounting
+(compression ratio, bytes saved — ISSUE 4).
 
 Everything renders through pure functions over plain records
 (``summarize`` / ``summarize_stats``) so tests — and notebooks — can call
@@ -605,9 +605,8 @@ _is_registry_snapshot = drift.is_registry_snapshot
 
 
 def summarize_snapshot(doc: dict) -> str:
-    """Summary of a persisted registry-snapshot file (the
-    ``BENCH_PS_OBS.json`` bench_ps writes at the repo root): one
-    section per component registry, codec accounting surfaced."""
+    """Summary of a persisted registry-snapshot file: one section per
+    component registry, codec accounting surfaced."""
     sections = []
     if isinstance(doc.get("config"), dict):
         sections.append(["== Config ==",
@@ -1026,7 +1025,7 @@ def summarize_continual(stats: dict, verdicts=None,
                         source: str = "live") -> str:
     """Continual-loop summary (ISSUE 8): deploy history, window-verdict
     tally (with the per-interval table when the decision log is
-    available — the persisted ``BENCH_CONTINUAL_OBS.json`` carries it),
+    available — a persisted document carries it under ``verdicts``),
     training-health histograms, and the two alarms: DRIFT-DIRTY (the
     current window classifies step/trend — deploys blocked) and
     RETRACING (the serve health check's sentinel rule)."""
@@ -1095,7 +1094,7 @@ def summarize_continual(stats: dict, verdicts=None,
 def run_continual(target: str) -> int:
     """``--continual`` body: live HOST:PORT (the decode service's
     ``stats`` RPC — a trainer sharing the engine's registry shows up in
-    the same snapshot) or a persisted ``BENCH_CONTINUAL_OBS.json``."""
+    the same snapshot) or a persisted registry-snapshot document."""
     host, _, port = target.rpartition(":")
     if host and port.isdigit():
         reply = poll_serve(host, int(port))
@@ -1124,8 +1123,8 @@ def run_continual(target: str) -> int:
 
 
 def summarize_scenario(doc: dict, source: str) -> str:
-    """Scenario-harness panel (ISSUE 17) over a persisted
-    ``BENCH_SCENARIO_OBS.json``: one per-phase SLO table + scale-event
+    """Scenario-harness panel (ISSUE 17) over a persisted document
+    with a ``row.scenarios`` table: one per-phase SLO table + scale-event
     trail per scenario, the open-loop accounting identity, and the
     SLO-MISS alarm for any phase whose attainment landed under the
     committed target."""
@@ -1234,7 +1233,7 @@ def summarize_scenario_live(reply: dict, target: str) -> str:
 
 def run_scenario(target: str) -> int:
     """``--scenario`` body: live HOST:PORT (a ``ServeRouter`` or engine
-    stats RPC) or a persisted ``BENCH_SCENARIO_OBS.json``."""
+    stats RPC) or a persisted ``row.scenarios`` document."""
     host, _, port = target.rpartition(":")
     if host and port.isdigit():
         reply = poll_serve(host, int(port))
@@ -1448,8 +1447,8 @@ def run_diff(base: str, cand: str, thresholds=None) -> int:
                     baseline = drift.load_baseline(found)
                 except (OSError, ValueError) as e:
                     # auto-discovered config: degrade to defaults with a
-                    # note (same policy as bench.py) — an unrelated bad
-                    # file must not fail every diff of valid snapshots
+                    # note — an unrelated bad file must not fail every
+                    # diff of valid snapshots
                     emit(f"obsview --diff: ignoring invalid {found} "
                          f"({e}); using default thresholds", err=True)
         report = drift.diff_files(base, cand, baseline=baseline)
@@ -1492,12 +1491,12 @@ def main(argv=None) -> int:
                     help="continual-loop view (ISSUE 8): HOST:PORT polls "
                          "a live decode service whose registry the "
                          "continual trainer shares; a file path reads a "
-                         "persisted BENCH_CONTINUAL_OBS.json (window "
+                         "persisted registry-snapshot document (window "
                          "verdicts, deploy history, stream lag, "
                          "DRIFT-DIRTY/RETRACING alarms)")
     ap.add_argument("--scenario", metavar="TARGET",
                     help="scenario-harness view (ISSUE 17): a file path "
-                         "reads a persisted BENCH_SCENARIO_OBS.json "
+                         "reads a persisted row.scenarios document "
                          "(per-phase SLO table, scale-event trail, "
                          "SLO-MISS alarm); HOST:PORT polls a live "
                          "decode service and renders the autoscaler's "

@@ -272,20 +272,17 @@ def test_metric_contract_flags_dead_threshold(tmp_path):
     assert found[0].line > 1  # anchored at the pattern's own line
 
 
-def test_metric_contract_flags_dead_ignore_and_missing_snapshot(tmp_path):
+def test_metric_contract_flags_dead_ignore(tmp_path):
     root = _mini_repo(
         tmp_path,
-        {"metrics": {}, "ignore": ["pkg.ghost"],
-         "snapshots": {"quick": "BENCH_QUICK.json"}},
+        {"metrics": {}, "ignore": ["pkg.ghost"]},
         """
         def build(registry):
             return registry.counter("pkg.live")
         """)
     msgs = [f.message for f in _metric_findings(root)]
-    assert any("dead ignore entry" in m and "pkg.ghost" in m for m in msgs)
-    assert any("BENCH_QUICK.json" in m and "does not exist" in m
-               for m in msgs)
-    assert len(msgs) == 2
+    assert len(msgs) == 1
+    assert "dead ignore entry" in msgs[0] and "pkg.ghost" in msgs[0]
 
 
 def test_metric_contract_flags_dead_renderer_read(tmp_path):

@@ -160,9 +160,8 @@ def test_flash_lse_rectangular_hop_compiles(one_chip, mosaic):
 
 @pytest.mark.parametrize("t", [200, 544])
 def test_causal_awkward_length_compiles(one_chip, mosaic, t):
-    """T=200 / T=544 (``bench.py``'s SERVE_FABRIC_PHASE seq_len) have no
-    128-aligned divisor; Mosaic refused the 100- and 68-wide blocks the
-    old rule handed it.  The causal route pads to a multiple of 128."""
+    """T=200 / T=544 have no 128-aligned divisor; Mosaic refused the
+    100- and 68-wide blocks the old rule handed it.  The causal route pads to a multiple of 128."""
     from distkeras_tpu.ops.attention import _flash_with_blocking
 
     def attn(q, k, v):

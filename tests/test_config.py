@@ -10,11 +10,11 @@ from distkeras_tpu import config as cfg_mod
 from distkeras_tpu.config import RunConfig
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_YAML = os.path.join(ROOT, "configs", "bench_all.yaml")
+SAMPLE_YAML = os.path.join(ROOT, "configs", "bench_all.yaml")
 
 
 def test_bench_yaml_loads_all_configs():
-    cfgs = cfg_mod.load_file(BENCH_YAML)
+    cfgs = cfg_mod.load_file(SAMPLE_YAML)
     # five BASELINE configs + LM config + distributed-streaming row +
     # streaming variant of #5
     assert len(cfgs) == 8

@@ -11,7 +11,6 @@ import copy
 import importlib.util
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -339,6 +338,15 @@ def test_e2e_continual_deploys_into_live_engine_drift_gated(lm):
         sum(1 for e in log if e["deployed"])
     assert snap["serve.promotions"]["value"] == \
         snap["continual.deploys"]["value"]
+    # every interval closed, was judged once and ended in a deploy or a
+    # recorded rejection
+    assert snap["continual.intervals"]["value"] == 16
+    assert snap["continual.windows"]["value"] == 16 * 4
+    assert sum(snap[f"continual.verdicts_{k}"]["value"]
+               for k in drift.WINDOW_KINDS) == 16
+    assert snap["continual.deploys"]["value"] + \
+        snap["continual.deploys_rejected"]["value"] == 16
+    assert snap["continual.stream_lag_seconds"]["count"] > 0
 
     # retrace contract under the committed zero-tolerance rule
     assert snap["jit.retraces"]["value"] == 0
@@ -512,64 +520,8 @@ def test_daemon_start_stop_trains_until_stopped(lm):
 
 
 # ---------------------------------------------------------------------------
-# bench.py --continual + obsview --continual
+# obsview --continual
 # ---------------------------------------------------------------------------
-
-def test_bench_continual_emits_row_and_self_checks(tmp_path, monkeypatch):
-    if _ROOT not in sys.path:
-        sys.path.insert(0, _ROOT)
-    import bench
-    monkeypatch.setattr(
-        bench, "_baseline_snapshot_path",
-        lambda cfg, key, default: str(tmp_path / default))
-    kw = dict(intervals=4, snapshot_every=2, window=2, batch=8,
-              history=2, min_history=1, drift_interval=2,
-              out_dir=str(tmp_path), vocab=VOCAB, dim=16, heads=2,
-              blocks=1, seq_len=SEQ)
-    row = bench.bench_continual(**kw)
-    assert row["mode"] == "bench_continual"
-    assert row["jit_retraces"] == 0
-    assert row["windows"] == 4 * 2
-    assert sum(row["verdicts"].values()) == 4  # every interval judged
-    assert row["deploys"] + row["deploys_rejected"] == 4
-    assert row["obs_drift"] == {"checked": False,
-                                "reason": "no baseline snapshot"}
-    snap_path = tmp_path / "BENCH_CONTINUAL_OBS.json"
-    assert snap_path.exists()
-    with open(snap_path) as f:
-        doc = json.load(f)
-    assert doc["config"]["intervals"] == 4
-    assert doc["continual"]["jit.retraces"]["value"] == 0
-    assert doc["continual"]["continual.intervals"]["value"] == 4
-    assert len(doc["verdicts"]) == 4
-    assert doc["continual"]["continual.stream_lag_seconds"]["count"] > 0
-
-    row2 = bench.bench_continual(**kw)
-    assert row2["obs_drift"]["checked"] is True
-
-
-def test_committed_continual_snapshot_matches_baseline_contract():
-    """The committed BENCH_CONTINUAL_OBS.json records BOTH halves of the
-    loop's contract: drift-clean deploys happened AND the injected dirty
-    window was rejected — at zero retraces."""
-    path = os.path.join(_ROOT, "BENCH_CONTINUAL_OBS.json")
-    assert os.path.exists(path), "bench.py --continual snapshot not committed"
-    with open(path) as f:
-        doc = json.load(f)
-    assert doc["config"]["mode"] == "bench_continual"
-    assert drift.is_registry_snapshot(doc["continual"])
-    snap = doc["continual"]
-    assert snap["jit.retraces"]["value"] == 0
-    assert snap["continual.deploys"]["value"] >= 1
-    assert snap["continual.rejected_dirty"]["value"] >= 1
-    assert snap["continual.loss"]["count"] > 0
-    assert doc["verdicts"], "window-verdict log missing"
-    assert any(e["deployed"] for e in doc["verdicts"])
-    assert any(e["kind"] == "step" for e in doc["verdicts"])
-    with open(os.path.join(_ROOT, "OBS_BASELINE.json")) as f:
-        bl = json.load(f)
-    assert bl["snapshots"]["continual_bench"] == "BENCH_CONTINUAL_OBS.json"
-
 
 def _load_obsview():
     spec = importlib.util.spec_from_file_location(
@@ -579,10 +531,25 @@ def _load_obsview():
     return mod
 
 
-def test_obsview_continual_renders_offline_and_alarms(capsys):
+def test_obsview_continual_renders_offline_and_alarms(capsys, tmp_path):
+    # a persisted document of the shape the panel reads: one named
+    # registry beside the gate's verdict log
+    reg = Registry()
+    reg.counter("continual.intervals").inc(2)
+    reg.counter("continual.deploys").inc(1)
+    reg.counter("jit.retraces")
+    reg.histogram("continual.loss", LOSS_BUCKETS).observe(2.5)
+    reg.histogram("continual.stream_lag_seconds").observe(1e-4)
+    doc = {"config": {"mode": "continual"}, "continual": reg.snapshot(),
+           "verdicts": [
+               {"interval": 1, "kind": "stable", "deploy": False,
+                "deployed": False, "reason": "warmup (1/2 intervals)"},
+               {"interval": 2, "kind": "stable", "deploy": True,
+                "deployed": True, "reason": "drift-clean"}]}
+    path = tmp_path / "continual_obs.json"
+    path.write_text(json.dumps(doc))
     obsview = _load_obsview()
-    rc = obsview.run_continual(os.path.join(_ROOT,
-                                            "BENCH_CONTINUAL_OBS.json"))
+    rc = obsview.run_continual(str(path))
     out = capsys.readouterr().out
     assert rc == 0
     assert "Continual training" in out

@@ -282,12 +282,17 @@ def test_stats_rpc_matches_ground_truth(devices):
 
 
 def test_stats_rpc_while_commits_in_flight():
-    """STATS is answerable mid-run: concurrent committers + a poller."""
+    """STATS is answerable mid-run: concurrent committers + a poller.
+    Each committer keeps a registry of its own; merged, they count every
+    RPC of the fleet once."""
     ps = DeltaParameterServer(_tree([0.0]), num_workers=4)
     replies = []
+    regs = [Registry() for _ in range(4)]
     with SocketParameterServer(ps) as server:
         def hammer(k):
-            with PSClient("127.0.0.1", server.port, k) as c:
+            with PSClient("127.0.0.1", server.port, k,
+                          registry=regs[k]) as c:
+                c.pull()
                 for _ in range(20):
                     c.commit(_tree([1.0]))
         ts = [threading.Thread(target=hammer, args=(k,)) for k in range(4)]
@@ -301,6 +306,8 @@ def test_stats_rpc_while_commits_in_flight():
     assert 0 <= mid["stats"]["ps.commits"]["value"] <= 80
     assert final["stats"]["ps.commits"]["value"] == 80
     assert final["num_updates"] == 80
+    merged = Registry.merge_snapshots(*(r.snapshot() for r in regs))
+    assert merged["ps.client.rtt_seconds"]["count"] == 4 * (1 + 20)
 
 
 def test_client_reconnect_counter():

@@ -335,7 +335,7 @@ def test_pipeline_trainer_mixed_precision():
 def test_pipeline_tick_count_is_gpipe_schedule(mesh):
     """The compiled schedule is exactly GPipe: the scan runs M + S − 1
     ticks (the (S−1) extra are the fill/drain bubble, quantified in
-    BASELINE.md via scripts/pp_bubble_bench.py)."""
+    BASELINE.md by a probe script since removed)."""
     from distkeras_tpu.parallel.pipeline import (pipeline_apply_sharded,
                                                  stack_stage_params)
     S = 4

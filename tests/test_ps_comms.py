@@ -379,95 +379,6 @@ def test_quantized_downpour_converges(ds, codec):
     assert a_q > 0.7, (codec, a_q)
 
 
-# -- bench_ps + obsview tooling ---------------------------------------------
-
-def test_bench_ps_emits_row_and_snapshot(tmp_path):
-    sys.path.insert(0, ROOT)
-    try:
-        import bench
-    finally:
-        sys.path.remove(ROOT)
-    row = bench.bench_ps(codec="int8", windows=4, mb=0.25,
-                         out_dir=str(tmp_path))
-    assert row["mode"] == "bench_ps"
-    assert row["commit_rtt_ms_p50"] > 0
-    assert row["wire_bytes_per_window"] > 0
-    assert row["compression_ratio"] > 3
-    assert row["wire_version"] == 2
-    json.dumps(row)  # the printed line is valid JSON
-    snap_file = tmp_path / "BENCH_PS_OBS.json"
-    assert snap_file.exists()
-    doc = json.loads(snap_file.read_text())
-    assert doc["client"]["ps.codec.bytes_saved"]["value"] > 0
-    assert doc["server"]["ps.commits"]["value"] == 4
-
-
-def test_bench_ps_contention_sweep_merges_snapshots(tmp_path):
-    """--ps-workers sweep point (ISSUE 5 satellite): N concurrent clients,
-    ONE merged client registry snapshot per point, named per point."""
-    sys.path.insert(0, ROOT)
-    try:
-        import bench
-    finally:
-        sys.path.remove(ROOT)
-    row = bench.bench_ps(codec="none", windows=3, mb=0.1,
-                         out_dir=str(tmp_path), ps_workers=2)
-    assert row["ps_workers"] == 2
-    snap_file = tmp_path / "BENCH_PS_OBS_w2.json"
-    assert snap_file.exists()
-    doc = json.loads(snap_file.read_text())
-    assert doc["config"]["ps_workers"] == 2
-    # merged across both clients: every client committed `windows` times,
-    # and every RPC (1 warm pull + 3x(pull+commit) each) observed an RTT
-    assert doc["server"]["ps.commits"]["value"] == 2 * 3
-    assert doc["client"]["ps.client.rtt_seconds"]["count"] == 2 * (1 + 2 * 3)
-    # obsview's snapshot-file mode reads the sweep point unchanged
-    sys.path.insert(0, os.path.join(ROOT, "scripts"))
-    try:
-        import obsview
-    finally:
-        sys.path.remove(os.path.join(ROOT, "scripts"))
-    out = obsview.summarize_snapshot(obsview.load_snapshot(str(snap_file)))
-    assert "client registry" in out and "server registry" in out
-
-
-def test_bench_ps_self_check_against_committed_baseline(tmp_path):
-    """The single-worker bench drift-checks against the committed
-    BENCH_PS_OBS.json (ISSUE 5): matching config -> checked; the config
-    recorded in the committed snapshot names the committed run."""
-    sys.path.insert(0, ROOT)
-    try:
-        import bench
-    finally:
-        sys.path.remove(ROOT)
-    with open(os.path.join(ROOT, "BENCH_PS_OBS.json")) as f:
-        committed_cfg = json.load(f)["config"]
-    # a config that cannot match the committed one -> skipped, with reason
-    row = bench.bench_ps(codec="none", windows=2, mb=0.05,
-                         out_dir=str(tmp_path))
-    assert row["obs_drift"]["checked"] is False
-    assert "config" in row["obs_drift"]["reason"]
-    assert committed_cfg["ps_workers"] == 1  # committed baseline shape
-    first = json.loads((tmp_path / "BENCH_PS_OBS.json").read_text())
-    # a config-incompatible rerun diverts to a .variant sidecar instead of
-    # clobbering the baseline snapshot in place
-    row2 = bench.bench_ps(codec="none", windows=3, mb=0.05,
-                          out_dir=str(tmp_path))
-    assert row2["snapshot"].endswith("BENCH_PS_OBS.variant.json")
-    assert (tmp_path / "BENCH_PS_OBS.variant.json").exists()
-    assert json.loads((tmp_path / "BENCH_PS_OBS.json").read_text()) == first
-    # a same-config rerun refreshes in place and the self-check engages
-    row3 = bench.bench_ps(codec="none", windows=2, mb=0.05,
-                          out_dir=str(tmp_path))
-    assert row3["snapshot"].endswith("BENCH_PS_OBS.json")
-    # a CORRUPT destination snapshot is never overwritten in place
-    (tmp_path / "BENCH_PS_OBS.json").write_text("{garbled")
-    row4 = bench.bench_ps(codec="none", windows=2, mb=0.05,
-                          out_dir=str(tmp_path))
-    assert row4["snapshot"].endswith("BENCH_PS_OBS.variant.json")
-    assert (tmp_path / "BENCH_PS_OBS.json").read_text() == "{garbled"
-
-
 # -- ISSUE 12: DOWN compression, adaptive per-link codecs, shm transport -----
 
 def big_tree(n=20_000, seed=0):
@@ -826,7 +737,7 @@ def test_obsview_prints_codec_accounting(tmp_path):
         {"event": "ps_stats", "num_updates": 7, "stats": stats}])
     assert "bytes saved: 3,000" in text
     assert "compression: 4.00x" in text
-    # snapshot-file mode (the BENCH_PS_OBS.json shape)
+    # snapshot-file mode (named registries beside a config)
     p = tmp_path / "snap.json"
     p.write_text(json.dumps({"config": {"codec": "int8"},
                              "server": stats}))

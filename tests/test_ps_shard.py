@@ -619,31 +619,7 @@ def test_racecheck_catches_write_after_publish():
         viol.clear()  # seeded deliberately: keep the autouse collector green
 
 
-# -- bench + obsview tooling --------------------------------------------------
-
-def test_bench_ps_sharded_sweep_point(tmp_path):
-    sys.path.insert(0, ROOT)
-    try:
-        import bench
-    finally:
-        sys.path.remove(ROOT)
-    row = bench.bench_ps(codec="none", windows=3, mb=0.1,
-                         out_dir=str(tmp_path), ps_workers=2, ps_shards=2)
-    assert row["ps_shards"] == 2 and row["ps_workers"] == 2
-    assert row["commit_rtt_ms_p50"] > 0
-    assert "shards=2" in row["metric"]
-    json.dumps(row)
-    doc = json.loads((tmp_path / "BENCH_PS_OBS_w2.json").read_text())
-    assert doc["config"]["ps_shards"] == 2
-    assert doc["plan"]["num_shards"] == 2
-    # every logical commit landed once per shard
-    assert doc["server"]["ps.commits"]["value"] == 2 * 2 * 3
-    # the single-server baseline config stays shard-free (committed
-    # BENCH_PS_OBS.json keeps matching un-sharded reruns)
-    bench.bench_ps(codec="none", windows=2, mb=0.05, out_dir=str(tmp_path))
-    doc1 = json.loads((tmp_path / "BENCH_PS_OBS.json").read_text())
-    assert "ps_shards" not in doc1["config"]
-
+# -- obsview tooling ----------------------------------------------------------
 
 def test_obsview_ps_fleet_targets_and_balance(tmp_path):
     sys.path.insert(0, os.path.join(ROOT, "scripts"))

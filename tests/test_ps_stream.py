@@ -11,7 +11,6 @@ edge downshifts the adaptive DOWN codec as a recorded
 ``ps.link.downshifts`` event.
 """
 
-import json
 import os
 import socket
 import sys
@@ -299,6 +298,22 @@ def test_sharded_pull_begin_join_matches_pull(rng):
             assert_trees_equal(out, ref)
 
 
+class _ThisThreadsFaults(chaos.SocketFaults):
+    """Counts the seam's calls from the thread that built it alone.  The
+    server's handler runs in this process too: once its one ``sendmsg``
+    is in the loopback buffer its next ``recv_msg`` races the client's
+    reads for an ordinal, and on a loaded host it wins — the reset then
+    lands on the server's side of a stream the client already holds."""
+
+    def __init__(self, schedule):
+        super().__init__(schedule)
+        self._thread = threading.current_thread()
+
+    def __call__(self, stage, action=None):
+        if threading.current_thread() is self._thread:
+            super().__call__(stage, action)
+
+
 def test_midstream_reset_resumes_via_reconnect_backoff(rng):
     """A connection reset while chunk k is on the wire: ``pull_join``
     reconnects through the standard backoff and re-pulls — an
@@ -311,7 +326,7 @@ def test_midstream_reset_resumes_via_reconnect_backoff(rng):
                       stream_chunk_bytes=128 * 1024) as c:
             # recv fault ordinal 3: (1) the pull reply's announce, (2)
             # the prologue frame, (3) the FIRST chunk — mid-stream
-            with chaos.SocketFaults({"recv": [3]}) as faults:
+            with _ThisThreadsFaults({"recv": [3]}) as faults:
                 c.pull_begin()
                 out, n, _, _ = c.pull_join()
             assert faults.injected == 1
@@ -526,21 +541,3 @@ def test_obsview_renders_stream_section_and_link_table(rng):
                 "link_rtt_s": 0.006}]
     assert "Link quality" in "\n".join(
         obsview._link_lines(detect_from_heartbeats(records)))
-
-
-def test_bench_ps_stream_ab_fields(tmp_path):
-    sys.path.insert(0, ROOT)
-    import bench
-    row = bench.bench_ps(windows=6, mb=0.5, out_dir=str(tmp_path))
-    assert row["stream"] is True
-    assert 0.0 <= row["pull_hidden_fraction"] <= 1.0
-    assert row["pull_to_dispatch_ms_p50_mono"] > 0
-    assert row["pull_to_dispatch_ms_p50_stream"] > 0
-    assert row["stream_chunks"] > 0
-    snap = json.loads(
-        (tmp_path / os.path.basename(row["snapshot"])).read_text())
-    # counters pre-created: 0 is PRESENT, not missing
-    assert "ps.link.downshifts" in snap["client"]
-    assert "ps.pull.streams" in snap["client"]
-    assert "ps.pull.streams" in snap["server"]
-    assert "bench.ps.pull_to_dispatch_seconds_mono" in snap["client"]

@@ -6,13 +6,11 @@ pure ``decide()`` unit plus the ``tick()`` action wiring over a real
 two-engine fleet, the tier-1 open-loop runner smoke (exact
 ``dispatched == completed + rejected + timeouts`` accounting, client
 deadline timeouts, recovery stamping, ``jit.retraces == 0``), the
-committed ``BENCH_SCENARIO_OBS.json`` contract (parts present,
-verdicts green, self-diff clean, injected attainment regression fails
-``obsview --diff`` with exit 1), the ``obsview --scenario`` panel, and
-the slow chaos acceptance: a REAL engine subprocess killed with
-SIGKILL mid-trace while the fleet keeps serving."""
+``obsview --scenario`` panel over a persisted ``row.scenarios``
+document (an injected attainment regression fails ``obsview --diff``
+with exit 1), and the slow chaos acceptance: a REAL engine subprocess
+killed with SIGKILL mid-trace while the fleet keeps serving."""
 
-import copy
 import importlib.util
 import json
 import os
@@ -26,7 +24,7 @@ import numpy as np
 import pytest
 
 from distkeras_tpu.models import zoo
-from distkeras_tpu.obs import Registry, drift
+from distkeras_tpu.obs import Registry
 from distkeras_tpu.scenario import (AutoScaler, AutoscalePolicy,
                                     LengthModel, PhaseAccountant,
                                     PrefixMix, SCENARIO_COUNTERS,
@@ -464,11 +462,8 @@ def test_runner_client_deadline_counts_timeouts(lm):
 
 
 # ---------------------------------------------------------------------------
-# committed snapshot contract + obsview panel + diff gate
+# obsview panel + diff gate over a persisted scenario document
 # ---------------------------------------------------------------------------
-
-_SNAP = os.path.join(_ROOT, "BENCH_SCENARIO_OBS.json")
-
 
 def _load_obsview():
     spec = importlib.util.spec_from_file_location(
@@ -478,43 +473,60 @@ def _load_obsview():
     return mod
 
 
-def _committed_doc():
-    with open(_SNAP) as f:
-        return json.load(f)
+def _phase(name, offered):
+    return {"phase": name, "offered": offered, "completed": offered,
+            "attainment": 1.0, "shed_rate": 0.0, "goodput_tps": 400.0,
+            "ttft_p99": 0.19, "e2e_p99": 0.2}
 
 
-def test_committed_scenario_snapshot_contract():
-    """The committed BENCH_SCENARIO_OBS.json carries all three parts,
-    green machine-checked verdicts, the pre-created scenario.* metric
-    surface, and self-diffs clean under the committed thresholds."""
-    doc = _committed_doc()
-    row = doc["row"]
-    assert row["attainment_ok"] is True
-    assert row["autoscaler_tracked"] is True
-    assert row["jit_retraces"] == 0
-    for part in ("scenario_diurnal", "scenario_spike", "scenario_chaos"):
-        assert part in doc, part
-        snap = doc[part]
-        for name in SCENARIO_COUNTERS:
-            assert name in snap, f"{part}/{name} not pre-created"
-        assert "serve.ttft_seconds" in snap
-    for name, s in row["scenarios"].items():
-        assert s["accounting_exact"] is True, name
-        assert s["counts"]["dispatched"] == (
-            s["counts"]["completed"] + s["counts"]["rejected"]
-            + s["counts"]["timeouts"]), name
-    assert row["scenarios"]["diurnal"]["scale_up"] > 0
-    assert row["scenarios"]["diurnal"]["scale_down"] > 0
-    assert row["scenarios"]["chaos"]["recovery_s_p50"] is not None
-    bl = drift.load_baseline(os.path.join(_ROOT, "OBS_BASELINE.json"))
-    assert bl["snapshots"]["scenario_bench"] == "BENCH_SCENARIO_OBS.json"
-    rep = drift.diff_docs(doc, copy.deepcopy(doc), baseline=bl)
-    assert not rep.drifted, rep.drifted_metrics
+def _scenario(phases, **extra):
+    n = sum(p["offered"] for p in phases)
+    return {"seed": 17, "arrivals": n, "wall_s": 4.0, "engines": 2,
+            "phases": phases,
+            "counts": {"dispatched": n, "completed": n, "rejected": 0,
+                       "timeouts": 0}, **extra}
 
 
-def test_obsview_scenario_panel_renders(capsys):
+def _snapshot_doc():
+    """A persisted scenario document of the shape the panel and the diff
+    read: the harness's ``row`` beside one named registry a scenario."""
+    reg = precreate_metrics(Registry())
+    for name in ("serve.ttft_seconds", "serve.e2e_seconds"):
+        h = reg.histogram(name)
+        for _ in range(32):
+            h.observe(0.02)
+    row = {"slo": {"ttft_s": 0.5, "e2e_s": 2.5, "attainment": 0.95},
+           "attainment_ok": True, "autoscaler_tracked": True,
+           "jit_retraces": 0,
+           "scenarios": {
+               "diurnal": _scenario(
+                   [_phase("night", 8), _phase("ramp_up", 16),
+                    _phase("peak", 32)],
+                   scale_up=1, scale_down=1, scale_events=[
+                       {"t": 2.5, "action": "up", "engine": "127.0.0.1:1",
+                        "ok": True, "alive": 2,
+                        "reason": "queue/engine=4.0"},
+                       {"t": 3.5, "action": "down",
+                        "engine": "127.0.0.1:1", "ok": True, "alive": 1,
+                        "reason": "queue/engine=0.0"}]),
+               "spike": _scenario([_phase("pre", 8), _phase("spike", 32)]),
+               "chaos": _scenario([_phase("steady", 16)],
+                                  recovery_s_p50=0.4, engines_alive_end=1)}}
+    return {"config": {"mode": "scenario"}, "row": row,
+            **{f"scenario_{name}": reg.snapshot()
+               for name in row["scenarios"]}}
+
+
+@pytest.fixture()
+def snap_path(tmp_path):
+    path = tmp_path / "scenario_obs.json"
+    path.write_text(json.dumps(_snapshot_doc()))
+    return str(path)
+
+
+def test_obsview_scenario_panel_renders(snap_path, capsys):
     obsview = _load_obsview()
-    assert obsview.run_scenario(_SNAP) == 0
+    assert obsview.run_scenario(snap_path) == 0
     out = capsys.readouterr().out
     assert "Scenario harness" in out
     assert "diurnal" in out and "spike" in out and "chaos" in out
@@ -524,7 +536,7 @@ def test_obsview_scenario_panel_renders(capsys):
 
 
 def test_obsview_scenario_slo_miss_alarm(tmp_path, capsys):
-    doc = _committed_doc()
+    doc = _snapshot_doc()
     ph = doc["row"]["scenarios"]["diurnal"]["phases"][2]
     ph["attainment"] = 0.5  # inject a miss into the peak phase
     bad = tmp_path / "bad_snap.json"
@@ -554,25 +566,22 @@ def test_obsview_scenario_live_and_bad_targets(lm, capsys):
         server.stop()
 
 
-def test_obsview_diff_flags_injected_attainment_regression(tmp_path,
-                                                           capsys):
-    """The CI gate: shift the committed diurnal part's e2e mass past
-    the SLO bound (every request suddenly slow) -> ``obsview --diff``
-    exits 1; the committed doc against itself exits 0."""
+def test_obsview_diff_flags_injected_attainment_regression(
+        snap_path, tmp_path, capsys):
+    """The CI gate: shift the diurnal part's e2e mass past the SLO bound
+    (every request suddenly slow) -> ``obsview --diff`` exits 1 under
+    the committed thresholds; the document against itself exits 0."""
     obsview = _load_obsview()
-    doc = _committed_doc()
-    clean = tmp_path / "clean.json"
-    clean.write_text(json.dumps(doc))
-    assert obsview.run_diff(_SNAP, str(clean)) == 0
+    assert obsview.run_diff(snap_path, snap_path) == 0
     capsys.readouterr()
-    bad = copy.deepcopy(doc)
+    bad = _snapshot_doc()
     h = bad["scenario_diurnal"]["serve.e2e_seconds"]
     # p50 explodes: all observations land in the top bucket
     h["counts"] = [0] * (len(h["counts"]) - 1) + [h["count"]]
     h["sum"] = float(h["count"]) * 10.0
     regressed = tmp_path / "regressed.json"
     regressed.write_text(json.dumps(bad))
-    assert obsview.run_diff(_SNAP, str(regressed)) == 1
+    assert obsview.run_diff(snap_path, str(regressed)) == 1
     out = capsys.readouterr().out
     assert "serve.e2e_seconds" in out
 

@@ -3,21 +3,19 @@ offline-decode parity, mid-decode joins, admission control (queue-full
 load shedding, draining rejections, hard-stop aborts — nothing drops
 without a recorded rejection), the serve wire (v1<->v2 interop over the
 shared hello seam), the steady-state ``jit.retraces == 0`` contract
-drift-gated by the committed ``OBS_BASELINE.json``, ``bench.py --serve``
-and the ``obsview --serve`` rendering.
+drift-gated by the committed ``OBS_BASELINE.json``, and the
+``obsview --serve`` rendering.
 
 ISSUE 11 adds the decode accelerators: prefix-KV-cache warm joins
 (parity, ttft split, LRU eviction under budget pressure, the
 ``promote()`` flush) and speculative decoding (greedy parity vs
 ``generate_tokens`` across bucket boundaries and eos-mid-window, at any
-draft quality), their config-time knob validation, and their bench /
-obsview surfaces."""
+draft quality), their config-time knob validation, and their obsview
+surface."""
 
 import copy
 import importlib.util
-import json
 import os
-import sys
 import threading
 import time
 
@@ -794,8 +792,9 @@ def test_server_acceptance_continuous_join_steady_state(lm):
     eng = _engine(lm, registry=reg, max_new_tokens=24).warmup()
     reply_a: dict = {}
     with ServeServer(eng) as srv:
-        with ServeClient("127.0.0.1", srv.port) as ca, \
-                ServeClient("127.0.0.1", srv.port) as cb:
+        with ServeClient("127.0.0.1", srv.port, registry=Registry()) as ca, \
+                ServeClient("127.0.0.1", srv.port,
+                            registry=Registry()) as cb:
             t = threading.Thread(
                 target=lambda: reply_a.update(ca.generate(long_p, 24)))
             t.start()
@@ -813,7 +812,15 @@ def test_server_acceptance_continuous_join_steady_state(lm):
                           _ref(lm, short_p, 4))
     assert st["stats"]["serve.joins"]["value"] == 2
     assert st["stats"]["serve.completed"]["value"] == 2
+    assert st["stats"]["serve.rejected"]["value"] == 0
     assert st["stats"]["jit.retraces"]["value"] == 0
+    # the load generator's side: its clients' registries merge into one
+    # view that counts every request once
+    mine = Registry.merge_snapshots(ca.registry.snapshot(),
+                                    cb.registry.snapshot())
+    assert mine["serve.client.requests"]["value"] == 2
+    assert mine["serve.client.rejected"]["value"] == 0
+    assert mine["serve.client.e2e_seconds"]["count"] == 2
 
 
 def test_server_malformed_fields_answer_instead_of_dropping(lm):
@@ -899,216 +906,8 @@ def test_steady_state_retraces_zero_drift_gated(lm):
 
 
 # ---------------------------------------------------------------------------
-# bench.py --serve + obsview --serve
+# obsview --serve
 # ---------------------------------------------------------------------------
-
-def test_bench_serve_emits_row_and_self_checks(tmp_path, monkeypatch):
-    if _ROOT not in sys.path:
-        sys.path.insert(0, _ROOT)
-    import bench
-    # point the designated baseline into the sandbox so the second run
-    # self-checks against the first (the committed BENCH_SERVE_OBS.json
-    # belongs to the full-size bench config)
-    monkeypatch.setattr(
-        bench, "_baseline_snapshot_path",
-        lambda cfg, key, default: str(tmp_path / default))
-    # shrink both accelerator phases to this test's toy scale (the
-    # committed SERVE_*_PHASE defaults are sized for real prefill cost)
-    kw = dict(requests=6, concurrency=2, prompt_len=5, max_new=4,
-              slots=2, queue=4, out_dir=str(tmp_path), vocab=VOCAB,
-              dim=16, heads=2, blocks=1, seq_len=SEQ,
-              prefix_phase=dict(requests=3, vocab=VOCAB, dim=16, heads=2,
-                                blocks=1, seq_len=SEQ, shared=16, tail=3,
-                                max_new=2, suffix_bucket=8, cache_mb=8.0,
-                                block=8),
-              spec_phase=dict(k=2, requests=3, prompt_len=4, max_new=6,
-                              vocab=VOCAB, dim=16, heads=2, blocks=1,
-                              seq_len=SEQ),
-              router_phase=dict(engines=2, groups=4, per_group=3,
-                                concurrency=4, shared=16, tail=3,
-                                max_new=4, block=8, slots=2, queue=16,
-                                cache_mb=8.0, vocab=VOCAB, dim=16,
-                                heads=2, blocks=1, seq_len=SEQ),
-              fabric_phase=dict(engines=2, groups=2, rounds=2,
-                                shared=16, tail=3, max_new=2,
-                                suffix_bucket=8, prefill_bucket=32,
-                                block=8, slots=2, queue=8,
-                                cache_mb=8.0, vocab=VOCAB, dim=16,
-                                heads=2, blocks=1, seq_len=SEQ))
-    row = bench.bench_serve(**kw)
-    assert row["mode"] == "bench_serve"
-    assert row["rejected"] == 0  # closed loop under capacity never sheds
-    assert row["jit_retraces"] == 0
-    assert row["e2e_ms_p50"] > 0 and row["ttft_ms_p50"] > 0
-    assert row["tokens_per_sec"] > 0
-    # accelerator-phase rows are PRESENT (the pre-created contract)
-    assert row["prefix_hit_rate"] == round(2 / 3, 3)
-    assert row["ttft_warm_ms_p50"] > 0 and row["ttft_cold_ms_p50"] > 0
-    assert row["spec_k"] == 2 and row["spec_parity"] is True
-    assert row["spec_accept_rate"] == 1.0  # self-draft ceiling
-    assert row["tokens_per_sec_spec"] > 0
-    # router phase (ISSUE 14): one scaling point per fleet size, exact
-    # deterministic fleet accounting, no fleet misbehavior
-    assert row["router_engines"] == 2
-    assert [p["engines"] for p in row["router_scaling"]] == [1, 2]
-    for p in row["router_scaling"]:
-        assert p["tokens_per_sec"] > 0 and p["e2e_ms_p99"] > 0
-        assert p["prefix_hit_rate"] == round(8 / 12, 3)
-        assert p["requeues"] == 0 and p["evictions"] == 0
-        assert p["jit_retraces"] == 0
-    assert row["router_speedup"] > 0
-    assert row["router_affinity_hit_rate"] == round(8 / 12, 3)
-    # KV-fabric phase (ISSUE 16): replication landed, nothing refused
-    assert row["fabric_engines"] == 2
-    assert row["fabric_kv_replications"] >= 1
-    assert row["fabric_kv_migrations"] >= 1
-    assert row["fabric_kv_push_bytes"] > 0
-    assert row["fabric_kv_refused_stale"] == 0
-    assert row["fabric_ttft_spill_cold_ms_p50"] > 0
-    assert row["fabric_ttft_spill_warm_ms_p50"] > 0
-    assert row["obs_drift"] == {"checked": False,
-                                "reason": "no baseline snapshot"}
-    snap_path = tmp_path / "BENCH_SERVE_OBS.json"
-    assert snap_path.exists()
-    with open(snap_path) as f:
-        doc = json.load(f)
-    assert doc["config"]["requests"] == 6
-    # the zero-pinned sentinels are PRESENT (0), not missing
-    assert doc["server"]["jit.retraces"]["value"] == 0
-    assert doc["server"]["jit.compiles"]["value"] > 0
-    assert doc["server"]["serve.completed"]["value"] == 6
-    assert doc["client"]["serve.client.requests"]["value"] == 6
-    # the load-phase engine runs with the cache off: its accelerator
-    # counters are present zeros, never missing
-    assert doc["server"]["serve.prefix.hits"]["value"] == 0
-    assert doc["server"]["serve.spec.proposed"]["value"] == 0
-    # the phase registries ride in the same drift-gated document
-    assert doc["prefix"]["serve.prefix.hits"]["value"] == 2
-    assert doc["prefix"]["serve.ttft_warm_seconds"]["count"] == 2
-    assert doc["spec"]["serve.spec.accept_rate"]["value"] == 1.0
-    assert doc["spec_base"]["serve.spec.proposed"]["value"] == 0
-    assert doc["row"]["spec_parity"] is True
-    # one merged fleet snapshot per router point, retrace-clean with
-    # exact front-door accounting
-    for n in (1, 2):
-        part = doc[f"router_n{n}"]
-        assert part["jit.retraces"]["value"] == 0
-        assert part["serve.router.requests"]["value"] == 12
-        assert part["serve.router.requests"]["value"] == \
-            part["serve.router.completed"]["value"] + \
-            part["serve.router.rejected"]["value"]
-        assert part["serve.prefix.hits"]["value"] == 8
-        assert part["serve.router.evictions"]["value"] == 0
-
-    row2 = bench.bench_serve(**kw)
-    assert row2["obs_drift"]["checked"] is True
-
-    # phases off: row keys still present, explicitly None
-    row3 = bench.bench_serve(**{**kw, "prefix_phase": False,
-                                "spec_phase": False,
-                                "router_phase": False,
-                                "fabric_phase": False})
-    assert row3["prefix_hit_rate"] is None
-    assert row3["spec_uplift"] is None
-    assert row3["router_scaling"] is None
-    assert row3["fabric_spill_speedup"] is None
-
-
-def test_committed_serve_snapshot_matches_baseline_contract():
-    """The committed BENCH_SERVE_OBS.json is a valid registry-snapshot
-    document with the sentinels present at zero retraces — the state the
-    drift gate protects.  ISSUE 11: the committed artifact also carries
-    both accelerator phases, and the acceptance numbers hold — warm ttft
-    p50 at least 3x lower than cold, and a tokens/sec uplift from
-    speculative decoding at exact greedy parity.  ISSUE 14: it also
-    carries the router scaling curve — aggregate tokens/sec INCREASING
-    with fleet size (N >= 3) when the recording host had cores to give
-    each engine, prefix-affinity hit rate within 20% of the
-    single-engine warm baseline, zero retraces fleet-wide.  ISSUE 16:
-    the KV-fabric phase rides in the artifact too — replicated spills
-    at least 2x faster to first token than cold spills, real bytes
-    moved, ZERO stale refusals."""
-    path = os.path.join(_ROOT, "BENCH_SERVE_OBS.json")
-    assert os.path.exists(path), "bench.py --serve snapshot not committed"
-    with open(path) as f:
-        doc = json.load(f)
-    assert doc["config"]["mode"] == "bench_serve"
-    n_committed = doc["config"]["router_phase"]["engines"]
-    assert n_committed >= 3
-    for part in ("client", "server", "prefix", "spec_base", "spec",
-                 "fabric",
-                 *(f"router_n{n}" for n in range(1, n_committed + 1))):
-        assert drift.is_registry_snapshot(doc[part]), part
-    assert doc["server"]["jit.retraces"]["value"] == 0
-    for name in ("serve.e2e_seconds", "serve.ttft_seconds",
-                 "serve.queue_wait_seconds", "serve.per_token_seconds"):
-        assert doc["server"][name]["count"] > 0
-    # prefix phase: a real warm/cold split, zero retraces, >= 3x ttft win
-    assert doc["prefix"]["jit.retraces"]["value"] == 0
-    assert doc["prefix"]["serve.ttft_cold_seconds"]["count"] >= 1
-    assert doc["prefix"]["serve.ttft_warm_seconds"]["count"] >= 2
-    assert doc["prefix"]["serve.prefix.hits"]["value"] >= 2
-    assert doc["prefix"]["serve.prefix.evictions"]["value"] == 0
-    # the true ratio sits ~3-4x but the phase has ONE cold prefill
-    # observation, so host noise moves the committed value; the gate
-    # exists to catch a BROKEN cache (ratio ~1), not to pin the draw
-    assert doc["row"]["warm_speedup"] >= 2.0
-    # spec phase: uplift at full acceptance and exact parity
-    assert doc["spec"]["jit.retraces"]["value"] == 0
-    assert doc["spec"]["serve.spec.proposed"]["value"] > 0
-    assert doc["spec"]["serve.spec.accept_rate"]["value"] == 1.0
-    assert doc["row"]["spec_parity"] is True
-    assert doc["row"]["spec_uplift"] > 1.0
-    # router phase (ISSUE 14 acceptance): tokens/sec increases with N,
-    # fleet affinity hit rate within 20% of the single-engine warm
-    # baseline, nothing evicted/requeued/re-traced in the clean run
-    curve = doc["row"]["router_scaling"]
-    assert [p["engines"] for p in curve] == \
-        list(range(1, n_committed + 1))
-    tps = [p["tokens_per_sec"] for p in curve]
-    assert all(t > 0 for t in tps)
-    # scale-up is only expressible when the host could run the engines
-    # in parallel — a single-core container serializes the fleet and
-    # the curve shape is scheduler noise, not a serving property
-    if doc["row"].get("host_cpus") and \
-            doc["row"]["host_cpus"] > n_committed:
-        assert all(b > a for a, b in zip(tps, tps[1:])), \
-            f"fleet tokens/sec must increase with N, got {tps}"
-    single = curve[0]["prefix_hit_rate"]
-    assert curve[-1]["prefix_hit_rate"] >= 0.8 * single
-    for p in curve:
-        assert p["jit_retraces"] == 0
-        assert p["requeues"] == 0 and p["evictions"] == 0
-        assert doc[f"router_n{p['engines']}"][
-            "serve.router.evictions"]["value"] == 0
-    with open(os.path.join(_ROOT, "OBS_BASELINE.json")) as f:
-        bl = json.load(f)
-    assert bl["snapshots"]["serve_bench"] == "BENCH_SERVE_OBS.json"
-    # the accelerator gates the CI satellite names: exact prefix
-    # counters, the opted-in accept-rate gauge; ISSUE 14 adds the exact
-    # front-door accounting rules and the opted-in fleet hit-rate gauge
-    assert bl["metrics"]["serve.prefix.*"]["counter_abs"] == 0.0
-    assert bl["metrics"]["serve.spec.accept_rate"]["gauge_abs"] <= 0.2
-    assert bl["metrics"]["serve.router.requests"]["counter_abs"] == 0.0
-    assert bl["metrics"]["serve.router.evictions"]["counter_abs"] == 0.0
-    assert bl["metrics"]["serve.router.affinity_hit_rate"][
-        "gauge_abs"] <= 0.2
-    # KV-fabric phase (ISSUE 16 acceptance): replicated spills beat
-    # cold spills >= 2x to first token, the fabric moved real bytes,
-    # and the committed baseline gates stale refusals at EXACTLY zero
-    assert doc["row"]["fabric_spill_speedup"] >= 2.0
-    assert doc["row"]["fabric_kv_replications"] >= 1
-    assert doc["row"]["fabric_kv_migrations"] >= 1
-    assert doc["row"]["fabric_kv_push_bytes"] > 0
-    assert doc["row"]["fabric_kv_refused_stale"] == 0
-    assert doc["fabric"]["jit.retraces"]["value"] == 0
-    assert doc["fabric"][
-        "serve.router.ttft_spill_warm_seconds"]["count"] >= 1
-    assert doc["fabric"][
-        "serve.router.ttft_spill_cold_seconds"]["count"] >= 1
-    assert bl["metrics"]["serve.router.kv_refused_stale"][
-        "counter_abs"] == 0.0
-
 
 def _load_obsview():
     spec = importlib.util.spec_from_file_location(

@@ -604,7 +604,7 @@ def test_obsview_kvfabric_panel_and_cold_spill_alarm():
     eng = obsview.summarize_serve(
         {"server": "ServeServer", "stats": Registry().snapshot()})
     assert "== KV fabric ==" not in eng
-    # snapshot mode (the committed BENCH_SERVE_OBS.json shape) renders
+    # snapshot mode (a persisted registry-snapshot document) renders
     # the same panel per fabric-bearing registry
     out = obsview.summarize_snapshot(
         {"config": {"mode": "serve_bench"},

@@ -96,21 +96,3 @@ def test_compile_cache_default_is_one_fixed_path(monkeypatch):
         == os.path.join(root, ".jax_cache")
     assert set_to == [("jax_compilation_cache_dir",
                        os.path.join(root, ".jax_cache"))] * 2
-
-
-def test_mfu_refuses_a_device_without_a_published_peak():
-    """An MFU against a guessed peak is not a measurement: an unlisted
-    device_kind raises (the old table matched substrings and fell back to
-    a 0.1 TFLOP/s "cpu" peak); --peak-tflops is the only way around."""
-    import importlib.util
-    import os
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "mfu", os.path.join(root, "scripts", "mfu.py"))
-    mfu = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mfu)
-    assert mfu.peak_flops("TPU v5 lite") == 197e12
-    assert mfu.peak_flops("cpu", 0.5) == 0.5e12
-    for kind in ("cpu", "TPU v5 lite pod", "tpu v5 lite", "TPU v4"):
-        with pytest.raises(ValueError, match="no peak FLOP/s on record"):
-            mfu.peak_flops(kind)
