@@ -9,13 +9,25 @@ where the dense path's scores alone would need tens of GB).
 Two walks over the (q tile, k tile) pairs, chosen by ``_blocks`` from what
 it can see (``causal``, the two lengths, T, Dh, the dtype):
 
-* the GRID walk: K/V blocks stream through VMEM on a (batch·head, block,
-  block) grid, the online-softmax running statistics in VMEM scratch that
-  persists across the minor grid dimension.  Every non-causal call, every
-  rectangular one (the zigzag ring's hops), every call with explicit
-  ``block_q``/``block_k``, and causal lengths whose whole-sequence
-  operands would not fit ``_CAUSAL_VMEM_BUDGET`` (T = 4,096 and up at
-  bf16).  Blocks come from ``_auto_block``: as large as VMEM allows.
+* the GRID walk: one block pair a grid step, K/V blocks streaming through
+  VMEM, the online-softmax running statistics in VMEM scratch that
+  persists across a row's steps.  Blocks come from ``_auto_block``: as
+  large as VMEM allows.  One set of kernel bodies on three grids
+  (``_walk`` builds them, ``_step`` tells a body where it is):
+  - a causal call — explicit ``block_q``/``block_k``, or a length whose
+    whole-sequence operands would not fit ``_CAUSAL_VMEM_BUDGET``
+    (T = 4,096 and up at bf16) — runs a (batch·head, step) grid over
+    ``_walk_table``: a static table of the block pairs with work,
+    scalar-prefetched, that every index map reads; a pair past the
+    diagonal is no step and no DMA, and the mask is built only in the
+    blocks the diagonal crosses.  At T = 8,192 in 512-blocks 136 of 256
+    pairs are steps, 16 of them masked;
+  - a sliding window runs (batch·head, query block, band): the key
+    blocks the band touches alone (2 of 16), by arithmetic index maps —
+    with one idle step in 32 the table's dearer step does not pay;
+  - a non-causal call (every rectangular one: the zigzag ring's hops)
+    runs the dense (batch·head, block, block) grid: it has no pair to
+    skip.
 * the IN-KERNEL causal walk (default blocks, causal, Tq == Tk, 256 <= T
   within the budget): one grid step a batch·head with q, k, v (and dO)
   resident as whole-sequence blocks; the kernel body walks the static
@@ -82,13 +94,38 @@ def _dot_t(a, b):  # a @ b.T, same precision policy as _dot
                            preferred_element_type=jnp.float32)
 
 
-def _causal_mask(qi, kb, block_q, block_k, shape, window=None):
-    """key <= query, and with a ``window`` also query - key < window."""
-    q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32, shape, 0)
-    k_pos = kb * block_k + lax.broadcasted_iota(jnp.int32, shape, 1)
-    if window is None:
-        return k_pos <= q_pos
-    return (k_pos <= q_pos) & (q_pos - k_pos < window)
+#: a step's flags in the causal grid walk's table: the row's first and
+#: last step, and whether the block needs a mask
+_FIRST, _LAST, _MASKED = 1, 2, 4
+
+
+def _walk_table(tq: int, tk: int, bq: int, bk: int, by_keys: bool = False):
+    """The causal grid walk's schedule, one entry a grid step:
+    ``(query block, key block, first, last, masked)``.
+
+    Only block pairs with work are steps: those with a key at or before
+    a query (``kb·bk <= qi·bq + bq − 1``).  A ROW of the grid is a query
+    block and its key blocks in ascending order (the forward and dQ: the
+    diagonal block last), or ``by_keys`` a key block and its query
+    blocks (dK/dV: the diagonal block first); a row's steps are
+    contiguous, ``first`` / ``last`` mark its ends (init / finalize),
+    and the row's output block stays put over them.  ``masked`` is true
+    where the diagonal passes through the block (its last key is after
+    its first query); in every other block every pair attends and no
+    mask is built.  136 steps, 16 of them masked, at T = 8,192 with
+    512-blocks (256 pairs in all)."""
+    def needed(qi, kb):
+        return kb * bk <= qi * bq + bq - 1
+
+    n_q, n_k = tq // bq, tk // bk
+    if by_keys:
+        rows = [[(qi, kb) for qi in range(n_q) if needed(qi, kb)]
+                for kb in range(n_k)]
+    else:
+        rows = [[(qi, kb) for kb in range(n_k) if needed(qi, kb)]
+                for qi in range(n_q)]
+    return [(qi, kb, j == 0, j == len(row) - 1, kb * bk + bk - 1 > qi * bq)
+            for row in rows for j, (qi, kb) in enumerate(row)]
 
 
 def _band_blocks(window: int, block: int) -> int:
@@ -98,138 +135,187 @@ def _band_blocks(window: int, block: int) -> int:
     return 1 + -(-(window - 1) // block)
 
 
+def _step(refs, walk, block_q: int, block_k: int):
+    """Where this grid step is, for each of the three grids ``_walk``
+    builds: ``(offset, first, last, runs, operand refs)``.  ``offset``
+    is the block's first query position less its first key position
+    (what a mask needs); ``first`` / ``last`` the ends of the row (init
+    / finalize); ``runs`` the ``(condition, with_mask)`` bodies the step
+    may take, a condition of None meaning always.
+
+    * dense (``walk`` None): every pair, no mask anywhere.
+    * a window's band, ``("band", by_keys, n_q)``: the minor axis is the
+      band, step j of query block i is key block i - (band - 1) + j (of
+      key block i: query block i + j); a step that falls off the
+      sequence does nothing, every other builds the mask.
+    * the causal table, ``("table", plain, masked)``: the three
+      scalar-prefetched columns of ``_walk_table`` say where the step is
+      and whether its block is masked; one body for each kind the table
+      holds."""
+    if walk is not None and walk[0] == "table":
+        qi_ref, kb_ref, flag_ref, *refs = refs
+        s = pl.program_id(1)
+        flags = flag_ref[s]
+        masked = (flags & _MASKED) != 0
+        runs = [(jnp.logical_not(masked), False), (masked, True)]
+        if not (walk[1] and walk[2]):  # one kind alone: no branch
+            runs = [(None, walk[2])]
+        return (qi_ref[s] * block_q - kb_ref[s] * block_k,
+                (flags & _FIRST) != 0, (flags & _LAST) != 0, runs, refs)
+    row, j, n = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    if walk is None:
+        return None, j == 0, j == n - 1, [(None, False)], refs
+    _, by_keys, n_q = walk
+    if by_keys:
+        qi, kb = row + j, row
+    else:
+        qi, kb = row, row - (n - 1) + j
+    inside = qi < n_q if by_keys else kb >= 0
+    return (qi * block_q - kb * block_k, j == 0, j == n - 1,
+            [(inside, True)], refs)
+
+
+def _run(runs, compute) -> None:
+    for condition, with_mask in runs:
+        body = functools.partial(compute, with_mask)
+        body() if condition is None else pl.when(condition)(body)
+
+
+def _pair_mask(offset, shape, window, keys_first: bool = False):
+    """Mask of a (queries, keys) block — (keys, queries) if
+    ``keys_first`` — whose first query is ``offset`` positions after its
+    first key: key <= query, and with a ``window`` also query - key <
+    window."""
+    rows = lax.broadcasted_iota(jnp.int32, shape, 0)
+    cols = lax.broadcasted_iota(jnp.int32, shape, 1)
+    ahead = rows - cols if keys_first else cols - rows  # key - query
+    mask = ahead <= offset
+    if window is not None:
+        mask &= ahead > offset - window
+    return mask
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc, *,
-                causal: bool, scale: float, block_q: int, block_k: int,
-                window=None):
-    """Grid (bh, qi, kb): one K/V block per step; accumulators persist
-    across kb (TPU executes the grid sequentially, minor-most last).
-    With a ``window`` the last grid axis walks the BAND alone: step j is
-    key block qi - (band - 1) + j (the launcher's index map clamps it),
-    and a step before the sequence's first block does nothing."""
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    n_kb = pl.num_programs(2)
-    kb = step if window is None else qi - (n_kb - 1) + step
+def _lanes(x, n: int):
+    """A (rows, 128) lane-replicated statistic as (rows, n): whole
+    128-lane tiles repeated, or the first ``n`` lanes of one."""
+    if n % 128:
+        return x[:, :n] if n < 128 else jnp.broadcast_to(
+            x[:, :1], (x.shape[0], n))
+    return x if n == 128 else pltpu.repeat(x, n // 128, axis=1)
 
-    @pl.when(step == 0)
+
+def _fwd_kernel(*refs, scale: float, block_q: int, block_k: int, window,
+                walk):
+    """One (q block, K/V block) pair a grid step; the accumulators
+    persist across a row's steps (TPU executes the grid sequentially,
+    minor-most last).  Grid (bh, q blocks, k blocks) for a non-causal
+    call; (bh, steps of ``_walk_table``) for a causal one.  The running
+    max and sum are kept REPLICATED over the 128 lanes of their scratch:
+    a lane reduction leaves its result that way, so a step loads,
+    updates and stores them as whole vregs; kept as one column they cost
+    a lane gather and a rotate a vreg a step (451 + 448 in the lowered
+    body), a third of the forward's time at 512² blocks (v5e)."""
+    offset, first, last, runs, refs = _step(refs, walk, block_q, block_k)
+    q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc = refs
+
+    @pl.when(first)
     def _init():
         o_acc[:] = jnp.zeros_like(o_acc)
         m_acc[:] = jnp.full_like(m_acc, _NEG)
         l_acc[:] = jnp.zeros_like(l_acc)
 
-    def _compute():
+    def _compute(with_mask):
         # matmuls in the input dtype (f32 → HIGHEST, bf16 → full MXU
         # rate with f32 accumulation); softmax statistics always f32
         s = _dot_t(q_ref[0], k_ref[0]) * scale
-        if causal:
-            mask = _causal_mask(qi, kb, block_q, block_k, s.shape, window)
+        if with_mask:
+            mask = _pair_mask(offset, s.shape, window)
             s = jnp.where(mask, s, _NEG)
-        m_prev = m_acc[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        if causal:
+        m_prev = m_acc[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, s.shape[1]))
+        if with_mask:
             p = jnp.where(mask, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_acc[:, 0] = l_acc[:, 0] * corr + jnp.sum(p, axis=-1)
-        o_acc[:] = o_acc[:] * corr[:, None] + _dot(
+        l_acc[:] = l_acc[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        o_acc[:] = o_acc[:] * _lanes(corr, o_acc.shape[1]) + _dot(
             p.astype(v_ref.dtype), v_ref[0])
-        m_acc[:, 0] = m_new
+        m_acc[:] = m_new
 
-    if window is not None:
-        pl.when(kb >= 0)(_compute)
-    elif causal:
-        # skip K/V blocks entirely in the future of this q block
-        pl.when(kb * block_k <= qi * block_q + block_q - 1)(_compute)
-    else:
-        _compute()
+    _run(runs, _compute)
 
-    @pl.when(step == n_kb - 1)
+    @pl.when(last)
     def _finalize():
-        l = l_acc[:, 0]
-        o_ref[0] = (o_acc[:] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_acc[:, 0] + jnp.log(l)
+        l = l_acc[:]
+        o_ref[0] = (o_acc[:] / _lanes(l, o_acc.shape[1])).astype(o_ref.dtype)
+        lse_ref[0, 0] = (m_acc[:] + jnp.log(l))[:, 0]
 
 
 # ---------------------------------------------------------------------------
 # backward (Dao 2022 recurrence; P recomputed blockwise from L)
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref, dq_ref,
-                   dq_acc, *, causal: bool, scale: float, block_q: int,
-                   block_k: int, window=None):
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    n_kb = pl.num_programs(2)
-    kb = step if window is None else qi - (n_kb - 1) + step  # the band
+def _bwd_dq_kernel(*refs, scale: float, block_q: int, block_k: int, window,
+                   walk):
+    offset, first, last, runs, refs = _step(refs, walk, block_q, block_k)
+    q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref, dq_ref, dq_acc = refs
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    def _compute():
+    def _compute(with_mask):
         s = _dot_t(q_ref[0], k_ref[0]) * scale
         p = jnp.exp(s - lse_ref[0, 0][:, None])
-        if causal:
-            mask = _causal_mask(qi, kb, block_q, block_k, s.shape, window)
-            p = jnp.where(mask, p, 0.0)
+        if with_mask:
+            p = jnp.where(_pair_mask(offset, s.shape, window), p, 0.0)
         dp = _dot_t(do_ref[0], v_ref[0])
         ds = p * (dp - dvec_ref[0, 0][:, None]) * scale
         dq_acc[:] = dq_acc[:] + _dot(ds.astype(k_ref.dtype), k_ref[0])
 
-    if window is not None:
-        pl.when(kb >= 0)(_compute)
-    elif causal:
-        pl.when(kb * block_k <= qi * block_q + block_q - 1)(_compute)
-    else:
-        _compute()
+    _run(runs, _compute)
 
-    @pl.when(step == n_kb - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, causal: bool,
-                    scale: float, block_q: int, block_k: int, window=None,
-                    n_q=None):
-    """With a ``window`` the last grid axis walks the band: step j is
-    query block kb + j, and a step past the last of the ``n_q`` query
-    blocks does nothing."""
-    kb = pl.program_id(1)
-    step = pl.program_id(2)
-    n_qb = pl.num_programs(2)
-    qj = step if window is None else kb + step
+def _bwd_dkv_kernel(*refs, scale: float, block_q: int, block_k: int, window,
+                    walk):
+    """A row is a K/V block and its query blocks: every one on the dense
+    grid, from the diagonal's on down the causal walk.  S and dP are
+    built TRANSPOSED (keys on sublanes), as the in-kernel walk builds
+    them: P^T and dS^T feed the dV and dK matmuls as they are (no 512²
+    transpose a step), and ``lse`` / ``dvec`` broadcast from the (1, bq)
+    lane layout they arrive in."""
+    offset, first, last, runs, refs = _step(refs, walk, block_q, block_k)
+    (k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref, dk_ref, dv_ref, dk_acc,
+     dv_acc) = refs
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def _compute():
-        s = _dot_t(q_ref[0], k_ref[0]) * scale        # (BQ, BK)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])
-        if causal:
-            mask = _causal_mask(qj, kb, block_q, block_k, s.shape, window)
-            p = jnp.where(mask, p, 0.0)
+    def _compute(with_mask):
+        st = _dot_t(k_ref[0], q_ref[0]) * scale       # (BK, BQ)
+        pt = jnp.exp(st - lse_ref[0])
+        if with_mask:
+            pt = jnp.where(_pair_mask(offset, st.shape, window,
+                                      keys_first=True), pt, 0.0)
         # dV += P^T dO ; dS = P∘(dO V^T − D) ; dK += dS^T Q
-        dv_acc[:] = dv_acc[:] + _dot(p.T.astype(do_ref.dtype), do_ref[0])
-        dp = _dot_t(do_ref[0], v_ref[0])
-        ds = p * (dp - dvec_ref[0, 0][:, None]) * scale
-        dk_acc[:] = dk_acc[:] + _dot(ds.T.astype(q_ref.dtype), q_ref[0])
+        dv_acc[:] = dv_acc[:] + _dot(pt.astype(do_ref.dtype), do_ref[0])
+        dpt = _dot_t(v_ref[0], do_ref[0])
+        dst = pt * (dpt - dvec_ref[0]) * scale
+        dk_acc[:] = dk_acc[:] + _dot(dst.astype(q_ref.dtype), q_ref[0])
 
-    if window is not None:
-        pl.when(qj < n_q)(_compute)
-    elif causal:
-        # skip q blocks entirely ABOVE this k block's diagonal
-        pl.when(qj * block_q + block_q - 1 >= kb * block_k)(_compute)
-    else:
-        _compute()
+    _run(runs, _compute)
 
-    @pl.when(step == n_qb - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -351,51 +437,120 @@ def _bwd_dkv_causal_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref,
 def _count_tiles(causal, tq, tk, bq, bk, tile, kernels: int,
                  window=None) -> None:
     """The schedule is static, so it is counted where it is built: a
-    causal call adds the tile pairs its kernels execute and the tile
-    pairs in all, once for each of the ``kernels`` it puts into the
+    causal call adds, once for each of the ``kernels`` it puts into the
     program, to the default registry's ``flash.causal_tiles_executed`` /
-    ``flash.causal_tiles_total`` (10 and 16 a kernel at T = 1,024); a
+    ``flash.causal_tiles_total`` the tile pairs its kernels execute and
+    the tile pairs in all (10 and 16 a kernel at T = 1,024; 136 and 256
+    at T = 8,192, 512-blocks), to ``flash.causal_grid_steps`` the pairs
+    its walk takes a step for (the same number on both walks: neither
+    steps where there is no work) and to ``flash.causal_tiles_masked``
+    those it builds a mask in (4 and 16: the diagonal's); a
     sliding-window call to ``flash.window_tiles_executed`` /
     ``flash.window_tiles_total`` instead (31 of 256 at T = 8,192,
     window 512)."""
     if not causal or sizing():  # the recompute plan's own trace of a child
         return
+    registry = default_registry()
     if window is not None:
         band, n = _band_blocks(window, bq), tq // bq
-        registry = default_registry()
         registry.counter("flash.window_tiles_executed").inc(
             kernels * sum(min(band, qi + 1) for qi in range(n)))
         registry.counter("flash.window_tiles_total").inc(kernels * n * n)
         return
     if tile is not None:
-        executed = sum((hi - lo) // tile
-                       for _, lo, hi in _causal_schedule(tq, tile))
+        schedule = _causal_schedule(tq, tile)
+        executed = sum((hi - lo) // tile for _, lo, hi in schedule)
+        masked = len(schedule)
         total = (tq // tile) ** 2
     else:
+        table = _walk_table(tq, tk, bq, bk)
+        executed = len(table)
+        masked = sum(masked for *_, masked in table)
         total = (tq // bq) * (tk // bk)
-        executed = sum(1 for qi in range(tq // bq) for kb in range(tk // bk)
-                       if kb * bk <= qi * bq + bq - 1)
-    registry = default_registry()
     registry.counter("flash.causal_tiles_executed").inc(kernels * executed)
     registry.counter("flash.causal_tiles_total").inc(kernels * total)
+    registry.counter("flash.causal_grid_steps").inc(kernels * executed)
+    registry.counter("flash.causal_tiles_masked").inc(kernels * masked)
 
 
 def _whole(t, dh):
     return pl.BlockSpec((1, t, dh), lambda b: (b, 0, 0))
 
 
-def _kv_index(window, band):
-    """Index map of the operand the last grid axis walks in the forward
-    and dQ kernels: step j is block j, or with a ``window`` the band's
-    block i - (band - 1) + j, held at 0 where that falls before the
-    sequence (the kernel skips the step; the block is already there)."""
-    if window is None:
-        return lambda b, i, j: (b, j, 0)
-    return lambda b, i, j: (b, jnp.maximum(i - (band - 1) + j, 0), 0)
-
-
 def _whole_row(t):
     return pl.BlockSpec((1, 1, t), lambda b: (b, 0, 0))
+
+
+def _walk(causal, tq, tk, bq, bk, window, by_keys=False):
+    """The grid a grid-walk kernel runs on, from what the launcher can
+    see: ``(walk, grid, at, prefetch)`` — what ``_step`` reads in the
+    kernel, the grid's axes after batch·head, the index maps of a block
+    on the query side (``"q"``), on the key side (``"k"``) and of a query
+    block of ``lse`` / ``dvec`` on lanes (``"row"``), and the
+    scalar-prefetched operands.  A row of the grid is a query block, or
+    ``by_keys`` (the dK/dV kernel) a key block.
+
+    * a causal call: (steps,) over ``_walk_table``, whose three columns
+      are prefetched and read by every index map;
+    * a sliding window: (rows, band) — the band's blocks alone, the
+      index held at the sequence's end where a step falls off it (the
+      kernel skips the step; the block is already there);
+    * any other call: the dense (rows, blocks)."""
+    n_q, n_k = tq // bq, tk // bk
+    if causal and window is None:
+        table = _walk_table(tq, tk, bq, bk, by_keys)
+        kinds = {masked for *_, masked in table}
+        at = {"q": lambda b, s, qi, kb, flags: (b, qi[s], 0),
+              "k": lambda b, s, qi, kb, flags: (b, kb[s], 0),
+              "row": lambda b, s, qi, kb, flags: (b, 0, qi[s])}
+        prefetch = tuple(
+            jnp.asarray(column, jnp.int32) for column in zip(*(
+                (qi, kb, first * _FIRST | last * _LAST | masked * _MASKED)
+                for qi, kb, first, last, masked in table)))
+        return (("table", False in kinds, True in kinds), (len(table),), at,
+                prefetch)
+    walk, cols = None, n_q if by_keys else n_k
+    if window is not None:
+        walk, cols = ("band", by_keys, n_q), _band_blocks(window, bk)
+
+    def walked(i, j):  # the block of the other side that step j meets
+        if window is None:
+            return j
+        return jnp.minimum(i + j, n_q - 1) if by_keys else jnp.maximum(
+            i - (cols - 1) + j, 0)
+
+    def q_of(i, j): return walked(i, j) if by_keys else i
+    def k_of(i, j): return i if by_keys else walked(i, j)
+    at = {"q": lambda b, i, j: (b, q_of(i, j), 0),
+          "k": lambda b, i, j: (b, k_of(i, j), 0),
+          "row": lambda b, i, j: (b, 0, q_of(i, j))}
+    return walk, (n_k if by_keys else n_q, cols), at, ()
+
+
+def _grid_walk(body, kernel, args, operands, outputs, out_shape, scratch, *,
+               causal, bq, bk, scale, window, interpret, by_keys=False):
+    """One grid-walk kernel, ``flash_<kernel>`` (``window_attn_<kernel>``
+    with a window: the names a trace row reads), on the grid ``_walk``
+    gives it.  ``operands`` and ``outputs`` name the kind of each block
+    of ``args`` and of the results: ``"q"`` / ``"k"`` a (block, Dh) tile
+    on the query / key side, ``"row"`` a query block of ``lse`` or
+    ``dvec`` on lanes."""
+    sides = dict(zip(operands, args))
+    (bh, tq, dh), tk = sides["q"].shape, sides["k"].shape[1]
+    walk, grid, at, prefetch = _walk(causal, tq, tk, bq, bk, window, by_keys)
+    shape = {"q": (1, bq, dh), "k": (1, bk, dh), "row": (1, 1, bq)}
+    in_specs, out_specs = ([pl.BlockSpec(shape[x], at[x]) for x in kinds]
+                           for kinds in (operands, outputs))
+    return pl.pallas_call(
+        functools.partial(body, scale=scale, block_q=bq, block_k=bk,
+                          window=window, walk=walk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch), grid=(bh, *grid),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        interpret=interpret,
+        name=f"flash_{kernel}" if window is None else f"window_attn_{kernel}",
+    )(*prefetch, *args)
 
 
 #: the launchers are jitted so that equal calls (a model's blocks) share
@@ -441,30 +596,14 @@ def _flash_fwd_raw(qr, kr, vr, *, causal, bq, bk, scale, tile, interpret,
             interpret=interpret,
             name="flash_fwd",
         )(qr, kr, vr)
-    kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale,
-                               block_q=bq, block_k=bk, window=window)
-    band = tk // bk if window is None else _band_blocks(window, bk)
-    kv_spec = pl.BlockSpec((1, bk, dh), _kv_index(window, band))
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(bh, tq // bq, band),
-        in_specs=[
-            pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
-        ],
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32),
-                        pltpu.VMEM((bq, 128), jnp.float32),
-                        pltpu.VMEM((bq, 128), jnp.float32)],
-        interpret=interpret,
-        name="flash_fwd" if window is None else "window_attn_fwd",
-    )(qr, kr, vr)
-    return out, lse
+    return _grid_walk(
+        _fwd_kernel, "fwd", (qr, kr, vr), ["q", "k", "k"], ["q", "row"],
+        out_shape,
+        [pltpu.VMEM((bq, dh), jnp.float32),
+         pltpu.VMEM((bq, 128), jnp.float32),
+         pltpu.VMEM((bq, 128), jnp.float32)],
+        causal=causal, bq=bq, bk=bk, scale=scale, window=window,
+        interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=_LAUNCHER_STATICS)
@@ -499,60 +638,17 @@ def _flash_bwd_raw(qr, kr, vr, do, lse, dvec, *, causal, bq, bk, scale,
         )(kr, vr, qr, do, lse, dvec)
         return dq, dk, dv
 
-    n_q = tq // bq
-    band = None if window is None else _band_blocks(window, bk)
-    kv_spec = pl.BlockSpec((1, bk, dh), _kv_index(window, band))
-    if window is None:
-        def q_walk(b, i, j):  # the dK/dV kernel's q, dO, lse, dvec blocks
-            return j
-    else:
-        def q_walk(b, i, j):  # band: query block i + j, held at the last
-            return jnp.minimum(i + j, n_q - 1)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
-                          block_q=bq, block_k=bk, window=window),
-        grid=(bh, n_q, band or tk // bk),
-        in_specs=[
-            pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),  # q
-            kv_spec,                                               # k
-            kv_spec,                                               # v
-            pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),  # do
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),   # lse
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),   # dvec
-        ],
-        out_specs=pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
-        out_shape=dq_shape,
-        scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
-        interpret=interpret,
-        name="flash_bwd_dq" if window is None else "window_attn_bwd_dq",
-    )(*operands)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
-                          block_q=bq, block_k=bk, window=window, n_q=n_q),
-        grid=(bh, tk // bk, band or n_q),
-        in_specs=[
-            pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, i, 0)),  # k
-            pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, i, 0)),  # v
-            pl.BlockSpec((1, bq, dh),
-                         lambda b, i, j: (b, q_walk(b, i, j), 0)),  # q
-            pl.BlockSpec((1, bq, dh),
-                         lambda b, i, j: (b, q_walk(b, i, j), 0)),  # do
-            pl.BlockSpec((1, 1, bq),
-                         lambda b, i, j: (b, 0, q_walk(b, i, j))),  # lse
-            pl.BlockSpec((1, 1, bq),
-                         lambda b, i, j: (b, 0, q_walk(b, i, j))),  # dvec
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=dkv_shape,
-        scratch_shapes=[pltpu.VMEM((bk, dh), jnp.float32),
-                        pltpu.VMEM((bk, dh), jnp.float32)],
-        interpret=interpret,
-        name="flash_bwd_dkv" if window is None else "window_attn_bwd_dkv",
-    )(kr, vr, qr, do, lse, dvec)
+    statics = dict(causal=causal, bq=bq, bk=bk, scale=scale, window=window,
+                   interpret=interpret)
+    dq, = _grid_walk(
+        _bwd_dq_kernel, "bwd_dq", operands,
+        ["q", "k", "k", "q", "row", "row"], ["q"], [dq_shape],
+        [pltpu.VMEM((bq, dh), jnp.float32)], **statics)
+    dk, dv = _grid_walk(
+        _bwd_dkv_kernel, "bwd_dkv", (kr, vr, qr, do, lse, dvec),
+        ["k", "k", "q", "q", "row", "row"], ["k", "k"], dkv_shape,
+        [pltpu.VMEM((bk, dh), jnp.float32),
+         pltpu.VMEM((bk, dh), jnp.float32)], by_keys=True, **statics)
     return dq, dk, dv
 
 
@@ -590,6 +686,9 @@ def _auto_block(t: int, dh: int) -> int:
     call of one length does not come here for its tiles where
     ``_causal_tile`` engages the in-kernel walk: one 1,024² block at
     T = 1,024 has no block to skip, which is why that walk exists.
+    Where it does come here (T = 8,192: 512-blocks at head 128), the
+    block is also the grain the causal grid walk skips and masks at:
+    ``_walk_table`` takes a step for 136 of the 256 pairs.
 
     Only blocks Mosaic can tile come back (see :func:`_tileable`); any
     other T is refused — ``ops.attention`` pads such causal lengths to a
@@ -610,8 +709,10 @@ def _blocks(q, k, causal, block_q, block_k, window=None):
     """(block_q, block_k, tile) for (B, T, H, Dh) operands: the grid
     walk's blocks, and the in-kernel causal walk's tile where it engages
     (default blocks, causal, one length, no window; see
-    ``_causal_tile``) — else None and the blocks decide.  A sliding
-    window keeps the grid walk, over the band's blocks alone."""
+    ``_causal_tile``) — else None and the blocks decide: ``_walk``
+    builds the grid from them, ``_walk_table``'s steps for a causal call
+    (the block pairs at or below the diagonal), the band's blocks for a
+    sliding window, the dense grid for any other."""
     tq, tk, dh = q.shape[1], k.shape[1], q.shape[3]
     if window is not None and not (causal and tq == tk and window >= 1):
         raise ValueError(
@@ -653,8 +754,11 @@ def flash_attention(q, k, v, causal: bool = False, block_q=None,
     the diagonal run, ``_causal_tile``); every other call the grid walk
     with ``_auto_block``'s blocks, the largest VMEM-fitting block
     dividing T — large blocks are where that walk beats XLA dense (see
-    BASELINE.md flash-vs-dense ladder).  Interpret mode is selected
-    automatically off TPU.
+    BASELINE.md flash-vs-dense ladder).  On the grid walk a causal call
+    takes a grid step only for the block pairs with a key at or before
+    a query (136 of 256 at T = 8,192, 512-blocks) and masks only those
+    the diagonal crosses (16), whatever the two blocks' sizes.
+    Interpret mode is selected automatically off TPU.
     ``window`` (static, causal self-attention only): a query sees the
     keys at most ``window - 1`` positions before it and itself.  The
     grid then walks only the key blocks that band touches (2 of 16 at

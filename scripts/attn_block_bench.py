@@ -38,15 +38,51 @@ dQ + dK/dV (the same runs):
     in-kernel walk, tile 128: 9.28       tile 512: 10.17
     an online softmax over runs of keys: 10.13
 
+The T = 8,192, head 128 ladder (PR 33's builder's chip runs, 3 Oct 2026;
+1x v5e, the Laguna cell's full layers: ``--kernels --batch 1 --seq 8192
+--heads 48 --dh 128``, bf16, causal, default 512-blocks; the kernels alone
+from a device trace, us a batch·head, forward + dQ + dK/dV):
+
+    grid walk, every pair a step (PR 32's tree):
+                                    293.86 + 230.12 + 286.08 = 810.07
+    table walk (136 of 256 pairs a step, 16 masked), bodies as before:
+                                    252.13 + 183.04 + 257.35 = 692.51
+      + dK/dV builds S, dP transposed:        ... + 232.85 = 668.01
+      + running max / sum as (512, 1) columns: 252.12 (no change)
+      + running max / sum lane-replicated (the default since PR 33):
+                                    152.68 + 183.22 + 233.12 = 569.02
+    the table packed into one int32 a step:   154.52 + 185.03 + 235.39
+    a fori_loop over resident K/V (q / dO), carried accumulators:
+                                    157.09 + 171.64 + 249.88 = 578.62
+      the same with its accumulators in VMEM scratch: forward 232.91
+    table grid, K/V whole-resident (no K/V DMA a step): forward 253.56
+    dense grid, no mask (256 steps): 444.23 + 315.70 + 406.90
+    table walk at 256² blocks: 1135.96   at 1,024² blocks: 538.49
+    (``_auto_block`` caps head 128 at 512: the window layers share it)
+
+and the window layers (``--heads 64 --window 512``, 31 of 256 pairs):
+
+    band walk (PR 32's tree):        68.80 + 45.87 + 59.57 = 174.24
+    band walk, PR 33's bodies:       50.39 + 45.56 + 56.72 = 152.66
+    the same on the table walk:      52.14 + 48.10 + 58.76 = 159.00
+
+What the ladder says: an idle grid step costs 0.2-0.35 us and a table
+step about 0.08 us more than an arithmetic one (so the window, with one
+idle step in 32, keeps its band); the forward's time was not its mask
+but its statistics (a lane gather and a rotate a vreg a step to keep
+them as a column); K/V DMA a step is free (hidden under the block).
+
 "flash default" passes no blocks: causal lengths that fit VMEM whole take
 the in-kernel causal walk (``_causal_tile``), every other call the grid
 walk with ``_auto_block``'s blocks.  Explicit blocks are always the grid
-walk, where a skipped block still pays its grid step and its K/V DMA:
-that is why smaller blocks lose there, and why the causal walk moved
-inside the kernel.
+walk; since PR 33 a causal call there takes a grid step only for the
+block pairs with work (``_walk_table``), so smaller blocks lose by their
+per-step overhead and small matmuls alone, no longer by idle steps.
 
 Usage: python scripts/attn_block_bench.py [--seq 8192] [--dh 64]
        python scripts/attn_block_bench.py --seq 1024 --batch 32 --heads 12
+       python scripts/attn_block_bench.py --kernels --batch 1 --heads 48 \
+           --dh 128 [--window 512]
 """
 
 import argparse
@@ -66,6 +102,9 @@ def main():
     ap.add_argument("--dh", type=int, default=64)
     ap.add_argument("--iters", type=int, default=24)
     ap.add_argument("--dtype", default="bfloat16")
+    ap.add_argument("--window", type=int, default=None)
+    ap.add_argument("--kernels", action="store_true",
+                    help="the Mosaic kernels alone, from a device trace")
     args = ap.parse_args()
 
     import numpy as np
@@ -105,6 +144,52 @@ def main():
         t0 = time.perf_counter()
         fence(f(x))
         return time.perf_counter() - t0
+
+    def kernels_alone(attn):
+        """us a batch·head of every Mosaic kernel of one forward +
+        backward: the rows a benchmark trace reads, by the same reader."""
+        import tempfile
+        sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+        import reduce_trace
+        g = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+            attn(q, k, v).astype(jnp.float32) ** 2), argnums=(0, 1, 2)))
+        fence(g(q0, k, v))
+        with tempfile.TemporaryDirectory() as trace_dir:
+            with jax.profiler.trace(trace_dir):
+                for _ in range(N):
+                    fence(g(q0, k, v))
+            devices = reduce_trace.load(
+                reduce_trace.newest_xplane(trace_dir))["devices"]
+        if 0 not in devices:
+            raise SystemExit("no device plane in the trace: a kernel's "
+                             "time comes from a chip run")
+        ops = devices[0][reduce_trace.OPS_LINE]
+        total = {}
+        for name, start, end in ops:
+            if reduce_trace.MOSAIC in name:
+                name = reduce_trace.op_name(name).split(":")[1]
+                total[name] = total.get(name, 0.0) + (end - start)
+        return {name: ns / 1e3 / N / (B * H) for name, ns in total.items()}
+
+    if args.kernels:
+        walks = [("default", ())] + [
+            (f"{b}x{b}", (b, b)) for b in (256, 512, 1024)
+            if T % b == 0 and args.window is None]
+        for label, blocks in walks:
+            blocks = blocks or (None, None)
+            try:
+                us = kernels_alone(
+                    lambda q, k, v, blocks=blocks: flash_attention(
+                        q, k, v, True, *blocks, args.window))
+            except Exception as e:  # noqa: BLE001 — a block VMEM refuses
+                print(f"kernels {label}: {str(e).splitlines()[0][:120]}")
+                continue
+            print(f"kernels {label} (blocks, tile "
+                  f"{_blocks(q0, k, True, *blocks, args.window)}): "
+                  + "  ".join(f"{n} {t:.2f}" for n, t in sorted(us.items()))
+                  + f"  sum {sum(us.values()):.2f} us a batch·head",
+                  flush=True)
+        return
 
     d = lambda q, k, v: dot_product_attention(q, k, v, causal=True)  # noqa
     print(f"dense: fwd {measure(d, 'fwd'):.2f} ms  "

@@ -358,3 +358,54 @@ def test_the_router_scores_in_float32_under_mixed_precision(monkeypatch):
          jax.random.PRNGKey(0)), (x, x)))
     assert seen == [(jnp.bfloat16, jnp.float32)]
     assert "bf16[4,32,48]" in text  # the held experts' gate_up, cast
+
+
+def test_a_step_past_the_vmem_budget_walks_the_causal_grid(monkeypatch):
+    """The rehearsal's Laguna step is short enough for the in-kernel
+    walk, so the causal GRID walk (the 8k cell's full layers) is driven
+    through the model here: the budget forced to 0 sends a full layer of
+    T = 1,024, head 128 down it in 512-blocks (three pairs walked, one
+    left out, two masked), beside a window layer, GQA and the gate,
+    under ``remat``'s kept kernel outputs — loss and every gradient leaf
+    against the dense implementation."""
+    from distkeras_tpu.ops import pallas_attention
+    from distkeras_tpu.ops.losses import sparse_categorical_crossentropy
+    monkeypatch.setattr(pallas_attention, "_CAUSAL_VMEM_BUDGET", 0)
+    sizes = dict(
+        SIZES, num_hidden_layers=2, seq_len=1024, head_dim=128,
+        layer_types=["full_attention", "sliding_attention"],
+        num_attention_heads_per_layer=[4, 4], mlp_layer_types=["dense"] * 2,
+        sliding_window=300)
+    q = jnp.ones((1, 1024, 4, 128), jnp.float32)
+    assert pallas_attention._blocks(q, q, True, None, None) == (512, 512,
+                                                                None)
+    flash = zoo.decoder_lm(**sizes, attention_impl="flash")
+    dense = zoo.decoder_lm(**sizes, attention_impl="dense")
+    variables = flash.init(5)
+    x, y = tokens(6, (1, 1024)), tokens(7, (1, 1024))
+
+    def loss(model, remat):
+        def go(params):
+            out, _ = model.layer.apply(params, variables["state"], x,
+                                       train=True, remat=remat)
+            return sparse_categorical_crossentropy(out, y)
+        return jax.jit(jax.value_and_grad(go))
+
+    names = ("grid_steps", "tiles_masked", "tiles_executed", "tiles_total")
+    counters = [default_registry().counter(f"flash.causal_{name}")
+                for name in names]
+    before = [c.value for c in counters]
+    got_loss, got = loss(flash, True)(variables["params"])
+    steps, masked, executed, total = (
+        c.value - b for c, b in zip(counters, before))
+    # the forward's kernel and the backward's two, the full layer alone
+    assert steps == executed and steps % 3 == 0 and steps >= 9
+    assert (masked, total) == (steps // 3 * 2, steps // 3 * 4)
+    want_loss, want = loss(dense, False)(variables["params"])
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-5)
+    got, _ = jax.tree_util.tree_flatten_with_path(got)
+    for (path, a), b in zip(got, jax.tree_util.tree_leaves(want)):
+        assert float(jnp.max(jnp.abs(b))) > 0, path  # the leaf is used
+        np.testing.assert_allclose(
+            a, b, rtol=2e-3, atol=2e-5 * float(jnp.max(jnp.abs(b))) + 1e-8,
+            err_msg=jax.tree_util.keystr(path))

@@ -183,26 +183,18 @@ def _dense_lse(q, k, v, causal):
         jax.scipy.special.logsumexp(s, axis=-1)
 
 
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("with_lse", [False, True],
-                         ids=["flash_attention", "flash_attention_lse"])
-@pytest.mark.parametrize("t", [256, 384, 640])
-def test_default_block_causal_matches_dense(t, with_lse, dtype):
-    """Default blocks + causal take the in-kernel walk (tile 128: 2, 3
-    and 5 tiles a side, unmasked tiles before a masked diagonal): values
-    and all three gradients against dense attention, the ``lse`` output
-    and its cotangent included."""
-    from distkeras_tpu.ops.pallas_attention import (_blocks,
-                                                    flash_attention_lse)
+def _check_causal_against_dense(t, with_lse, dtype, blocks=(None, None)):
+    """Values and all three gradients of a causal call against dense
+    attention, the ``lse`` output and its cotangent included."""
+    from distkeras_tpu.ops.pallas_attention import flash_attention_lse
     q, k, v = qkv(b=1, t=t, h=2, dh=32, seed=t)
     xs = tuple(a.astype(dtype) for a in (q, k, v))
-    assert _blocks(xs[0], xs[1], True, None, None)[2] is not None
     val, grad = ((2e-5, 5e-4) if dtype == "float32" else (0.06, 0.15))
 
     def flash(q, k, v):
         if with_lse:
-            return flash_attention_lse(q, k, v, True)
-        return flash_attention(q, k, v, True), None
+            return flash_attention_lse(q, k, v, True, *blocks)
+        return flash_attention(q, k, v, True, *blocks), None
 
     def loss(fn):
         def go(q, k, v):
@@ -226,6 +218,103 @@ def test_default_block_causal_matches_dense(t, with_lse, dtype):
     for a, b in zip(g, g_r):
         np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
                                    rtol=grad, atol=max(grad / 10, 5e-5))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("with_lse", [False, True],
+                         ids=["flash_attention", "flash_attention_lse"])
+@pytest.mark.parametrize("t", [256, 384, 640])
+def test_default_block_causal_matches_dense(t, with_lse, dtype):
+    """Default blocks + causal take the in-kernel walk (tile 128: 2, 3
+    and 5 tiles a side, unmasked tiles before a masked diagonal): values
+    and all three gradients against dense attention, the ``lse`` output
+    and its cotangent included."""
+    from distkeras_tpu.ops.pallas_attention import _blocks
+    x = jnp.ones((1, t, 2, 32), dtype)
+    assert _blocks(x, x, True, None, None)[2] is not None
+    _check_causal_against_dense(t, with_lse, dtype)
+
+
+# ---------------------------------------------------------------------------
+# the causal grid walk (explicit blocks, or a length past the VMEM budget):
+# a grid step only for the block pairs with work, a mask only on the diagonal
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("with_lse", [False, True],
+                         ids=["flash_attention", "flash_attention_lse"])
+@pytest.mark.parametrize("bq,bk", [(128, 128), (256, 128), (128, 256)])
+def test_causal_grid_walk_matches_dense(bq, bk, with_lse, dtype):
+    """T = 512 in explicit blocks walks ``_walk_table``'s steps: 10 of 16
+    pairs at 128², 4 of them masked; 6 of 8 with two masked a row where
+    the blocks are not square.  Values, the three gradients, ``lse`` and
+    its cotangent against dense attention."""
+    _check_causal_against_dense(512, with_lse, dtype, (bq, bk))
+
+
+@pytest.mark.parametrize("t", [257, 300])
+def test_causal_grid_walk_through_the_padded_awkward_length(t, monkeypatch):
+    """A length with no block pads to 384 and, past the VMEM budget
+    (forced here), takes the grid walk in 128-blocks: exact, gradients
+    included, as on the in-kernel walk."""
+    from distkeras_tpu.ops import pallas_attention
+    from distkeras_tpu.ops.attention import _flash_with_blocking
+    monkeypatch.setattr(pallas_attention, "_CAUSAL_VMEM_BUDGET", 0)
+    q, k, v = qkv(b=1, t=t, h=2, dh=16, seed=t)
+    x = jnp.ones((1, 384, 2, 16), jnp.float32)
+    assert pallas_attention._blocks(x, x, True, None, None) == (128, 128,
+                                                                None)
+    flash = lambda q, k, v: _flash_with_blocking(q, k, v, True, t)  # noqa
+    dense = lambda q, k, v: dot_product_attention(q, k, v, causal=True)  # noqa
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), rtol=2e-5,
+                               atol=2e-5)
+    for a, b in zip(
+            jax.grad(lambda *x: jnp.sum(flash(*x) ** 2), (0, 1, 2))(q, k, v),
+            jax.grad(lambda *x: jnp.sum(dense(*x) ** 2), (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(a, b, rtol=5e-4, atol=1e-5)
+
+
+#: (T, block_q, block_k): Laguna's full layers; square blocks; blocks
+#: that are not (a row then holds two masked pairs, or a pair holds two
+#: rows' diagonals); one block
+TABLES = [(8192, 512, 512), (512, 128, 128), (512, 256, 128),
+          (512, 128, 256), (1024, 128, 512), (384, 128, 128),
+          (128, 128, 128)]
+
+
+@pytest.mark.parametrize("by_keys", [False, True], ids=["by_queries",
+                                                        "by_keys"])
+@pytest.mark.parametrize("t,bq,bk", TABLES)
+def test_walk_table_holds_every_needed_pair_once(t, bq, bk, by_keys):
+    """The schedule alone, no kernel: a step for every block pair with a
+    key at or before a query and for no other, each once; a row's steps
+    contiguous and ascending with one ``first`` and one ``last``; a mask
+    exactly where the block's last key is after its first query."""
+    from distkeras_tpu.ops.pallas_attention import _walk_table
+    table = _walk_table(t, t, bq, bk, by_keys=by_keys)
+    pairs = [(qi, kb) for qi, kb, *_ in table]
+    needed = {(qi, kb) for qi in range(t // bq) for kb in range(t // bk)
+              if kb * bk <= qi * bq + bq - 1}
+    assert len(pairs) == len(set(pairs)) and set(pairs) == needed
+    row_of, along = (1, 0) if by_keys else (0, 1)
+    rows = [step[row_of] for step in table]
+    assert rows == sorted(rows)  # contiguous, and every output block once
+    assert set(rows) == set(range(t // (bk if by_keys else bq)))
+    for row in set(rows):
+        steps = [step for step in table if step[row_of] == row]
+        walked = [step[along] for step in steps]
+        assert walked == sorted(walked)
+        assert [s[2] for s in steps] == [True] + [False] * (len(steps) - 1)
+        assert [s[3] for s in steps] == [False] * (len(steps) - 1) + [True]
+    for qi, kb, _, _, kind in table:
+        assert kind == (kb * bk + bk - 1 > qi * bq), (qi, kb)
+    if (t, bq, bk) == (8192, 512, 512):
+        assert len(table) == 136 and sum(s[4] for s in table) == 16
+        # dK/dV meets its diagonal block first, the forward and dQ last
+        assert table[1][:2] == (1, 0) and table[2][:2] == (
+            (2, 0) if by_keys else (1, 1))
+        assert [s[4] for s in table if s[row_of] == 3] == (
+            [1] + [0] * 12 if by_keys else [0, 0, 0, 1])
 
 
 def _eqns(jaxpr):
@@ -252,32 +341,35 @@ def _pallas_calls(fn, *args) -> dict:
         if e.primitive.name == "pallas_call"}
 
 
-def _grid_walk(bh, tq, tk, dh, bq, bk):
-    """What the three kernels were built with before the in-kernel walk
-    (PR 26's tree): a (bh, q blocks, k blocks) grid, the dK/dV kernel's
-    the other way round, one block of each operand a step."""
+def _grid_walk(bh, tq, tk, dh, bq, bk, steps=None):
+    """What the three grid-walk kernels are built with, one block of each
+    operand a step: a (bh, q blocks, k blocks) grid, the dK/dV kernel's
+    the other way round (PR 26's tree); for a causal call a (bh,
+    ``steps``) grid, the block pairs with work alone."""
     qb, kb, row = (1, bq, dh), (1, bk, dh), (1, 1, bq)
+    by_q = (bh, steps) if steps else (bh, tq // bq, tk // bk)
+    by_k = (bh, steps) if steps else (bh, tk // bk, tq // bq)
     return {
-        "flash_fwd": ((bh, tq // bq, tk // bk), [qb, kb, kb, qb, row]),
-        "flash_bwd_dq": ((bh, tq // bq, tk // bk),
-                         [qb, kb, kb, qb, row, row, qb]),
-        "flash_bwd_dkv": ((bh, tk // bk, tq // bq),
-                          [kb, kb, qb, qb, row, row, kb, kb]),
+        "flash_fwd": (by_q, [qb, kb, kb, qb, row]),
+        "flash_bwd_dq": (by_q, [qb, kb, kb, qb, row, row, qb]),
+        "flash_bwd_dkv": (by_k, [kb, kb, qb, qb, row, row, kb, kb]),
     }
 
 
-@pytest.mark.parametrize("tq,tk,dh,causal,bq,bk", [
-    (1024, 1024, 64, False, 1024, 1024),   # non-causal: a ring's far hop
-    (1024, 512, 64, False, 1024, 512),     # rectangular: a zigzag half hop
-    (384, 384, 32, False, 128, 128),       # non-causal, 3 blocks a side
-    (4096, 4096, 64, True, 1024, 1024),    # causal, K/V over the VMEM budget
-    (4096, 4096, 128, True, 512, 512),     # the same at head 128
-    (128, 128, 64, True, 128, 128),        # causal, one tile: nothing to skip
+@pytest.mark.parametrize("tq,tk,dh,causal,bq,bk,steps", [
+    (1024, 1024, 64, False, 1024, 1024, None),  # non-causal: a ring's far hop
+    (1024, 512, 64, False, 1024, 512, None),    # rectangular: a zigzag half hop
+    (384, 384, 32, False, 128, 128, None),      # non-causal, 3 blocks a side
+    (4096, 4096, 64, True, 1024, 1024, 10),  # causal, K/V over the VMEM budget
+    (4096, 4096, 128, True, 512, 512, 36),   # the same at head 128
+    (8192, 8192, 128, True, 512, 512, 136),  # Laguna's full layers
+    (128, 128, 64, True, 128, 128, 1),       # causal, one tile: nothing to skip
 ], ids=["noncausal", "rectangular", "noncausal-384", "causal-4096",
-        "causal-4096-dh128", "causal-128"])
-def test_other_calls_keep_the_grid_walk(tq, tk, dh, causal, bq, bk):
-    """Non-causal, rectangular and over-budget causal calls build the
-    ``pallas_call``s they built before: same grids, same blocks."""
+        "causal-4096-dh128", "causal-8192-dh128", "causal-128"])
+def test_other_calls_keep_the_grid_walk(tq, tk, dh, causal, bq, bk, steps):
+    """Non-causal and rectangular calls build the ``pallas_call``s they
+    built before: same dense grids, same blocks.  Over-budget causal
+    calls keep the blocks, on a grid of the pairs with work alone."""
     from distkeras_tpu.ops.pallas_attention import flash_attention_lse
     q = jnp.ones((1, tq, 2, dh), jnp.bfloat16)
     kv = jnp.ones((1, tk, 2, dh), jnp.bfloat16)
@@ -287,7 +379,7 @@ def test_other_calls_keep_the_grid_walk(tq, tk, dh, causal, bq, bk):
         return jnp.sum(o.astype(jnp.float32)) + jnp.sum(lse)
 
     assert _pallas_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) \
-        == _grid_walk(2, tq, tk, dh, bq, bk)
+        == _grid_walk(2, tq, tk, dh, bq, bk, steps)
 
 
 def test_explicit_blocks_keep_the_grid_walk_and_default_takes_the_kernel_walk():
@@ -299,7 +391,7 @@ def test_explicit_blocks_keep_the_grid_walk_and_default_takes_the_kernel_walk():
 
     grad = lambda *blocks: jax.grad(loss(*blocks), argnums=(0, 1, 2))  # noqa
     assert _pallas_calls(grad(256, 256), q, q, q) \
-        == _grid_walk(2, 1024, 1024, 64, 256, 256)
+        == _grid_walk(2, 1024, 1024, 64, 256, 256, steps=10)
     whole, row = (1, 1024, 64), (1, 1, 1024)
     assert _pallas_calls(grad(), q, q, q) == {
         "flash_fwd": ((2,), [whole] * 4 + [row]),
@@ -308,11 +400,11 @@ def test_explicit_blocks_keep_the_grid_walk_and_default_takes_the_kernel_walk():
     }
 
 
-def _tile_counts():
+def _tile_counts(names=("tiles_executed", "tiles_total")):
     from distkeras_tpu.obs.registry import default_registry
     registry = default_registry()
-    return (registry.counter("flash.causal_tiles_executed").value,
-            registry.counter("flash.causal_tiles_total").value)
+    return tuple(registry.counter(f"flash.causal_{name}").value
+                 for name in names)
 
 
 @pytest.mark.parametrize("dh", [64, 128])
@@ -370,3 +462,30 @@ def test_registry_counts_the_tiles_a_kernel_will_run(t, executed, total):
         == (3 * executed, 3 * total)
     jax.make_jaxpr(grad(False))(q, q, q)
     assert _tile_counts() == after
+
+
+@pytest.mark.parametrize("t,dh,blocks,steps,masked", [
+    (8192, 128, (), 136, 16),         # Laguna's full layers: the grid walk
+    (4096, 64, (), 10, 4),            # 1,024-blocks past the VMEM budget
+    (512, 64, (256, 128), 6, 4),      # explicit blocks, two masked a row
+    (1024, 64, (), 10, 4),            # the in-kernel walk: what it does
+    (128, 64, (), 1, 1),              # one block
+], ids=["laguna-full", "causal-4096", "explicit-256x128", "in-kernel-1024",
+        "one-block"])
+def test_registry_counts_the_steps_a_walk_takes_and_the_tiles_it_masks(
+        t, dh, blocks, steps, masked):
+    """``flash.causal_grid_steps`` / ``flash.causal_tiles_masked`` beside
+    the two above, once a kernel built: the grid walk takes a step only
+    where there is work (so its steps equal its executed tiles: 136 of
+    256 at T = 8,192) and masks only where the diagonal passes (16)."""
+    q = jnp.ones((1, t, 2, dh), jnp.bfloat16)
+    names = ("grid_steps", "tiles_masked", "tiles_executed")
+    grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, True, *blocks).astype(jnp.float32)), argnums=(0, 1, 2))
+    before = _tile_counts(names)
+    jax.make_jaxpr(grad)(q, q, q)
+    got = tuple(a - b for a, b in zip(_tile_counts(names), before))
+    assert got == (3 * steps, 3 * masked, 3 * steps)
+    jax.make_jaxpr(lambda q: flash_attention(q, q, q, False))(q)
+    assert _tile_counts(names) == tuple(
+        b + g for b, g in zip(before, got))
