@@ -67,6 +67,11 @@ def uniform_scale(rng, shape, scale=0.05, dtype=jnp.float32):
     return jax.random.uniform(rng, shape, dtype, -scale, scale)
 
 
+def relu2(x):
+    """Squared ReLU (So et al. 2021, Primer)."""
+    return jnp.square(jax.nn.relu(x))
+
+
 ACTIVATIONS: dict[str, Callable] = {
     "linear": lambda x: x,
     "relu": jax.nn.relu,
@@ -78,6 +83,7 @@ ACTIVATIONS: dict[str, Callable] = {
     "elu": jax.nn.elu,
     "silu": jax.nn.silu,
     "leaky_relu": jax.nn.leaky_relu,
+    "relu2": relu2,
 }
 
 
@@ -266,6 +272,11 @@ def swiglu(x, gate_up, down):
     h = x @ gate_up.astype(x.dtype)
     f = h.shape[-1] // 2
     return (jax.nn.silu(h[..., :f]) * h[..., f:]) @ down.astype(x.dtype)
+
+
+def relu2_mlp(x, up, down):
+    """``relu(x W_up)^2 W_down``: the ungated squared-ReLU feed-forward."""
+    return relu2(x @ up.astype(x.dtype)) @ down.astype(x.dtype)
 
 
 @register

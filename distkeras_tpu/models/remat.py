@@ -6,9 +6,10 @@ decided here, where the step is traced and the shapes are known, from
 what the code can observe (shapes, dtypes, the device's memory limit):
 
 1. every ``jax.checkpoint`` the step builds (:func:`checkpoint`) keeps the
-   attention kernels' outputs (``ops.pallas_attention`` tags them with
-   :func:`name_kernel_outputs`): a recomputed child rebuilds q, k, v and
-   reads ``out`` / ``lse`` as kept, so the forward kernels run once;
+   attention and scan kernels' outputs (``ops.pallas_attention`` and
+   ``ops.pallas_ssm`` tag them with :func:`name_kernel_outputs`): a recomputed
+   child rebuilds the kernels' inputs and reads ``out`` / ``lse`` (``y``
+   and the chunks' states) as kept, so the forward kernels run once;
 2. the last child of a ``Sequential`` is never wrapped: its backward
    begins where its forward ends, a checkpoint there buys no memory;
 3. whole children are kept, from the last one backward, while an estimate
@@ -37,9 +38,14 @@ from jax.extend.core import Var
 from ..obs.registry import default_registry
 from ..obs.spans import default_tracer
 
-#: the names the forward kernels' outputs carry out of
-#: ``pallas_attention._vjp_fwd``; outside a checkpoint they lower to nothing
-KERNEL_OUTPUTS = ("flash_out", "flash_lse")
+#: the names the forward kernels' outputs carry out of their custom VJPs'
+#: forward rules, by kernel family (``pallas_attention._vjp_fwd``: the
+#: output and its row statistics; ``pallas_ssm._ssd_pallas_fwd``: the output and
+#: the chunks' incoming states); outside a checkpoint they lower to nothing
+KERNEL_OUTPUT_NAMES = {"flash": ("flash_out", "flash_lse"),
+                       "ssd": ("ssd_out", "ssd_state")}
+KERNEL_OUTPUTS = tuple(n for names in KERNEL_OUTPUT_NAMES.values()
+                       for n in names)
 #: the share of the device's limit that the estimate may fill
 FILL = 0.92
 #: a compiled program over this share of the limit: one child back
@@ -65,14 +71,16 @@ def sizing() -> bool:
     return getattr(_SIZING, "on", False)
 
 
-def name_kernel_outputs(out, lse):
-    return (checkpoint_name(out, KERNEL_OUTPUTS[0]),
-            checkpoint_name(lse, KERNEL_OUTPUTS[1]))
+def name_kernel_outputs(*outputs, kernel: str = "flash"):
+    """A forward kernel's outputs, tagged so that :func:`checkpoint`
+    keeps them."""
+    return tuple(checkpoint_name(o, n) for o, n in
+                 zip(outputs, KERNEL_OUTPUT_NAMES[kernel], strict=True))
 
 
 def checkpoint(fn):
     """``jax.checkpoint`` as the step builds it: everything is recomputed
-    but the attention kernels' outputs."""
+    but the forward kernels' named outputs."""
     return jax.checkpoint(
         fn, policy=jax.checkpoint_policies.save_only_these_names(
             *KERNEL_OUTPUTS))

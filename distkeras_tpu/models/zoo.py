@@ -344,6 +344,88 @@ def decoder_lm(vocab_size: int, hidden_size: int, num_hidden_layers: int,
                  name="decoder_lm")
 
 
+def hybrid_lm(vocab_size: int, hidden_size: int, num_hidden_layers: int,
+              hybrid_override_pattern: str, seq_len: int,
+              mamba_num_heads: int = 0, mamba_head_dim: int = 0,
+              ssm_state_size: int = 0, n_groups: int = 1,
+              conv_kernel: int = 4, chunk_size: int = 128,
+              num_attention_heads: int = 0, num_key_value_heads: int = 0,
+              head_dim: int = 0, intermediate_size: int = 0,
+              n_routed_experts: int = 0, num_experts_per_tok: int = 1,
+              moe_intermediate_size: int = 0,
+              moe_shared_expert_intermediate_size: int = 0,
+              routed_scaling_factor: float = 1.0,
+              norm_topk_prob: bool = True,
+              layer_norm_epsilon: float = 1e-5,
+              experts_held: Optional[int] = None, first_expert: int = 0,
+              attention_impl: str = "dense",
+              ssm_impl: str = "chunked") -> Model:
+    """Decoder-only language model with ONE mixer a layer, by pattern (the
+    ``nemotron_h`` family; the keyword names are its ``config.json``'s):
+    layer l is ``x + mixer_l(RMSNorm(x))``, a final RMSNorm and an untied
+    head follow, and no linear layer has a bias.  Logits, no softmax.
+
+    ``hybrid_override_pattern[l]`` (its first ``num_hidden_layers``
+    letters are built) names the mixer: ``M`` a Mamba-2 mixer
+    (``ops.ssm.Mamba2Mixer``: ``mamba_num_heads`` heads of
+    ``mamba_head_dim``, state ``ssm_state_size``, ``n_groups``,
+    ``conv_kernel`` taps, the scan in chunks of ``chunk_size`` by
+    ``ssm_impl``); ``*`` causal attention of ``num_attention_heads`` query
+    heads of ``head_dim`` over ``num_key_value_heads`` K/V heads, with no
+    positional term of any kind; ``E`` a mixture of experts
+    (``ops.moe.SparseMoE``: sigmoid scores with a choice-only bias, top
+    ``num_experts_per_tok`` of ``n_routed_experts`` relu² experts of width
+    ``moe_intermediate_size``, weights normalised if ``norm_topk_prob``
+    and times ``routed_scaling_factor``, plus a shared expert of width
+    ``moe_shared_expert_intermediate_size``); ``-`` a dense relu² MLP of
+    width ``intermediate_size``.
+
+    ``experts_held`` / ``first_expert``: one chip's share of an
+    expert-parallel deployment, as in :func:`decoder_lm`."""
+    from ..ops.attention import MultiHeadAttention
+    from ..ops.moe import SparseMoE
+    from ..ops.ssm import Mamba2Mixer
+    from .layers import RMSNorm
+
+    def mixer(kind: str):
+        if kind == "M":
+            return Mamba2Mixer(mamba_num_heads, mamba_head_dim,
+                               ssm_state_size, n_groups=n_groups,
+                               conv_kernel=conv_kernel,
+                               chunk_size=chunk_size,
+                               norm_eps=layer_norm_epsilon, impl=ssm_impl)
+        if kind == "*":
+            return MultiHeadAttention(
+                num_attention_heads, causal=True, impl=attention_impl,
+                num_kv_heads=num_key_value_heads, head_dim=head_dim,
+                rope=False)
+        if kind == "E":
+            return SparseMoE(
+                n_routed_experts, num_experts_per_tok, moe_intermediate_size,
+                shared_hidden=moe_shared_expert_intermediate_size,
+                routed_scale=routed_scaling_factor, normalise=norm_topk_prob,
+                experts_held=experts_held, first_expert=first_expert,
+                expert_activation="relu2", scoring="sigmoid")
+        if kind == "-":
+            return Sequential([
+                Dense(intermediate_size, "relu2", use_bias=False),
+                Dense(hidden_size, use_bias=False)])
+        raise ValueError(f"unknown layer kind {kind!r} in "
+                         f"hybrid_override_pattern (M, E, * or -)")
+
+    if num_hidden_layers > len(hybrid_override_pattern):
+        raise ValueError(f"{num_hidden_layers} layers of a pattern of "
+                         f"{len(hybrid_override_pattern)}")
+    layers = [Embedding(vocab_size, hidden_size)]
+    for kind in hybrid_override_pattern[:num_hidden_layers]:
+        layers.append(Residual(Sequential([RMSNorm(layer_norm_epsilon),
+                                           mixer(kind)])))
+    layers += [RMSNorm(layer_norm_epsilon),
+               Dense(vocab_size, use_bias=False)]
+    return Model(Sequential(layers), input_shape=(seq_len,),
+                 name="hybrid_lm")
+
+
 def draft_lm(target: Model, dim: int = 32, num_heads: int = 2,
              num_blocks: int = 1, ff_mult: int = 4,
              positional: str = "learned") -> Model:

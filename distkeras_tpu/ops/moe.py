@@ -252,21 +252,37 @@ def _take_rows_bwd(res, g):
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-def route_top_k(x, kernel, k: int, *, normalise: bool, scale: float):
-    """Router scores in float32: softmax over ALL experts, the k largest,
-    their weights (divided by their sum if ``normalise``) times
-    ``scale``.  The tokens are cast to float32 and multiply the float32
-    kernel at HIGHEST (a TPU's default matmul would round both to bf16);
-    under mixed precision the trainers hand the router its master weights
-    (``parallel.sync.make_local_step`` leaves ``router`` leaves uncast).
-    -> (idx (N, k), weights (N, k) float32, probabilities (N, E))."""
+def route_top_k(x, kernel, k: int, *, normalise: bool, scale: float,
+                bias=None):
+    """Router scores in float32 over ALL experts, the k best, their
+    weights (divided by their sum if ``normalise``) times ``scale``.
+    ``bias`` None: softmax probabilities, the k largest.  ``bias``
+    (num_experts,): sigmoid scores (DeepSeek-V3's router), the k largest
+    of score + bias; the bias bears on the choice alone, the weights are
+    the scores themselves, so it has no gradient.  The tokens are cast to
+    float32 and multiply the float32 kernel at HIGHEST (a TPU's default
+    matmul would round both to bf16); under mixed precision the trainers
+    hand the router its master weights (``parallel.sync.make_local_step``
+    leaves ``router`` leaves uncast).
+    -> (idx (N, k), weights (N, k) float32, probabilities (N, E): the
+    scores over their sum where they are sigmoids)."""
     logits = jnp.dot(x.astype(jnp.float32), kernel.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, idx = lax.top_k(probs, k)
+    if bias is None:
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, idx = lax.top_k(probs, k)
+        if normalise:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        return idx, weights * scale, probs
+    scores = jax.nn.sigmoid(logits)
+    _, idx = lax.top_k(scores + lax.stop_gradient(
+        bias.astype(jnp.float32)), k)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
     if normalise:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    return idx, weights * scale, probs
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + 1e-20)
+    return idx, weights * scale, \
+        scores / jnp.sum(scores, axis=-1, keepdims=True)
 
 
 def routed_experts(x, idx, weights, expert_rows, *, first_expert: int,
@@ -341,7 +357,7 @@ def routing_stats(state: Tree):
 # ---------------------------------------------------------------------------
 
 from ..models.layers import (Layer, glorot_uniform, register,  # noqa: E402
-                             swiglu)
+                             relu2, relu2_mlp, swiglu)
 
 
 def _pallas_moe():
@@ -437,21 +453,30 @@ class MoEDense(Layer):
 
 @register
 class SparseMoE(Layer):
-    """Dropless top-k mixture of SwiGLU experts with a shared expert, as
-    one chip of an expert-parallel deployment runs it: the router scores
-    ALL ``num_experts`` (float32, softmax), a token takes its
-    ``experts_per_token`` best, weighted by their probabilities (divided
-    by their sum if ``normalise``) times ``routed_scale``; THIS layer
-    holds the ``experts_held`` experts from ``first_expert`` and adds
-    their part of the sum alone, plus the ungated shared expert that
-    every chip computes.  No capacity, no dropped token, and nothing
-    stands in for the experts held elsewhere: with ``experts_held =
-    num_experts`` (the default) it is the whole layer, and the parts of
-    all shares add up to it (shared expert counted once).
+    """Dropless top-k mixture of experts with a shared expert, as one
+    chip of an expert-parallel deployment runs it: the router scores ALL
+    ``num_experts`` in float32, a token takes its ``experts_per_token``
+    best, weighted by their scores (divided by their sum if
+    ``normalise``) times ``routed_scale``; THIS layer holds the
+    ``experts_held`` experts from ``first_expert`` and adds their part of
+    the sum alone, plus the ungated shared expert that every chip
+    computes.  No capacity, no dropped token, and nothing stands in for
+    the experts held elsewhere: with ``experts_held = num_experts`` (the
+    default) it is the whole layer, and the parts of all shares add up to
+    it (shared expert counted once).
 
-    Parameters: ``router.kernel`` (D, num_experts); ``experts.gate_up``
-    (experts_held, D, 2·d_hidden) and ``experts.down`` (experts_held,
-    d_hidden, D); ``shared.gate_up`` / ``shared.down`` where
+    ``expert_activation``: ``"swiglu"`` (``(silu(x W_gate) * x W_up)
+    W_down``, gate and up side by side) or ``"relu2"`` (``relu(x W_up)^2
+    W_down``, no gate), routed and shared experts alike.  ``scoring``:
+    ``"softmax"`` over the experts, or ``"sigmoid"`` a score an expert
+    with ``router.bias`` added for the CHOICE alone (DeepSeek-V3's
+    router; the bias gets no gradient, a balancing rule would set it).
+
+    Parameters: ``router.kernel`` (D, num_experts), and ``router.bias``
+    (num_experts,) under ``"sigmoid"``; ``experts.gate_up``
+    (experts_held, D, 2·d_hidden) or ``experts.up`` (experts_held, D,
+    d_hidden), and ``experts.down`` (experts_held, d_hidden, D);
+    ``shared.gate_up`` or ``shared.up``, and ``shared.down``, where
     ``shared_hidden > 0``.  State each step: ``aux_loss`` (the switch
     load-balance loss), ``rows_needed`` / ``rows_run`` (rows the grouped
     matmuls needed and ran) and ``load_max_over_mean``."""
@@ -459,7 +484,15 @@ class SparseMoE(Layer):
     def __init__(self, num_experts: int, experts_per_token: int,
                  d_hidden: int, shared_hidden: int = 0,
                  routed_scale: float = 1.0, normalise: bool = True,
-                 experts_held: Optional[int] = None, first_expert: int = 0):
+                 experts_held: Optional[int] = None, first_expert: int = 0,
+                 expert_activation: str = "swiglu",
+                 scoring: str = "softmax"):
+        if expert_activation not in ("swiglu", "relu2"):
+            raise ValueError(f"expert_activation must be 'swiglu' or "
+                             f"'relu2', got {expert_activation!r}")
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring must be 'softmax' or 'sigmoid', "
+                             f"got {scoring!r}")
         self.num_experts = int(num_experts)
         self.experts_per_token = int(experts_per_token)
         self.d_hidden = int(d_hidden)
@@ -469,29 +502,40 @@ class SparseMoE(Layer):
         self.experts_held = self.num_experts if experts_held is None \
             else int(experts_held)
         self.first_expert = int(first_expert)
+        self.expert_activation = expert_activation
+        self.scoring = scoring
         if not 0 <= self.first_expert <= self.num_experts \
                 - self.experts_held or self.experts_held < 1:
             raise ValueError(
                 f"experts {self.first_expert}..{self.first_expert}+"
                 f"{self.experts_held} are not among {self.num_experts}")
 
+    @property
+    def _up(self) -> tuple:
+        """(the first matrix's name, its width in ``d_hidden``s)."""
+        return ("gate_up", 2) if self.expert_activation == "swiglu" \
+            else ("up", 1)
+
     def init(self, rng, in_shape):
         d, f = in_shape[-1], self.d_hidden
         kr, kg, kd, ksg, ksd = jax.random.split(rng, 5)
         held = self.experts_held
+        up, wide = self._up
         params = {
             "router": {"kernel": glorot_uniform(kr, (d, self.num_experts))},
             "experts": {
-                "gate_up": glorot_uniform(kg, (held, d, 2 * f), fan_in=d,
-                                          fan_out=f),
+                up: glorot_uniform(kg, (held, d, wide * f), fan_in=d,
+                                   fan_out=f),
                 "down": glorot_uniform(kd, (held, f, d), fan_in=f,
                                        fan_out=d)},
         }
+        if self.scoring == "sigmoid":
+            params["router"]["bias"] = jnp.zeros((self.num_experts,))
         if self.shared_hidden:
             fs = self.shared_hidden
             params["shared"] = {
-                "gate_up": glorot_uniform(ksg, (d, 2 * fs), fan_in=d,
-                                          fan_out=fs),
+                up: glorot_uniform(ksg, (d, wide * fs), fan_in=d,
+                                   fan_out=fs),
                 "down": glorot_uniform(ksd, (fs, d))}
         # one buffer a leaf: the trainers donate the state
         state = {name: jnp.zeros((), jnp.float32) for name in (
@@ -503,27 +547,32 @@ class SparseMoE(Layer):
         ex = params["experts"]
         grouped_matmul = _pallas_moe().grouped_matmul
         tile_rows = _pallas_moe().TILE_ROWS
+        up, _ = self._up
 
-        def swiglu_experts(rows, plan):
-            h = grouped_matmul(rows, ex["gate_up"].astype(rows.dtype),
+        def experts(rows, plan):
+            h = grouped_matmul(rows, ex[up].astype(rows.dtype),
                                plan.tile_expert, plan.num_tiles)
-            f = h.shape[-1] // 2
-            return grouped_matmul(
-                jax.nn.silu(h[:, :f]) * h[:, f:],
-                ex["down"].astype(rows.dtype), plan.tile_expert,
-                plan.num_tiles)
+            if up == "gate_up":
+                f = h.shape[-1] // 2
+                h = jax.nn.silu(h[:, :f]) * h[:, f:]
+            else:
+                h = relu2(h)
+            return grouped_matmul(h, ex["down"].astype(rows.dtype),
+                                  plan.tile_expert, plan.num_tiles)
 
         with jax.named_scope("router"):
             idx, weights, probs = route_top_k(
                 tokens, params["router"]["kernel"], self.experts_per_token,
-                normalise=self.normalise, scale=self.routed_scale)
+                normalise=self.normalise, scale=self.routed_scale,
+                bias=params["router"].get("bias"))
         out, plan = routed_experts(
-            tokens, idx, weights, swiglu_experts,
+            tokens, idx, weights, experts,
             first_expert=self.first_expert, experts_held=self.experts_held,
             tile_rows=tile_rows)
         if self.shared_hidden:
             with jax.named_scope("shared_expert"):
-                out = out + swiglu(tokens, params["shared"]["gate_up"],
+                shared = swiglu if up == "gate_up" else relu2_mlp
+                out = out + shared(tokens, params["shared"][up],
                                    params["shared"]["down"])
         return out.reshape(x.shape), routing_state(idx, probs, plan,
                                                    tile_rows)
@@ -536,4 +585,6 @@ class SparseMoE(Layer):
                 "routed_scale": self.routed_scale,
                 "normalise": self.normalise,
                 "experts_held": self.experts_held,
-                "first_expert": self.first_expert}
+                "first_expert": self.first_expert,
+                "expert_activation": self.expert_activation,
+                "scoring": self.scoring}

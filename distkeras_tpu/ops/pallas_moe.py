@@ -16,10 +16,15 @@ tiles end.
   consecutive tiles of one expert keep its block of ``rhs`` in VMEM, so
   every expert's matrix is read once a column tile.  A tile past
   ``num_tiles`` is not computed: its step writes zeros and moves no
-  operand (the index maps hold the last used blocks).
+  operand (the index maps hold the last used blocks).  Where even the
+  narrowest block over the whole depth C does not fit (a width like
+  1,856 that 128 does not divide is one block, and (2,688, 1,856) is
+  10 MB in bf16), the depth is tiled too: a third, innermost grid axis of
+  contraction steps into a float32 accumulator.
 * its backward: the same kernel with ``rhs`` transposed for the rows'
   gradient, and ``_tgmm`` (``lhs^T @ dy`` summed over each expert's tiles
-  into a float32 accumulator) for the matrices'.
+  into a float32 accumulator, a block of ``lhs``'s columns at a time
+  where a (C, N tile) accumulator does not fit) for the matrices'.
 
 Precision follows ``pallas_attention._dot``: float32 operands multiply at
 HIGHEST, bf16 at the MXU's rate into float32.  Off the TPU the kernels
@@ -45,18 +50,38 @@ TILE_ROWS = 128
 #: may take; v5e's scoped default is 16 MiB for a whole kernel
 _BLOCK_BUDGET = 4 * 2**20
 
+#: ... and what the operand block of a depth-tiled call may take: such a
+#: call holds little else (row blocks and the accumulator, 128 rows
+#: each), and every contraction step is a grid step that the idle tiles
+#: past the used ones pay too
+_DEPTH_BUDGET = 8 * 2**20
 
-def _col_tile(n: int, c: int, itemsize: int) -> int:
-    """Columns of a block that is ``c`` deep: the widest multiple of 128
-    dividing ``n`` whose two buffers fit ``_BLOCK_BUDGET``, or all of
-    ``n`` where 128 does not divide it (a block equal to the array's
-    dimension needs no alignment: the CPU tests' sizes)."""
+
+def _tile_of(n: int, fits) -> int:
+    """The widest multiple of 128 dividing ``n``, at most 1,024 wide, that
+    ``fits``; 128 where none does; all of ``n`` where 128 does not divide
+    it (a block equal to the array's dimension needs no alignment: the
+    CPU tests' sizes, and a published width like 1,856)."""
     if n % 128:
         return n
-    for tn in (1024, 512, 256, 128):
-        if n % tn == 0 and 2 * c * tn * itemsize <= _BLOCK_BUDGET:
-            return tn
-    return 128
+    return next((tn for tn in range(min(n, 1024), 127, -128)
+                 if n % tn == 0 and fits(tn)), 128)
+
+
+def _col_tile(n: int, c: int, itemsize: int) -> int:
+    """Columns of a block that is ``c`` deep: the widest whose two buffers
+    fit ``_BLOCK_BUDGET`` (2,688 takes 896 or 384 columns, not 128)."""
+    return _tile_of(n, lambda tn: 2 * c * tn * itemsize <= _BLOCK_BUDGET)
+
+
+def _depth_tile(c: int, row_bytes: int, budget: int) -> int:
+    """Rows of a block whose every row takes ``row_bytes`` (over its
+    buffers): all ``c`` where that fits ``_BLOCK_BUDGET`` or 128 does not
+    divide ``c``, else the deepest multiple of 128 dividing ``c`` that
+    fits ``budget``."""
+    if c * row_bytes <= _BLOCK_BUDGET:
+        return c
+    return _tile_of(c, lambda tc: tc * row_bytes <= budget)
 
 
 def _dot_tn(a, b):
@@ -69,15 +94,31 @@ def _dot_tn(a, b):
                            preferred_element_type=jnp.float32)
 
 
-def _gmm_kernel(tile_expert, num_tiles, lhs_ref, rhs_ref, out_ref, *,
-                transpose_rhs: bool):
+def _gmm_kernel(tile_expert, num_tiles, lhs_ref, rhs_ref, out_ref, *scratch,
+                transpose_rhs: bool, depth_steps: int):
     del tile_expert  # read by the index maps
     i = pl.program_id(1)
+    # the grid has a third axis where the depth takes more than one step
+    k = pl.program_id(2) if depth_steps > 1 else 0
+    dot = _dot_t if transpose_rhs else _dot
 
     @pl.when(i < num_tiles[0])
     def _run():
-        dot = _dot_t if transpose_rhs else _dot
-        out_ref[...] = dot(lhs_ref[...], rhs_ref[...]).astype(out_ref.dtype)
+        if depth_steps == 1:
+            out_ref[...] = dot(lhs_ref[...], rhs_ref[...]).astype(
+                out_ref.dtype)
+            return
+        acc_ref, = scratch
+
+        @pl.when(k == 0)
+        def _first_step():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        acc_ref[...] += dot(lhs_ref[...], rhs_ref[...])
+
+        @pl.when(k == depth_steps - 1)
+        def _last_step():
+            out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
     @pl.when(i >= num_tiles[0])
     def _unused():
@@ -85,8 +126,8 @@ def _gmm_kernel(tile_expert, num_tiles, lhs_ref, rhs_ref, out_ref, *,
 
 
 def _tgmm_kernel(tile_expert, num_tiles, lhs_ref, dy_ref, out_ref, acc_ref,
-                 *, n_tiles: int):
-    i = pl.program_id(1)
+                 *, n_tiles: int, row_axis: int):
+    i = pl.program_id(row_axis)
     last = num_tiles[0] - 1
     expert = tile_expert[i]
 
@@ -116,25 +157,42 @@ def _gmm(lhs, rhs, tile_expert, num_tiles, *, transpose_rhs, tile_rows,
     rows, c = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     tn = _col_tile(n, c, lhs.dtype.itemsize)
+    tc = _depth_tile(c, 2 * tn * lhs.dtype.itemsize, _DEPTH_BUDGET)
+    steps = c // tc
+    # grid (N tiles, row tiles[, contraction steps]); an index map takes
+    # (j, i, k, tile_expert, num_tiles), k = 0 where the depth is whole
+    one = lambda fn: fn if steps > 1 else (  # noqa: E731
+        lambda j, i, te, nt: fn(j, i, 0, te, nt))
+
+    def depth(i, k, nt):
+        """Contraction step ``k``, held at the last one past the used
+        tiles: an unused tile moves no operand."""
+        return jnp.where(i < nt[0], k, steps - 1) if steps > 1 else 0
+
     if transpose_rhs:
-        rhs_spec = pl.BlockSpec(
-            (None, tn, c), lambda j, i, te, nt: (te[i], j, 0))
+        rhs_spec = pl.BlockSpec((None, tn, tc), one(
+            lambda j, i, k, te, nt: (te[i], j, depth(i, k, nt))))
     else:
-        rhs_spec = pl.BlockSpec(
-            (None, c, tn), lambda j, i, te, nt: (te[i], 0, j))
+        rhs_spec = pl.BlockSpec((None, tc, tn), one(
+            lambda j, i, k, te, nt: (te[i], depth(i, k, nt), j)))
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs,
+                          depth_steps=steps),
         out_shape=jax.ShapeDtypeStruct((rows, n), lhs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(n // tn, rows // tile_rows),
-            in_specs=[pl.BlockSpec((tile_rows, c),
-                                   lambda j, i, te, nt: (_used(i, nt), 0)),
-                      rhs_spec],
-            out_specs=pl.BlockSpec((tile_rows, tn),
-                                   lambda j, i, te, nt: (i, j))),
+            grid=(n // tn, rows // tile_rows) + ((steps,) if steps > 1
+                                                 else ()),
+            in_specs=[pl.BlockSpec((tile_rows, tc), one(
+                lambda j, i, k, te, nt: (_used(i, nt), depth(i, k, nt)))),
+                rhs_spec],
+            out_specs=pl.BlockSpec((tile_rows, tn), one(
+                lambda j, i, k, te, nt: (i, j))),
+            scratch_shapes=[pltpu.VMEM((tile_rows, tn), jnp.float32)]
+            if steps > 1 else []),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")
+            + (("arbitrary",) if steps > 1 else ())),
         interpret=interpret,
         name="moe_gmm",
     )(tile_expert, num_tiles, lhs, rhs)
@@ -149,22 +207,29 @@ def _tgmm(lhs, dy, tile_expert, num_tiles, *, num_experts, tile_rows,
     rows, c = lhs.shape
     n = dy.shape[1]
     tn = _col_tile(n, c, 4)  # the accumulator is float32
+    tc = _depth_tile(c, tn * 4, _BLOCK_BUDGET)  # ... in one buffer
     n_tiles = rows // tile_rows
+    # grid ([C tiles,] N tiles, row tiles); an index map takes
+    # (m, j, i, tile_expert, num_tiles), m = 0 where the depth is whole
+    one = lambda fn: fn if c > tc else (  # noqa: E731
+        lambda j, i, te, nt: fn(0, j, i, te, nt))
     return pl.pallas_call(
-        functools.partial(_tgmm_kernel, n_tiles=n_tiles),
+        functools.partial(_tgmm_kernel, n_tiles=n_tiles,
+                          row_axis=2 if c > tc else 1),
         out_shape=jax.ShapeDtypeStruct((num_experts, c, n), lhs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(n // tn, n_tiles),
-            in_specs=[pl.BlockSpec((tile_rows, c),
-                                   lambda j, i, te, nt: (_used(i, nt), 0)),
-                      pl.BlockSpec((tile_rows, tn),
-                                   lambda j, i, te, nt: (_used(i, nt), j))],
-            out_specs=pl.BlockSpec((None, c, tn),
-                                   lambda j, i, te, nt: (te[i], 0, j)),
-            scratch_shapes=[pltpu.VMEM((c, tn), jnp.float32)]),
+            grid=((c // tc,) if c > tc else ()) + (n // tn, n_tiles),
+            in_specs=[pl.BlockSpec((tile_rows, tc), one(
+                lambda m, j, i, te, nt: (_used(i, nt), m))),
+                pl.BlockSpec((tile_rows, tn), one(
+                    lambda m, j, i, te, nt: (_used(i, nt), j)))],
+            out_specs=pl.BlockSpec((None, tc, tn), one(
+                lambda m, j, i, te, nt: (te[i], m, j))),
+            scratch_shapes=[pltpu.VMEM((tc, tn), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=(("parallel",) if c > tc else ())
+            + ("parallel", "arbitrary")),
         interpret=interpret,
         name="moe_tgmm",
     )(tile_expert, num_tiles, lhs, dy)

@@ -96,6 +96,14 @@ def aux_losses(state: Tree) -> list:
             and path[-1].key == "aux_loss"]
 
 
+#: parameter keys whose leaves (and everything under them) a mixed-precision
+#: step hands to the forward uncast, as the float32 master weights: a
+#: routed layer scores its experts in float32 (``ops.moe.route_top_k``:
+#: ``router``), and a state-space mixer's decay rates, step bias and skip
+#: are float32 in the published layer (``ops.ssm.Mamba2Mixer``)
+FLOAT32_KEYS = frozenset({"router", "A_log", "dt_bias", "D"})
+
+
 def make_local_step(model, loss_fn: Callable,
                     optimizer: optax.GradientTransformation,
                     compute_dtype=None, remat: bool = False,
@@ -138,11 +146,10 @@ def make_local_step(model, loss_fn: Callable,
         return remat_plans.checkpoint(apply)(params, state, x, rng=rng)
 
     def cast_floats(tree):
-        # leaves under a "router" key stay as they are: a routed layer
-        # scores its experts in float32 (``ops.moe.route_top_k``)
+        # leaves under a ``FLOAT32_KEYS`` key stay as they are
         def cast(path, a):
             if not jnp.issubdtype(a.dtype, jnp.floating) or any(
-                    getattr(p, "key", None) == "router" for p in path):
+                    getattr(p, "key", None) in FLOAT32_KEYS for p in path):
                 return a
             return a.astype(compute_dtype)
 

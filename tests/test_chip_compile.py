@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from distkeras_tpu.ops import pallas_attention, pallas_moe
+from distkeras_tpu.ops import pallas_attention, pallas_moe, pallas_ssm, ssm
 
 
 @pytest.fixture(scope="module")
@@ -54,6 +54,7 @@ def mosaic(monkeypatch):
     the TPU compiler."""
     monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
     monkeypatch.setattr(pallas_moe, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_ssm, "_interpret", lambda: False)
 
 
 def _compile(fn, *shapes):
@@ -144,6 +145,59 @@ def test_grouped_matmuls_compile(one_chip, mosaic, dtype, tokens, mode):
                     shape(rows // pallas_moe.TILE_ROWS, dt="int32"),
                     shape(1, dt="int32"))
     assert "moe_gmm" in text and ("moe_tgmm" in text) == (mode != "fwd")
+
+
+@pytest.mark.parametrize("dtype,tokens,mode", [
+    ("bfloat16", 8192, "fwd_bwd"), ("float32", 16384, "fwd")],
+    ids=["train-bf16", "check-f32"])
+def test_grouped_matmuls_compile_at_widths_128_does_not_divide(
+        one_chip, mosaic, dtype, tokens, mode):
+    """The Nemotron cell's row buffer: 6 choices a token, 8 relu² experts
+    of (2688 x 1856) and (1856 x 2688) held here.  1,856 is one block, so
+    the depth of 2,688 is tiled (an accumulator over contraction steps)
+    and ``moe_tgmm``'s accumulator takes a block of columns at a time: a
+    whole (2688, 1856) block twice buffered is 20 MB of v5e's 16."""
+    rows = tokens * 6 + 8 * pallas_moe.TILE_ROWS
+
+    def shape(*dims, dt=dtype):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dt), sharding=one_chip)
+
+    def experts(x, up, down, tile_expert, num_tiles):
+        h = pallas_moe.grouped_matmul(x, up, tile_expert, num_tiles)
+        return jnp.sum(pallas_moe.grouped_matmul(
+            jnp.square(jax.nn.relu(h)), down, tile_expert,
+            num_tiles).astype(jnp.float32) ** 2)
+
+    fn = experts if mode == "fwd" else jax.grad(experts, argnums=(0, 1, 2))
+    text = _compile(fn, shape(rows, 2688), shape(8, 2688, 1856),
+                    shape(8, 1856, 2688),
+                    shape(rows // pallas_moe.TILE_ROWS, dt="int32"),
+                    shape(1, dt="int32"))
+    assert "moe_gmm" in text and ("moe_tgmm" in text) == (mode != "fwd")
+
+
+@pytest.mark.parametrize("dtype,batch,mode", [
+    ("bfloat16", 1, "fwd_bwd"), ("float32", 2, "fwd")],
+    ids=["train-bf16", "check-f32"])
+def test_scan_kernels_compile(one_chip, mosaic, dtype, batch, mode):
+    """The Mamba-2 scan at the Nemotron cell's shape: 64 heads of 64 in 8
+    groups, state 128, 64 chunks of 128 a row of 8,192."""
+    def shape(*dims, dt=dtype):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dt), sharding=one_chip)
+
+    def scan(*args):
+        return ssm.ssd(*args, chunk=128, impl="pallas")
+
+    fn = scan if mode == "fwd" else jax.grad(
+        lambda *args: jnp.sum(scan(*args).astype(jnp.float32) ** 2),
+        argnums=(0, 1, 2, 3, 4))
+    text = _compile(fn, shape(batch, 8192, 64, 64),
+                    shape(batch, 8192, 64, dt="float32"),
+                    shape(64, dt="float32"), shape(batch, 8192, 8, 128),
+                    shape(batch, 8192, 8, 128))
+    # the instructions' names are what a trace's rows read
+    assert "%ssd_chunk_fwd" in text
+    assert ("%ssd_chunk_bwd" in text) == (mode != "fwd")
 
 
 def test_flash_lse_rectangular_hop_compiles(one_chip, mosaic):

@@ -262,3 +262,183 @@ def test_trainer_aux_weight_folds_balance_loss():
     weighted = run(0.01)
     assert not np.allclose(plain, weighted)  # the aux term is in the loss
     assert weighted[-1] < weighted[0]        # and training still converges
+
+
+# ---------------------------------------------------------------------------
+# relu² experts under a sigmoid router (``SparseMoE``'s options), against
+# the plain reference ``benchmark/reference/nemotron_h.py``
+# ---------------------------------------------------------------------------
+
+def _nemotron_reference():
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from reference import nemotron_h
+    return nemotron_h
+
+
+ROUTED = dict(n_routed_experts=16, num_experts_per_tok=3,
+              routed_scaling_factor=2.5, norm_topk_prob=True)
+
+
+def relu2_layer(**kw):
+    from distkeras_tpu.ops.moe import SparseMoE
+    return SparseMoE(16, 3, 24, shared_hidden=40, routed_scale=2.5,
+                     expert_activation="relu2", scoring="sigmoid", **kw)
+
+
+def relu2_setup(bias_scale=0.0):
+    layer = relu2_layer()
+    params, state, _ = layer.init(jax.random.PRNGKey(5), (64, 32))
+    assert set(params["experts"]) == {"up", "down"} \
+        and set(params["shared"]) == {"up", "down"} \
+        and params["router"]["bias"].shape == (16,)
+    params["router"]["bias"] = bias_scale * jax.random.normal(
+        jax.random.PRNGKey(7), (16,))
+    u = jnp.asarray(np.random.default_rng(6).normal(size=(2, 64, 32)),
+                    jnp.float32)
+    return layer, params, state, u
+
+
+def test_relu2_sigmoid_layer_equals_the_reference_values_and_gradients():
+    ref = _nemotron_reference()
+    layer, params, state, u = relu2_setup(bias_scale=0.3)
+    w = jax.random.normal(jax.random.PRNGKey(8), u.shape)
+    got = jax.jit(jax.value_and_grad(lambda p, u: jnp.sum(
+        w * layer.apply(p, state, u)[0]), argnums=(0, 1)))(params, u)
+    want = jax.jit(jax.value_and_grad(lambda p, u: jnp.sum(
+        w * ref.sparse_ff(p, u, ROUTED)[0]), argnums=(0, 1)))(params, u)
+    flat, _ = jax.tree_util.tree_flatten_with_path(got)
+    for (path, a), b in zip(flat, jax.tree_util.tree_leaves(want),
+                            strict=True):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=1e-5 * float(jnp.max(jnp.abs(b))) + 1e-7,
+            err_msg=jax.tree_util.keystr(path))
+    # the bias bears on the choice alone: no gradient reaches it
+    assert not np.any(np.asarray(got[1][0]["router"]["bias"]))
+
+
+def test_the_bias_changes_a_choice_and_no_weight():
+    """``b`` large enough to change which experts are taken; a taken
+    expert's weight is its own sigmoid score over the taken scores' sum,
+    whatever ``b``."""
+    from distkeras_tpu.ops.moe import route_top_k
+    _, params, _, u = relu2_setup()
+    tokens, kernel = u.reshape(-1, 32), params["router"]["kernel"]
+    bias = jnp.zeros((16,)).at[3].set(5.0)
+    idx0, w0, _ = route_top_k(tokens, kernel, 3, normalise=True, scale=2.5,
+                              bias=jnp.zeros((16,)))
+    idx1, w1, _ = route_top_k(tokens, kernel, 3, normalise=True, scale=2.5,
+                              bias=bias)
+    assert np.all(np.any(np.asarray(idx1) == 3, axis=1))   # always taken
+    assert not np.all(np.any(np.asarray(idx0) == 3, axis=1))
+    scores = np.asarray(jax.nn.sigmoid(tokens @ kernel))
+    taken = np.take_along_axis(scores, np.asarray(idx1), axis=1)
+    np.testing.assert_allclose(
+        w1, 2.5 * taken / taken.sum(axis=1, keepdims=True), rtol=1e-5)
+    np.testing.assert_allclose(w1.sum(axis=1), 2.5, rtol=1e-5)
+    np.testing.assert_allclose(w0.sum(axis=1), 2.5, rtol=1e-5)
+
+
+def test_the_relu2_shares_add_up_to_the_uncut_layer():
+    """Each of 4 chips holds 4 of 16 experts: their routed parts, and the
+    shared expert counted once, are the whole layer."""
+    ref = _nemotron_reference()
+    whole, params, state, u = relu2_setup(bias_scale=0.3)
+    want = ref.sparse_ff(params, u, ROUTED)[0]
+    shared = ref.relu2_mlp(params["shared"]["up"],
+                           params["shared"]["down"], u)
+    total, needed = shared, 0.0
+    for share in range(4):
+        part = relu2_layer(experts_held=4, first_expert=4 * share)
+        mine = dict(params, experts=jax.tree_util.tree_map(
+            lambda a: a[4 * share:4 * share + 4], params["experts"]))
+        out, st = part.apply(mine, state, u)
+        # the same share, from the reference
+        np.testing.assert_allclose(out, ref.sparse_ff(
+            mine, u, dict(ROUTED, first_expert=4 * share))[0], atol=2e-5)
+        total = total + (out - shared)
+        needed += float(st["rows_needed"])
+    np.testing.assert_allclose(total, want, atol=5e-5)
+    np.testing.assert_allclose(whole.apply(params, state, u)[0], want,
+                               atol=5e-5)
+    assert needed == 2 * 64 * 3  # every assignment landed on one share
+
+
+def test_sparse_moe_refuses_unknown_options():
+    from distkeras_tpu.ops.moe import SparseMoE
+    with pytest.raises(ValueError, match="expert_activation"):
+        SparseMoE(4, 2, 8, expert_activation="gelu")
+    with pytest.raises(ValueError, match="scoring"):
+        SparseMoE(4, 2, 8, scoring="tanh")
+
+
+# ---------------------------------------------------------------------------
+# the grouped matmuls' tiles
+# ---------------------------------------------------------------------------
+
+def test_tiles_of_the_published_widths():
+    from distkeras_tpu.ops.pallas_moe import (_BLOCK_BUDGET, _DEPTH_BUDGET,
+                                              _col_tile, _depth_tile)
+    # Laguna's: (columns, depth, bytes an element) -> columns, as before
+    for (n, c, size), tile in {(1024, 2048, 2): 512, (2048, 512, 2): 1024,
+                               (512, 2048, 2): 512, (1024, 2048, 4): 256,
+                               (2048, 512, 4): 1024,
+                               (512, 2048, 4): 256}.items():
+        assert _col_tile(n, c, size) == tile
+        # ... and the depth whole, in moe_gmm and in moe_tgmm
+        assert _depth_tile(c, 2 * tile * size, _DEPTH_BUDGET) == c
+        assert _depth_tile(c, tile * 4, _BLOCK_BUDGET) == c
+    # Nemotron's: 2,688 = 21 x 128 takes 384 columns, not 128; 1,856 =
+    # 14.5 x 128 is one block, over a depth of 2,688 in 3 steps of 896
+    # (moe_tgmm's float32 accumulator: 7 blocks of 384 of its rows)
+    assert _col_tile(2688, 1856, 2) == 384
+    assert _depth_tile(1856, 2 * 384 * 2, _DEPTH_BUDGET) == 1856
+    assert _col_tile(1856, 2688, 2) == 1856
+    assert _depth_tile(2688, 2 * 1856 * 2, _DEPTH_BUDGET) == 896
+    assert _depth_tile(2688, 1856 * 4, _BLOCK_BUDGET) == 384
+    assert _col_tile(2688, 64, 2) == 896  # the widest divisor under 1,024
+
+
+@pytest.mark.parametrize("depth,width", [(384, 200), (200, 384), (256, 256)])
+def test_grouped_matmul_tiles_the_depth(monkeypatch, depth, width):
+    """A width that 128 does not divide (one block) over a depth that
+    takes three contraction steps, a depth that 128 does not divide
+    (whole), and both tiled: values and both gradients against plain
+    matmuls, the matrices' gradient a block of columns at a time."""
+    from distkeras_tpu.ops import pallas_moe
+    from distkeras_tpu.ops.moe import dispatch_plan
+    monkeypatch.setattr(pallas_moe, "_BLOCK_BUDGET", 128 * 1024)
+    monkeypatch.setattr(pallas_moe, "_DEPTH_BUDGET", 128 * 1024)
+    tn = pallas_moe._col_tile(width, depth, 4)
+    steps = depth // pallas_moe._depth_tile(depth, 2 * tn * 4, 128 * 1024)
+    assert (tn, steps) == {(384, 200): (200, 3), (200, 384): (128, 1),
+                           (256, 256): (128, 2)}[(depth, width)]
+    experts, tile = 3, pallas_moe.TILE_ROWS
+    plan = dispatch_plan(jax.random.randint(jax.random.PRNGKey(1), (300, 2),
+                                            0, experts + 1), 0, experts,
+                         tile)
+    rows = plan.row_used.shape[0]
+    lhs = jax.random.normal(jax.random.PRNGKey(2), (rows, depth)) \
+        * plan.row_used[:, None]
+    rhs = jax.random.normal(jax.random.PRNGKey(3), (experts, depth, width))
+    w = jax.random.normal(jax.random.PRNGKey(4), (rows, width))
+    row_expert = jnp.repeat(plan.tile_expert, tile)
+    used = (jnp.arange(rows) < plan.num_tiles[0] * tile)[:, None]
+
+    def plain(lhs, rhs):
+        return jnp.where(used, jnp.einsum("rc,rcn->rn", lhs,
+                                          rhs[row_expert]), 0.0)
+
+    def grouped(lhs, rhs):
+        return pallas_moe.grouped_matmul(lhs, rhs, plan.tile_expert,
+                                         plan.num_tiles)
+
+    with jax.default_matmul_precision("highest"):
+        for got, want in zip(*(jax.jit(lambda l, r, fn=fn: (fn(l, r),) + jax.grad(
+                lambda l, r: jnp.sum(w * fn(l, r)), argnums=(0, 1))(l, r))(
+                    lhs, rhs) for fn in (grouped, plain)), strict=True):
+            np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
