@@ -153,7 +153,8 @@ def _flash_with_blocking(q, k, v, causal: bool, t: int, window=None):
     positions sit strictly after every real query (never attended), and
     padded QUERY rows are sliced off (their zero cotangent keeps
     gradients exact too).  Non-causal attention would attend the padded
-    keys, so there we refuse loudly instead.
+    keys, so there we refuse loudly instead.  ``k`` / ``v`` may carry
+    fewer heads than ``q`` (grouped queries); they are padded as they are.
     """
     from .pallas_attention import _tileable, flash_attention
     if _tileable(t):
@@ -331,9 +332,14 @@ class MultiHeadAttention(Layer):
                 "cache); generate by full-context recompute")
 
     def _expand_kv(self, k):
-        """(B, T, KV, Dh) → (B, T, H, Dh): query groups share K/V heads
-        (the attention ops and flash kernels take equal head counts;
-        the decode CACHE stays KV-sized — that is where GQA saves)."""
+        """(B, T, KV, Dh) → (B, T, H, Dh): query groups share K/V heads,
+        head ``kv·G + g`` reading K/V head ``kv``.  Only for the paths
+        that take equal head counts: ``dot_product_attention``
+        (``impl="dense"``), the sequence-parallel ring (its hops rotate
+        K/V blocks of the query's head count) and ``apply_prefill``.
+        The flash kernels of ``apply`` read K and V at their own head
+        count (``pallas_attention.flash_attention``), and the decode
+        CACHE stays KV-sized."""
         g = self.num_heads // self._kv
         return k if g == 1 else jnp.repeat(k, g, axis=2)
 
@@ -350,8 +356,8 @@ class MultiHeadAttention(Layer):
                     "PositionalEmbedding")
             with jax.named_scope("rope"):
                 q, k = self._rotate(q, k, jnp.arange(t))
-        k = self._expand_kv(k)
-        v = self._expand_kv(v)
+        if self.mesh is not None or self.impl != "flash":
+            k, v = self._expand_kv(k), self._expand_kv(v)
         if self.mesh is not None:
             if self.window is not None:
                 raise ValueError("a sliding window over a sequence-sharded "

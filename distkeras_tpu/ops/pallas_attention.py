@@ -36,6 +36,16 @@ it can see (``causal``, the two lengths, T, Dh, the dtype):
   no grid step and no DMA for a tile past it.  At T = 1,024 (256 tiles)
   10 of 16 tile pairs run, 4 of them masked.
 
+Grouped queries: K and V come at their own head count, (B, T, KV, Dh)
+with KV dividing H, and the grid walk never makes them H heads wide.
+Query head ``kv·G + g`` reads K/V head ``kv``: the forward's and dQ's K/V
+blocks are indexed ``head // G``; dK/dV runs over the K/V heads, a key
+block's row walking its query blocks once for each of the group's G
+heads (a ``g`` column of the table, or one more grid axis) into one
+float32 accumulator, written once.  G is read from the shapes; G = 1
+builds the programs it always built.  (The in-kernel walk takes equal
+head counts; ``flash_attention`` repeats K/V for it alone.)
+
 Backward is the standard flash recurrence (Dao 2022): the forward saves
 only O and the per-row logsumexp L; dQ and dK/dV are each one fused kernel
 re-computing P = exp(S − L) tile by tile, so training memory is O(T·D) too.
@@ -99,7 +109,8 @@ def _dot_t(a, b):  # a @ b.T, same precision policy as _dot
 _FIRST, _LAST, _MASKED = 1, 2, 4
 
 
-def _walk_table(tq: int, tk: int, bq: int, bk: int, by_keys: bool = False):
+def _walk_table(tq: int, tk: int, bq: int, bk: int, by_keys: bool = False,
+                group: int = 1):
     """The causal grid walk's schedule, one entry a grid step:
     ``(query block, key block, first, last, masked)``.
 
@@ -113,7 +124,14 @@ def _walk_table(tq: int, tk: int, bq: int, bk: int, by_keys: bool = False):
     where the diagonal passes through the block (its last key is after
     its first query); in every other block every pair attends and no
     mask is built.  136 steps, 16 of them masked, at T = 8,192 with
-    512-blocks (256 pairs in all)."""
+    512-blocks (256 pairs in all).
+
+    ``group`` > 1 (``by_keys`` alone: dK/dV of a K/V head that ``group``
+    query heads share): a key block's row walks its query blocks once
+    for each of the group's heads in turn, the entry gains the head
+    ``g`` as a sixth field, and ``first`` / ``last`` are the row's ends
+    over the whole group, so one accumulator sums dK / dV over it
+    (136 × 6 = 816 steps a K/V head in Laguna's full layers)."""
     def needed(qi, kb):
         return kb * bk <= qi * bq + bq - 1
 
@@ -124,8 +142,11 @@ def _walk_table(tq: int, tk: int, bq: int, bk: int, by_keys: bool = False):
     else:
         rows = [[(qi, kb) for kb in range(n_k) if needed(qi, kb)]
                 for qi in range(n_q)]
-    return [(qi, kb, j == 0, j == len(row) - 1, kb * bk + bk - 1 > qi * bq)
-            for row in rows for j, (qi, kb) in enumerate(row)]
+    if group > 1:
+        rows = [[(qi, kb, g) for g in range(group) for qi, kb in row]
+                for row in rows]
+    return [(qi, kb, j == 0, j == len(row) - 1, kb * bk + bk - 1 > qi * bq,
+             *g) for row in rows for j, (qi, kb, *g) in enumerate(row)]
 
 
 def _band_blocks(window: int, block: int) -> int:
@@ -148,12 +169,16 @@ def _step(refs, walk, block_q: int, block_k: int):
       band, step j of query block i is key block i - (band - 1) + j (of
       key block i: query block i + j); a step that falls off the
       sequence does nothing, every other builds the mask.
-    * the causal table, ``("table", plain, masked)``: the three
-      scalar-prefetched columns of ``_walk_table`` say where the step is
-      and whether its block is masked; one body for each kind the table
-      holds."""
+    * the causal table, ``("table", plain, masked, columns)``: the first
+      three of its ``columns`` scalar-prefetched columns of
+      ``_walk_table`` say where the step is and whether its block is
+      masked; one body for each kind the table holds.
+    * ``("group", dense or band)``: dK/dV of a K/V head that several
+      query heads share, one grid axis more between the row and its
+      steps; the row begins at the first head's first step and ends at
+      the last head's last.  (On the table the group is in the rows.)"""
     if walk is not None and walk[0] == "table":
-        qi_ref, kb_ref, flag_ref, *refs = refs
+        (qi_ref, kb_ref, flag_ref), refs = refs[:3], refs[walk[3]:]
         s = pl.program_id(1)
         flags = flag_ref[s]
         masked = (flags & _MASKED) != 0
@@ -162,17 +187,27 @@ def _step(refs, walk, block_q: int, block_k: int):
             runs = [(None, walk[2])]
         return (qi_ref[s] * block_q - kb_ref[s] * block_k,
                 (flags & _FIRST) != 0, (flags & _LAST) != 0, runs, refs)
-    row, j, n = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    grouped = walk is not None and walk[0] == "group"
+    if grouped:
+        walk = walk[1]
+    minor = 3 if grouped else 2
+    row, j, n = pl.program_id(1), pl.program_id(minor), pl.num_programs(minor)
+
+    def ends():
+        if not grouped:
+            return j == 0, j == n - 1
+        g, last_g = pl.program_id(2), pl.num_programs(2) - 1
+        return (g == 0) & (j == 0), (g == last_g) & (j == n - 1)
+
     if walk is None:
-        return None, j == 0, j == n - 1, [(None, False)], refs
+        return None, *ends(), [(None, False)], refs
     _, by_keys, n_q = walk
     if by_keys:
         qi, kb = row + j, row
     else:
         qi, kb = row, row - (n - 1) + j
     inside = qi < n_q if by_keys else kb >= 0
-    return (qi * block_q - kb * block_k, j == 0, j == n - 1,
-            [(inside, True)], refs)
+    return (qi * block_q - kb * block_k, *ends(), [(inside, True)], refs)
 
 
 def _run(runs, compute) -> None:
@@ -435,7 +470,7 @@ def _bwd_dkv_causal_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref,
 # ---------------------------------------------------------------------------
 
 def _count_tiles(causal, tq, tk, bq, bk, tile, kernels: int,
-                 window=None) -> None:
+                 window=None, group: int = 1) -> None:
     """The schedule is static, so it is counted where it is built: a
     causal call adds, once for each of the ``kernels`` it puts into the
     program, to the default registry's ``flash.causal_tiles_executed`` /
@@ -447,10 +482,19 @@ def _count_tiles(causal, tq, tk, bq, bk, tile, kernels: int,
     those it builds a mask in (4 and 16: the diagonal's); a
     sliding-window call to ``flash.window_tiles_executed`` /
     ``flash.window_tiles_total`` instead (31 of 256 at T = 8,192,
-    window 512)."""
-    if not causal or sizing():  # the recompute plan's own trace of a child
+    window 512).  A call whose ``group`` > 1 query heads share a K/V
+    head, causal or not, adds its kernels to ``flash.kv_native_kernels``
+    where they read K and V at their own head count (the grid walk), to
+    ``flash.kv_expanded_kernels`` where they run on K and V repeated to
+    the query heads (the in-kernel walk, ``tile``)."""
+    if sizing():  # the recompute plan's own trace of a child
         return
     registry = default_registry()
+    if group > 1:
+        registry.counter("flash.kv_native_kernels" if tile is None
+                         else "flash.kv_expanded_kernels").inc(kernels)
+    if not causal:
+        return
     if window is not None:
         band, n = _band_blocks(window, bq), tq // bq
         registry.counter("flash.window_tiles_executed").inc(
@@ -481,34 +525,56 @@ def _whole_row(t):
     return pl.BlockSpec((1, 1, t), lambda b: (b, 0, 0))
 
 
-def _walk(causal, tq, tk, bq, bk, window, by_keys=False):
+def _walk(causal, tq, tk, bq, bk, window, by_keys=False, group=1):
     """The grid a grid-walk kernel runs on, from what the launcher can
     see: ``(walk, grid, at, prefetch)`` — what ``_step`` reads in the
-    kernel, the grid's axes after batch·head, the index maps of a block
-    on the query side (``"q"``), on the key side (``"k"``) and of a query
-    block of ``lse`` / ``dvec`` on lanes (``"row"``), and the
+    kernel, the grid's axes after its leading one, the index maps of a
+    block on the query side (``"q"``), on the key side (``"k"``) and of
+    a query block of ``lse`` / ``dvec`` on lanes (``"row"``), and the
     scalar-prefetched operands.  A row of the grid is a query block, or
     ``by_keys`` (the dK/dV kernel) a key block.
 
-    * a causal call: (steps,) over ``_walk_table``, whose three columns
-      are prefetched and read by every index map;
+    * a causal call: (steps,) over ``_walk_table``, whose columns are
+      prefetched and read by every index map;
     * a sliding window: (rows, band) — the band's blocks alone, the
       index held at the sequence's end where a step falls off it (the
       kernel skips the step; the block is already there);
-    * any other call: the dense (rows, blocks)."""
+    * any other call: the dense (rows, blocks).
+
+    ``group`` query heads share a K/V head (``head = kv·group + g``, so
+    row ``bh`` of the (B·H, T, Dh) arrays reads row ``bh // group`` of
+    the (B·KV, T, Dh) ones).  The leading axis of a query-row grid is
+    batch·head and its key-side maps read ``b // group``; of a
+    ``by_keys`` grid it is batch·K/V head, a key block's row walks the
+    group's heads in turn (the table's ``g`` column, or one more axis
+    between the rows and their steps) and the query-side maps read head
+    ``b·group + g``: the key block and its accumulators stay put over
+    the group.  ``group`` = 1 builds what it built before there was
+    one: no division, no axis, no column."""
     n_q, n_k = tq // bq, tk // bk
+    grouped = by_keys and group > 1
+
+    def key_head(b):  # the K/V head of a grid's leading index
+        return b if by_keys or group == 1 else lax.div(b, group)
+
     if causal and window is None:
-        table = _walk_table(tq, tk, bq, bk, by_keys)
-        kinds = {masked for *_, masked in table}
-        at = {"q": lambda b, s, qi, kb, flags: (b, qi[s], 0),
-              "k": lambda b, s, qi, kb, flags: (b, kb[s], 0),
-              "row": lambda b, s, qi, kb, flags: (b, 0, qi[s])}
+        table = _walk_table(tq, tk, bq, bk, by_keys, group if by_keys else 1)
+        kinds = {step[4] for step in table}
+
+        def q_head(b, s, g):  # ``g``: the table's fourth column, if any
+            return b * group + g[0][s] if grouped else b
+
+        at = {"q": lambda b, s, qi, kb, flags, *g: (q_head(b, s, g), qi[s],
+                                                    0),
+              "k": lambda b, s, qi, kb, flags, *g: (key_head(b), kb[s], 0),
+              "row": lambda b, s, qi, kb, flags, *g: (q_head(b, s, g), 0,
+                                                      qi[s])}
         prefetch = tuple(
             jnp.asarray(column, jnp.int32) for column in zip(*(
-                (qi, kb, first * _FIRST | last * _LAST | masked * _MASKED)
-                for qi, kb, first, last, masked in table)))
-        return (("table", False in kinds, True in kinds), (len(table),), at,
-                prefetch)
+                (qi, kb, first * _FIRST | last * _LAST | masked * _MASKED,
+                 *g) for qi, kb, first, last, masked, *g in table)))
+        return (("table", False in kinds, True in kinds, len(prefetch)),
+                (len(table),), at, prefetch)
     walk, cols = None, n_q if by_keys else n_k
     if window is not None:
         walk, cols = ("band", by_keys, n_q), _band_blocks(window, bk)
@@ -521,8 +587,13 @@ def _walk(causal, tq, tk, bq, bk, window, by_keys=False):
 
     def q_of(i, j): return walked(i, j) if by_keys else i
     def k_of(i, j): return i if by_keys else walked(i, j)
+    if grouped:
+        at = {"q": lambda b, i, g, j: (b * group + g, q_of(i, j), 0),
+              "k": lambda b, i, g, j: (b, k_of(i, j), 0),
+              "row": lambda b, i, g, j: (b * group + g, 0, q_of(i, j))}
+        return ("group", walk), (n_k, group, cols), at, ()
     at = {"q": lambda b, i, j: (b, q_of(i, j), 0),
-          "k": lambda b, i, j: (b, k_of(i, j), 0),
+          "k": lambda b, i, j: (key_head(b), k_of(i, j), 0),
           "row": lambda b, i, j: (b, 0, q_of(i, j))}
     return walk, (n_k if by_keys else n_q, cols), at, ()
 
@@ -534,10 +605,14 @@ def _grid_walk(body, kernel, args, operands, outputs, out_shape, scratch, *,
     gives it.  ``operands`` and ``outputs`` name the kind of each block
     of ``args`` and of the results: ``"q"`` / ``"k"`` a (block, Dh) tile
     on the query / key side, ``"row"`` a query block of ``lse`` or
-    ``dvec`` on lanes."""
+    ``dvec`` on lanes.  The query side's leading dimension is batch·head
+    and the key side's batch·K/V head; their ratio is the group that
+    shares a K/V head, and a ``by_keys`` grid leads with the key
+    side's."""
     sides = dict(zip(operands, args))
-    (bh, tq, dh), tk = sides["q"].shape, sides["k"].shape[1]
-    walk, grid, at, prefetch = _walk(causal, tq, tk, bq, bk, window, by_keys)
+    (bh, tq, dh), (bkv, tk, _) = sides["q"].shape, sides["k"].shape
+    walk, grid, at, prefetch = _walk(causal, tq, tk, bq, bk, window, by_keys,
+                                     bh // bkv)
     shape = {"q": (1, bq, dh), "k": (1, bk, dh), "row": (1, 1, bq)}
     in_specs, out_specs = ([pl.BlockSpec(shape[x], at[x]) for x in kinds]
                            for kinds in (operands, outputs))
@@ -545,7 +620,8 @@ def _grid_walk(body, kernel, args, operands, outputs, out_shape, scratch, *,
         functools.partial(body, scale=scale, block_q=bq, block_k=bk,
                           window=window, walk=walk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch), grid=(bh, *grid),
+            num_scalar_prefetch=len(prefetch),
+            grid=(bkv if by_keys else bh, *grid),
             in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
         out_shape=out_shape,
         interpret=interpret,
@@ -571,10 +647,11 @@ _LAUNCHER_STATICS = ("causal", "bq", "bk", "scale", "tile", "interpret",
 @functools.partial(jax.jit, static_argnames=_LAUNCHER_STATICS)
 def _flash_fwd_raw(qr, kr, vr, *, causal, bq, bk, scale, tile, interpret,
                    window=None):
-    """(BH, Tq, D) + (BH, Tk, D) in → (out (BH,Tq,D), lse (BH,Tq)) via the
-    fused kernel.  Rectangular Tq ≠ Tk is the ring's half-block hop shape
-    (zigzag schedule); causal requires Tq == Tk (diagonal alignment).
-    ``tile`` (``_causal_tile``) selects the in-kernel causal walk."""
+    """(BH, Tq, D) + (B·KV, Tk, D) in → (out (BH,Tq,D), lse (BH,Tq)) via
+    the fused kernel.  Rectangular Tq ≠ Tk is the ring's half-block hop
+    shape (zigzag schedule); causal requires Tq == Tk (diagonal
+    alignment).  ``tile`` (``_causal_tile``) selects the in-kernel causal
+    walk, which takes equal head counts."""
     bh, tq, dh = qr.shape
     tk = kr.shape[1]
     if causal and tq != tk:
@@ -610,10 +687,9 @@ def _flash_fwd_raw(qr, kr, vr, *, causal, bq, bk, scale, tile, interpret,
 def _flash_bwd_raw(qr, kr, vr, do, lse, dvec, *, causal, bq, bk, scale,
                    tile, interpret, window=None):
     bh, tq, dh = qr.shape
-    tk = kr.shape[1]
     dq_shape = jax.ShapeDtypeStruct((bh, tq, dh), qr.dtype)
-    dkv_shape = [jax.ShapeDtypeStruct((bh, tk, dh), kr.dtype),
-                 jax.ShapeDtypeStruct((bh, tk, dh), vr.dtype)]
+    dkv_shape = [jax.ShapeDtypeStruct(kr.shape, kr.dtype),
+                 jax.ShapeDtypeStruct(vr.shape, vr.dtype)]
     operands = (qr, kr, vr, do, lse, dvec)
 
     if tile is not None:
@@ -666,6 +742,28 @@ def _from_bh(x, b, h):
     return x.reshape(b, h, t, dh).transpose(0, 2, 1, 3)
 
 
+def _kv_to_bh(k, v, group: int, tile):
+    """K and V as the kernels take them: (B·KV, T, Dh), their own head
+    count, for the grid walk; repeated to the ``group`` query heads of
+    each for the in-kernel causal walk (``tile``), whose whole-sequence
+    kernels take equal head counts."""
+    kr, vr = _to_bh(k), _to_bh(v)
+    if group > 1 and tile is not None:
+        kr, vr = jnp.repeat(kr, group, axis=0), jnp.repeat(vr, group, axis=0)
+    return kr, vr
+
+
+def _kv_from_bh(x, b: int, kv: int):
+    """dK or dV (rows, T, Dh) → (B, T, KV, Dh).  Rows at the query head
+    count (the in-kernel walk ran on repeated heads) are first summed
+    over each group, in float32: the repeat's transpose."""
+    rows, t, dh = x.shape
+    if rows != b * kv:
+        x = x.reshape(b * kv, rows // (b * kv), t, dh).astype(
+            jnp.float32).sum(axis=1)
+    return _from_bh(x, b, kv)
+
+
 def _tileable(t: int) -> bool:
     """Whether a sequence length has a block Mosaic can tile: the
     ``lse``/``dvec`` specs map the block onto lanes, so it is a multiple
@@ -714,6 +812,10 @@ def _blocks(q, k, causal, block_q, block_k, window=None):
     (the block pairs at or below the diagonal), the band's blocks for a
     sliding window, the dense grid for any other."""
     tq, tk, dh = q.shape[1], k.shape[1], q.shape[3]
+    if q.shape[2] % k.shape[2]:
+        raise ValueError(
+            f"the query heads ({q.shape[2]}) must be a whole multiple of "
+            f"the K/V heads ({k.shape[2]})")
     if window is not None and not (causal and tq == tk and window >= 1):
         raise ValueError(
             f"a sliding window needs causal self-attention and window >= "
@@ -740,7 +842,23 @@ def _blocks(q, k, causal, block_q, block_k, window=None):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = False, block_q=None,
                     block_k=None, window=None):
-    """Pallas flash attention; q/k/v (B, T, H, Dh) → (B, T, H, Dh).
+    """Pallas flash attention; q (B, T, H, Dh), k/v (B, T, KV, Dh) →
+    (B, T, H, Dh).
+
+    KV divides H: query head ``kv·G + g`` reads K/V head ``kv`` (G = H /
+    KV, read from the shapes; ``MultiHeadAttention._expand_kv``'s order,
+    which the weights and the decode cache assume).  The grid walk's
+    kernels read K and V at their own head count — a K/V block's index
+    map is ``head // G``, and dK/dV runs over the K/V heads, a key
+    block's row walking its query blocks once for each head of the
+    group into one float32 accumulator — so nothing on the K/V side is
+    ever H heads wide: not the transposes around the kernels, not dK /
+    dV, not the K and V a checkpoint keeps for the backward.  The
+    in-kernel causal walk (whole-sequence operands) takes equal head
+    counts: for it alone K and V are repeated here, after the transpose,
+    and dK / dV summed over the group after the kernel
+    (``flash.kv_expanded_kernels`` counts those, ``flash.kv_native_kernels``
+    the others).  G = 1 is the program it always was.
 
     Numerically equal to ``dot_product_attention`` (tested, gradients
     included); O(T·D) HBM traffic on BOTH forward and backward (the
@@ -772,13 +890,14 @@ def flash_attention(q, k, v, causal: bool = False, block_q=None,
 def _vjp_fwd(q, k, v, causal, block_q, block_k, window=None):
     b, t, h, dh = q.shape
     bq, bk, tile = _blocks(q, k, causal, block_q, block_k, window)
+    group = h // k.shape[2]
     _count_tiles(causal, t, k.shape[1], bq, bk, tile, kernels=1,
-                 window=window)
+                 window=window, group=group)
     scale = 1.0 / math.sqrt(dh)
     # "layout": the (B, T, H, Dh) <-> (BH, T, Dh) transposes around the
     # kernels, named so a trace can charge their copies to attention
     with jax.named_scope("layout"):
-        qr, kr, vr = _to_bh(q), _to_bh(k), _to_bh(v)
+        qr, (kr, vr) = _to_bh(q), _kv_to_bh(k, v, group, tile)
     out, lse = _flash_fwd_raw(qr, kr, vr, causal=causal, bq=bq, bk=bk,
                               scale=scale, tile=tile,
                               interpret=_interpret(), window=window)
@@ -798,9 +917,10 @@ def _bwd_impl(causal, block_q, block_k, res, g_out, g_lse=None,
     it with the kernels unchanged."""
     q, k, v, out_bh, lse = res
     b, t, h, dh = q.shape
+    kv = k.shape[2]
     bq, bk, tile = _blocks(q, k, causal, block_q, block_k, window)
     _count_tiles(causal, t, k.shape[1], bq, bk, tile, kernels=2,
-                 window=window)
+                 window=window, group=h // kv)
     scale = 1.0 / math.sqrt(dh)
     with jax.named_scope("layout"):
         do = _to_bh(g_out.astype(q.dtype))
@@ -810,14 +930,14 @@ def _bwd_impl(causal, block_q, block_k, res, g_out, g_lse=None,
     if g_lse is not None:
         dvec = dvec - g_lse.astype(jnp.float32).reshape(b * h, 1, t)
     with jax.named_scope("layout"):
-        qr, kr, vr = _to_bh(q), _to_bh(k), _to_bh(v)
+        qr, (kr, vr) = _to_bh(q), _kv_to_bh(k, v, h // kv, tile)
     dq, dk, dv = _flash_bwd_raw(qr, kr, vr, do, lse, dvec, causal=causal,
                                 bq=bq, bk=bk, scale=scale, tile=tile,
                                 interpret=_interpret(), window=window)
     with jax.named_scope("layout"):
         return (_from_bh(dq, b, h).astype(q.dtype),
-                _from_bh(dk, b, h).astype(k.dtype),
-                _from_bh(dv, b, h).astype(v.dtype))
+                _kv_from_bh(dk, b, kv).astype(k.dtype),
+                _kv_from_bh(dv, b, kv).astype(v.dtype))
 
 
 def _vjp_bwd(causal, block_q, block_k, window, res, g):

@@ -72,6 +72,29 @@ idle step in 32, keeps its band); the forward's time was not its mask
 but its statistics (a lane gather and a rotate a vreg a step to keep
 them as a column); K/V DMA a step is free (hidden under the block).
 
+The 8k cells' three shapes with K and V at their own head count (PR 35's
+builder's chip run, 4 Oct 2026; ``--kv-heads 8`` / ``8`` / ``2``; native =
+the kernels read (B, T, KV, Dh), repeated = ``jnp.repeat`` to the query
+heads before the call, the program before PR 35), us a batch·head,
+forward + dQ + dK/dV, and beside them ALL the device's operations of one
+forward + backward (``all``, ms: the kernels, the transposes around them,
+the repeat and the sum over a group):
+
+    48 over 8 (Laguna's full layers)
+      native:    152.29 + 187.81 + 237.64 = 577.74    all 28.712
+      repeated:  152.18 + 189.99 + 233.20 = 575.36    all 29.109
+    64 over 8, window 512 (its window layers)
+      native:     50.01 +  45.00 +  52.58 = 147.59    all 10.843
+      repeated:   50.38 +  45.56 +  56.71 = 152.65    all 11.916
+    32 over 2 (Nemotron's attention layer)
+      native:    145.08 + 174.23 + 217.16 = 536.47    all 17.770
+      repeated:  156.65 + 182.86 + 232.10 = 571.61    all 19.469
+
+The kernels themselves hardly care (the K/V DMA was hidden already; 16
+heads to a K/V head is where reading it once shows: − 6 %); what goes is
+around them (− 0.4, − 1.1 and − 1.7 ms a layer): the repeat, the group
+sum and K/V-side transposes at the query head count.
+
 "flash default" passes no blocks: causal lengths that fit VMEM whole take
 the in-kernel causal walk (``_causal_tile``), every other call the grid
 walk with ``_auto_block``'s blocks.  Explicit blocks are always the grid
@@ -82,7 +105,7 @@ per-step overhead and small matmuls alone, no longer by idle steps.
 Usage: python scripts/attn_block_bench.py [--seq 8192] [--dh 64]
        python scripts/attn_block_bench.py --seq 1024 --batch 32 --heads 12
        python scripts/attn_block_bench.py --kernels --batch 1 --heads 48 \
-           --dh 128 [--window 512]
+           --dh 128 [--window 512] [--kv-heads 8]
 """
 
 import argparse
@@ -103,6 +126,9 @@ def main():
     ap.add_argument("--iters", type=int, default=24)
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--window", type=int, default=None)
+    ap.add_argument("--kv-heads", type=int, default=None,
+                    help="K/V heads (a divisor of --heads): with --kernels, "
+                         "native against repeated to the query heads")
     ap.add_argument("--kernels", action="store_true",
                     help="the Mosaic kernels alone, from a device trace")
     args = ap.parse_args()
@@ -119,10 +145,15 @@ def main():
     enable_compile_cache()
 
     B, T, H, DH, N = args.batch, args.seq, args.heads, args.dh, args.iters
+    KV = args.kv_heads or H
     rng = np.random.default_rng(0)
     dt = jnp.dtype(args.dtype)
-    q0, k, v = (jnp.asarray(rng.normal(size=(B, T, H, DH)), dt)
-                for _ in range(3))
+    q0, k, v = (jnp.asarray(rng.normal(size=(B, T, heads, DH)), dt)
+                for heads in (H, KV, KV))
+
+    def repeated(attn):  # K/V at the query heads before the call
+        return lambda q, k, v: attn(q, jnp.repeat(k, H // KV, axis=2),
+                                    jnp.repeat(v, H // KV, axis=2))
 
     def measure(attn, mode, reps=5):
         if mode == "fwd":
@@ -164,32 +195,39 @@ def main():
             raise SystemExit("no device plane in the trace: a kernel's "
                              "time comes from a chip run")
         ops = devices[0][reduce_trace.OPS_LINE]
-        total = {}
+        total, everything = {}, 0.0
         for name, start, end in ops:
+            everything += end - start
             if reduce_trace.MOSAIC in name:
                 name = reduce_trace.op_name(name).split(":")[1]
                 total[name] = total.get(name, 0.0) + (end - start)
-        return {name: ns / 1e3 / N / (B * H) for name, ns in total.items()}
+        return ({name: ns / 1e3 / N / (B * H) for name, ns in total.items()},
+                everything / 1e6 / N)
 
     if args.kernels:
-        walks = [("default", ())] + [
-            (f"{b}x{b}", (b, b)) for b in (256, 512, 1024)
+        walks = [("default", (), False)] + [
+            (f"{b}x{b}", (b, b), False) for b in (256, 512, 1024)
             if T % b == 0 and args.window is None]
-        for label, blocks in walks:
+        if KV != H:  # the default blocks alone, native against repeated
+            walks = [(f"{H} over {KV} heads, native", (), False),
+                     (f"{H} over {KV} heads, repeated", (), True)]
+        for label, blocks, repeat in walks:
             blocks = blocks or (None, None)
+            attn = lambda q, k, v, blocks=blocks: flash_attention(  # noqa
+                q, k, v, True, *blocks, args.window)
             try:
-                us = kernels_alone(
-                    lambda q, k, v, blocks=blocks: flash_attention(
-                        q, k, v, True, *blocks, args.window))
+                us, all_ms = kernels_alone(repeated(attn) if repeat else attn)
             except Exception as e:  # noqa: BLE001 — a block VMEM refuses
                 print(f"kernels {label}: {str(e).splitlines()[0][:120]}")
                 continue
             print(f"kernels {label} (blocks, tile "
-                  f"{_blocks(q0, k, True, *blocks, args.window)}): "
+                  f"{_blocks(q0, q0, True, *blocks, args.window)}): "
                   + "  ".join(f"{n} {t:.2f}" for n, t in sorted(us.items()))
-                  + f"  sum {sum(us.values()):.2f} us a batch·head",
-                  flush=True)
+                  + f"  sum {sum(us.values()):.2f} us a batch·head"
+                  + f"  all {all_ms:.3f} ms", flush=True)
         return
+    if KV != H:  # the block ladder below is the equal-heads one
+        k, v = (jnp.repeat(x, H // KV, axis=2) for x in (k, v))
 
     d = lambda q, k, v: dot_product_attention(q, k, v, causal=True)  # noqa
     print(f"dense: fwd {measure(d, 'fwd'):.2f} ms  "

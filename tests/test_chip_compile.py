@@ -122,6 +122,27 @@ def test_window_attention_compiles(one_chip, mosaic, shape, dtype, mode):
     assert "%window_attn_fwd" in text and "%flash_fwd" not in text
 
 
+@pytest.mark.parametrize("heads,kv,window", [
+    (48, 8, None), (64, 8, 512), (32, 2, None)],
+    ids=["laguna-full", "laguna-window", "nemotron"])
+def test_attention_on_its_own_kv_heads_compiles(one_chip, mosaic, heads, kv,
+                                                window):
+    """The 8k cells' attention with K and V at their own head count: the
+    forward's and dQ's K/V index maps divide the head, dK/dV leads with
+    the K/V heads and walks a group's query heads a row (a table of
+    136 × 6 and 136 × 16 steps scalar-prefetched, or one grid axis more
+    on the band); nothing at the query heads' size is a broadcast."""
+    def attn(q, k, v):
+        return pallas_attention.flash_attention(q, k, v, True, None, None,
+                                                window)
+
+    q, _, _ = _qkv(one_chip, 1, 8192, heads, 128, "bfloat16")
+    k, v, _ = _qkv(one_chip, 1, 8192, kv, 128, "bfloat16")
+    text = _compile(jax.grad(_sq_loss(attn), argnums=(0, 1, 2)), q, k, v)
+    assert text.count("tpu_custom_call") >= 3
+    assert f"bf16[1,8192,{kv},{heads // kv},128]" not in text
+
+
 @pytest.mark.parametrize("dtype,tokens,mode", [
     ("bfloat16", 8192, "fwd_bwd"), ("float32", 16384, "fwd")],
     ids=["train-bf16", "check-f32"])

@@ -4,6 +4,7 @@ SwiGLU FFs) against the plain reference ``benchmark/reference/laguna.py``,
 and the pieces it is made of against plain formulas.  Small sizes, CPU,
 the Pallas kernels in interpret mode."""
 
+import functools
 import math
 import os
 import sys
@@ -409,3 +410,79 @@ def test_a_step_past_the_vmem_budget_walks_the_causal_grid(monkeypatch):
         np.testing.assert_allclose(
             a, b, rtol=2e-3, atol=2e-5 * float(jnp.max(jnp.abs(b))) + 1e-8,
             err_msg=jax.tree_util.keystr(path))
+
+
+def test_a_flash_step_holds_no_key_or_value_at_the_query_heads(monkeypatch):
+    """Grouped queries through the layer, on the 8k cell's own builder
+    arguments at a short T (48 and 64 query heads of 128 over 8 K/V
+    heads; 128-blocks forced so the full layer walks the table as at
+    T = 8,192): the step's jaxpr holds no K or V repeated to the query
+    heads, forward or backward, each ``pallas_call`` takes its key side
+    at 8 heads, the kernels are counted as reading K/V at their own head
+    count, and the dense implementation (which still repeats) gives the
+    same output and gradients."""
+    from distkeras_tpu.ops import attention as attention_ops
+    t, kv, dh = 256, 8, 128
+
+    def layer(impl, heads, window):
+        return MultiHeadAttention(heads, causal=True, impl=impl,
+                                  num_kv_heads=kv, head_dim=dh,
+                                  window=window, rope=True, gate=True)
+
+    def blocked(q, k, v, causal, t, window=None):
+        assert k.shape == v.shape == (1, t, kv, dh)  # as projected
+        return flash_attention(q, k, v, causal, 128, 128, window)
+
+    monkeypatch.setattr(attention_ops, "_flash_with_blocking", blocked)
+    counters = [default_registry().counter(f"flash.kv_{kind}_kernels")
+                for kind in ("native", "expanded")]
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(1, t, 64)),
+                    jnp.float32)
+    for heads, window in ((48, None), (64, 100)):
+        flash, dense = layer("flash", heads, window), layer("dense", heads,
+                                                             window)
+        params, state, _ = flash.init(jax.random.PRNGKey(1), (t, 64))
+        def loss(params, x, layer=flash):
+            return jnp.sum(layer.apply(params, state, x, train=True)[0] ** 2)
+
+        before = [c.value for c in counters]
+        jaxpr = jax.make_jaxpr(jax.grad(loss))(params, x)
+        got = jax.grad(loss)(params, x)
+        assert [c.value - b for c, b in zip(counters, before)] == [6, 0]
+        calls = [e for e in _eqns(jaxpr.jaxpr)
+                 if e.primitive.name == "pallas_call"]
+        assert len(calls) == 3
+        for call in calls:
+            leading = sorted({v.aval.shape[0] for v in call.invars
+                              if len(v.aval.shape) == 3})
+            assert leading == [kv, heads], (call.params["name"], leading)
+        # a repeat is a broadcast to (B, T, KV, G, Dh) and a reshape: the
+        # dense step holds one, the flash step nothing of that shape
+
+        def repeats(jaxpr):
+            return [v for eqn in _eqns(jaxpr.jaxpr) for v in eqn.outvars
+                    if v.aval.shape == (1, t, kv, heads // kv, dh)]
+
+        assert not repeats(jaxpr)
+        assert repeats(jax.make_jaxpr(jax.grad(
+            functools.partial(loss, layer=dense)))(params, x))
+        want = jax.grad(functools.partial(loss, layer=dense))(params, x)
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_allclose(
+                a, b, rtol=2e-3, atol=2e-5 * float(jnp.max(jnp.abs(b))))
+        np.testing.assert_allclose(
+            flash.apply(params, state, x)[0], dense.apply(params, state, x)[0],
+            rtol=2e-4, atol=2e-5)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) \
+                    else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)

@@ -341,14 +341,20 @@ def _pallas_calls(fn, *args) -> dict:
         if e.primitive.name == "pallas_call"}
 
 
-def _grid_walk(bh, tq, tk, dh, bq, bk, steps=None):
+def _grid_walk(bh, tq, tk, dh, bq, bk, steps=None, group=1):
     """What the three grid-walk kernels are built with, one block of each
     operand a step: a (bh, q blocks, k blocks) grid, the dK/dV kernel's
     the other way round (PR 26's tree); for a causal call a (bh,
-    ``steps``) grid, the block pairs with work alone."""
+    ``steps``) grid, the block pairs with work alone.  ``bh`` counts the
+    K/V heads: with ``group`` query heads to each, the forward and dQ
+    lead with ``bh * group`` rows, and dK/dV stays at ``bh``, a key
+    block's row ``group`` times as long (one axis more off the table)."""
     qb, kb, row = (1, bq, dh), (1, bk, dh), (1, 1, bq)
-    by_q = (bh, steps) if steps else (bh, tq // bq, tk // bk)
-    by_k = (bh, steps) if steps else (bh, tk // bk, tq // bq)
+    heads = bh * group
+    by_q = (heads, steps) if steps else (heads, tq // bq, tk // bk)
+    by_k = (bh, steps * group) if steps else (
+        (bh, tk // bk, tq // bq) if group == 1
+        else (bh, tk // bk, group, tq // bq))
     return {
         "flash_fwd": (by_q, [qb, kb, kb, qb, row]),
         "flash_bwd_dq": (by_q, [qb, kb, kb, qb, row, row, qb]),
@@ -356,22 +362,27 @@ def _grid_walk(bh, tq, tk, dh, bq, bk, steps=None):
     }
 
 
-@pytest.mark.parametrize("tq,tk,dh,causal,bq,bk,steps", [
-    (1024, 1024, 64, False, 1024, 1024, None),  # non-causal: a ring's far hop
-    (1024, 512, 64, False, 1024, 512, None),    # rectangular: a zigzag half hop
-    (384, 384, 32, False, 128, 128, None),      # non-causal, 3 blocks a side
-    (4096, 4096, 64, True, 1024, 1024, 10),  # causal, K/V over the VMEM budget
-    (4096, 4096, 128, True, 512, 512, 36),   # the same at head 128
-    (8192, 8192, 128, True, 512, 512, 136),  # Laguna's full layers
-    (128, 128, 64, True, 128, 128, 1),       # causal, one tile: nothing to skip
+@pytest.mark.parametrize("tq,tk,dh,causal,bq,bk,steps,group", [
+    (1024, 1024, 64, False, 1024, 1024, None, 1),  # non-causal: a ring's far hop
+    (1024, 512, 64, False, 1024, 512, None, 1),    # rectangular: a zigzag half hop
+    (384, 384, 32, False, 128, 128, None, 1),      # non-causal, 3 blocks a side
+    (4096, 4096, 64, True, 1024, 1024, 10, 1),  # causal, K/V over the VMEM budget
+    (4096, 4096, 128, True, 512, 512, 36, 1),   # the same at head 128
+    (8192, 8192, 128, True, 512, 512, 136, 1),  # Laguna's full layers
+    (128, 128, 64, True, 128, 128, 1, 1),       # causal, one tile: nothing to skip
+    (8192, 8192, 128, True, 512, 512, 136, 6),  # Laguna's: 48 heads over 8
+    (384, 384, 32, False, 128, 128, None, 4),   # non-causal, 4 heads a K/V head
 ], ids=["noncausal", "rectangular", "noncausal-384", "causal-4096",
-        "causal-4096-dh128", "causal-8192-dh128", "causal-128"])
-def test_other_calls_keep_the_grid_walk(tq, tk, dh, causal, bq, bk, steps):
+        "causal-4096-dh128", "causal-8192-dh128", "causal-128",
+        "causal-8192-dh128-group6", "noncausal-384-group4"])
+def test_other_calls_keep_the_grid_walk(tq, tk, dh, causal, bq, bk, steps,
+                                        group):
     """Non-causal and rectangular calls build the ``pallas_call``s they
     built before: same dense grids, same blocks.  Over-budget causal
-    calls keep the blocks, on a grid of the pairs with work alone."""
+    calls keep the blocks, on a grid of the pairs with work alone.  K/V
+    heads shared by a group keep their own count in all three."""
     from distkeras_tpu.ops.pallas_attention import flash_attention_lse
-    q = jnp.ones((1, tq, 2, dh), jnp.bfloat16)
+    q = jnp.ones((1, tq, 2 * group, dh), jnp.bfloat16)
     kv = jnp.ones((1, tk, 2, dh), jnp.bfloat16)
 
     def loss(q, k, v):
@@ -379,7 +390,7 @@ def test_other_calls_keep_the_grid_walk(tq, tk, dh, causal, bq, bk, steps):
         return jnp.sum(o.astype(jnp.float32)) + jnp.sum(lse)
 
     assert _pallas_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) \
-        == _grid_walk(2, tq, tk, dh, bq, bk, steps)
+        == _grid_walk(2, tq, tk, dh, bq, bk, steps, group)
 
 
 def test_explicit_blocks_keep_the_grid_walk_and_default_takes_the_kernel_walk():
@@ -392,6 +403,10 @@ def test_explicit_blocks_keep_the_grid_walk_and_default_takes_the_kernel_walk():
     grad = lambda *blocks: jax.grad(loss(*blocks), argnums=(0, 1, 2))  # noqa
     assert _pallas_calls(grad(256, 256), q, q, q) \
         == _grid_walk(2, 1024, 1024, 64, 256, 256, steps=10)
+    # four query heads over the two K/V heads: dK/dV's rows are twice as long
+    q4 = jnp.ones((1, 1024, 4, 64), jnp.bfloat16)
+    assert _pallas_calls(grad(256, 256), q4, q, q) \
+        == _grid_walk(2, 1024, 1024, 64, 256, 256, steps=10, group=2)
     whole, row = (1, 1024, 64), (1, 1, 1024)
     assert _pallas_calls(grad(), q, q, q) == {
         "flash_fwd": ((2,), [whole] * 4 + [row]),
@@ -489,3 +504,157 @@ def test_registry_counts_the_steps_a_walk_takes_and_the_tiles_it_masks(
     jax.make_jaxpr(lambda q: flash_attention(q, q, q, False))(q)
     assert _tile_counts(names) == tuple(
         b + g for b, g in zip(before, got))
+
+
+# ---------------------------------------------------------------------------
+# grouped queries: K and V at their own head count
+# ---------------------------------------------------------------------------
+
+#: walk -> (T, (causal, block_q, block_k, window)): each of the four grids
+GROUP_WALKS = {
+    "in-kernel-causal": (256, (True, None, None, None)),
+    "causal-table": (384, (True, 128, 128, None)),
+    "window-band": (384, (True, 128, 128, 168)),
+    "dense-noncausal": (256, (False, 128, 128, None)),
+}
+
+
+def _grouped(group, t, dtype, kv=2, dh=32, seed=0):
+    rng = np.random.default_rng(seed)
+    q, k, v, w = (jnp.asarray(rng.normal(size=(2, t, heads, dh)), dtype)
+                  for heads in (kv * group, kv, kv, kv * group))
+    return q, k, v, w.astype(jnp.float32)
+
+
+def _native_and_repeated(group, args, q, k, v, w):
+    """``(out, dq, dk, dv)`` of a call on (B, T, KV, Dh) K/V and of the
+    same call on K/V repeated to the query heads, the repeated call's
+    dk / dv summed over each group (the repeat's transpose)."""
+    native = lambda q, k, v: flash_attention(q, k, v, *args)  # noqa: E731
+    repeated = lambda q, k, v: native(  # noqa: E731
+        q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2))
+
+    def both(attn):
+        out, grads = jax.value_and_grad(
+            lambda q, k, v: (lambda o: (jnp.sum(o.astype(jnp.float32) * w),
+                                        o))(attn(q, k, v)),
+            argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (out[1], *grads)
+
+    return both(native), both(repeated)
+
+
+@pytest.mark.parametrize("walk", list(GROUP_WALKS))
+@pytest.mark.parametrize("group", [2, 4])
+def test_kv_heads_at_their_own_count_equal_repeated_heads(group, walk):
+    """``flash_attention`` on (B, T, KV, Dh) K/V against the same call
+    on ``jnp.repeat``-ed K/V, on each of the four grids: the output and
+    dq, dk, dv in float32 to 1e-5."""
+    t, args = GROUP_WALKS[walk]
+    native, repeated = _native_and_repeated(
+        group, args, *_grouped(group, t, jnp.float32))
+    assert native[2].shape == native[3].shape == (2, t, 2, 32)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), native, repeated):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("window", [None, 200], ids=["full", "window"])
+def test_kv_heads_at_their_own_count_in_bf16_at_lagunas_proportions(window):
+    """Six query heads of 128 to a K/V head, 128-blocks, bf16: the output
+    and dq are the repeated call's to the bit (the same bodies over the
+    same blocks in the same order); dk and dv are one float32 sum over
+    the group rounded once, where the repeated call rounds a head's and
+    sums after: within bf16's rounding of each other."""
+    native, repeated = _native_and_repeated(
+        6, (True, 128, 128, window),
+        *_grouped(6, 384, jnp.bfloat16, kv=1, dh=128))
+    for a, b in zip(native[:2], repeated[:2]):
+        assert a.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    for a, b in zip(native[2:], repeated[2:]):
+        assert a.dtype == jnp.bfloat16 and a.shape == (2, 384, 1, 128)
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, rtol=0.02,
+                                   atol=0.01 * float(np.abs(b).max()))
+
+
+def test_heads_that_do_not_divide_are_refused():
+    q = jnp.ones((1, 128, 3, 32))
+    k = jnp.ones((1, 128, 2, 32))
+    with pytest.raises(ValueError, match="whole multiple"):
+        flash_attention(q, k, k, True)
+
+
+def _kv_kernel_counts():
+    from distkeras_tpu.obs.registry import default_registry
+    return tuple(default_registry().counter(f"flash.kv_{kind}_kernels").value
+                 for kind in ("native", "expanded"))
+
+
+@pytest.mark.parametrize("walk", list(GROUP_WALKS))
+def test_registry_counts_the_kernels_on_native_and_on_repeated_heads(walk):
+    """``flash.kv_native_kernels`` / ``flash.kv_expanded_kernels``, once a
+    kernel put into a program: the grid walk's three read K/V at their
+    own head count; the in-kernel causal walk's three run on K/V
+    repeated inside ``flash_attention`` (and keep the whole-sequence
+    blocks, a grid step a query head).  Equal head counts add nothing."""
+    t, args = GROUP_WALKS[walk]
+    q, k, v, _ = _grouped(2, t, jnp.bfloat16)
+    grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, *args).astype(jnp.float32)), argnums=(0, 1, 2))
+    before = _kv_kernel_counts()
+    calls = _pallas_calls(grad, q, k, v)
+    native, expanded = (a - b for a, b in zip(_kv_kernel_counts(), before))
+    if walk == "in-kernel-causal":
+        assert (native, expanded) == (0, 3)
+        assert {grid for grid, _ in calls.values()} == {(2 * 4,)}
+    else:
+        assert (native, expanded) == (3, 0)
+        assert calls[("window_attn" if args[3] else "flash")
+                     + "_bwd_dkv"][0][0] == 2 * 2
+    jax.make_jaxpr(grad)(q, q, q)
+    assert _kv_kernel_counts() == tuple(
+        b + n for b, n in zip(before, (native, expanded)))
+
+
+def _index_maps(fn, *args) -> dict:
+    """{kernel name: (scalar-prefetch operands, the primitives its
+    blocks' index maps are made of)} of a function's ``pallas_call``s."""
+    return {
+        e.params["name"]: (
+            e.params["grid_mapping"].num_index_operands,
+            {eqn.primitive.name
+             for m in e.params["grid_mapping"].block_mappings
+             for eqn in _eqns(m.index_map_jaxpr.jaxpr)})
+        for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+        if e.primitive.name == "pallas_call"}
+
+
+@pytest.mark.parametrize("walk", list(GROUP_WALKS))
+def test_equal_head_counts_build_the_programs_they_always_built(walk):
+    """What keeps the GPT-2 cells and the ring's hops still: with as many
+    K/V heads as query heads no index map divides or multiplies a head
+    index, the table is three scalar-prefetched columns and no grid has
+    a group axis (the grids themselves are pinned above).  With a group
+    the forward and dQ divide (``head // G``) and dK/dV multiplies
+    (``kv·G + g``), on a fourth column or a fourth axis."""
+    t, args = GROUP_WALKS[walk]
+    q, k, v, _ = _grouped(2, t, jnp.bfloat16)
+    grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, *args).astype(jnp.float32)), argnums=(0, 1, 2))
+    table = walk == "causal-table"
+    head_arithmetic = {"div", "mul", "rem", "jit", "pjit"}
+    for name, (prefetched, primitives) in _index_maps(grad, q, q, q).items():
+        assert prefetched == (3 if table else 0), name
+        assert not primitives & head_arithmetic, (name, primitives)
+    if walk == "in-kernel-causal":
+        return  # repeated heads: the equal-heads program
+    grouped = _index_maps(grad, q, k, v)
+    for name, (prefetched, primitives) in grouped.items():
+        dkv = name.endswith("bwd_dkv")
+        assert prefetched == ((4 if dkv else 3) if table else 0), name
+        assert ("mul" if dkv else "div") in primitives, (name, primitives)
+    assert len(_pallas_calls(grad, q, k, v)[
+        ("window_attn" if args[3] else "flash") + "_bwd_dkv"][0]) == (
+            2 if table else 4)

@@ -281,3 +281,30 @@ def test_without_remat_the_tags_cost_the_step_nothing(monkeypatch):
     monkeypatch.setattr(remat, "checkpoint_name", lambda x, name: x)
     jax.clear_caches()  # the jitted launchers' and the step's traces
     assert lowered() == tagged
+
+
+@pytest.mark.parametrize("window", [None, 64], ids=["in-kernel-walk",
+                                                     "window-band"])
+def test_a_grouped_attention_child_is_sized_at_its_own_kv_heads(
+        window, monkeypatch):
+    """What a kept attention child holds for its backward is K and V as
+    projected: against the same child on K/V repeated to the query heads
+    (the step before the kernels read K/V at their own head count) the
+    plan's ``whole`` falls by 2·(H − KV)·B·T·Dh·itemsize, on either walk,
+    and what a checkpoint keeps (``saved``) is what it was."""
+    from distkeras_tpu.ops import attention
+    heads, kv, dh, (b, t, d) = 8, 2, 16, (2, 256, 32)
+    layer = attention.MultiHeadAttention(
+        heads, causal=True, impl="flash", num_kv_heads=kv, head_dim=dh,
+        window=window)
+    params, state, _ = layer.init(jax.random.PRNGKey(0), (t, d))
+    args = (params, state, jax.ShapeDtypeStruct((b, t, d), jnp.float32), None)
+    call = functools.partial(layer.apply, train=True)
+    _, whole, named = remat._trace_child(call, *args)
+    flash = attention._flash_with_blocking
+    monkeypatch.setattr(
+        attention, "_flash_with_blocking", lambda q, k, v, *rest: flash(
+            q, layer._expand_kv(k), layer._expand_kv(v), *rest))
+    _, whole_repeated, named_repeated = remat._trace_child(call, *args)
+    assert named == named_repeated > 0
+    assert whole_repeated - whole == 2 * (heads - kv) * b * t * dh * 4
