@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.registry import default_registry
+
 LAYER_REGISTRY: dict[str, type] = {}
 
 
@@ -177,15 +179,37 @@ class Layer:
         y, _ = self.apply(params, state, x, train=False)
         return y, cache
 
+    # -- what a recompute plan keeps or runs again ---------------------------
+
+    def applications(self, params, state, *, train: bool = False) -> list:
+        """The layer as the applications a step's recompute plan decides
+        on (``models.remat.Application``), in the order they run: one for
+        a plain layer, one a pass and child for a ``Looped``."""
+        from .remat import Application
+        return [Application(functools.partial(self.apply, train=train),
+                            params, state)]
+
+    def apply_calls(self, calls, params, state, x, *, rng=None):
+        """``apply`` through the calls of its :meth:`applications`, as the
+        plan left them (under ``remat.checkpoint`` or as they were)."""
+        (call,) = calls
+        return call(params, state, x, rng=rng)
+
+    def decode_logits(self, out):
+        """The logits a user decodes from, of what ``apply`` gave."""
+        return out
+
     def iter_layers(self):
         """Yield this layer and every nested layer (depth-first through
-        the composition attributes: ``layers``, ``inner``, ``shortcut``).
-        The public way to find/configure layers inside a built model —
-        e.g. attaching a mesh to every ``MoEDense``."""
+        the composition attributes: ``layers``, ``body``, ``inner``,
+        ``shortcut``, ``closing``).  The public way to find/configure
+        layers inside a built model — e.g. attaching a mesh to every
+        ``MoEDense``."""
         yield self
-        for sub in getattr(self, "layers", None) or []:
-            yield from sub.iter_layers()
-        for attr in ("inner", "shortcut"):
+        for attr in ("layers", "body"):
+            for sub in getattr(self, attr, None) or []:
+                yield from sub.iter_layers()
+        for attr in ("inner", "shortcut", "closing"):
             sub = getattr(self, attr, None)
             if isinstance(sub, Layer):
                 yield from sub.iter_layers()
@@ -806,32 +830,41 @@ class Sequential(Layer):
 
     def apply(self, params, state, x, *, train=False, rng=None,
               remat=False):
-        """``remat``: the backward may recompute children to fit.  True,
-        or the step's ``remat.Plan``: each child before the plan's first
-        kept one runs under ``remat.checkpoint`` (its input and its
-        attention kernels' outputs are held, the rest is run again while
-        the child is differentiated); the last child never is, and a plan
-        with a budget keeps whole children from the end backward while
-        their estimate fits (none where the budget is unknown: True
+        """``remat``: the backward may recompute to fit.  True, or the
+        step's ``remat.Plan``: the children are laid out as their
+        applications in the order they run (a plain child is one, a
+        ``Looped`` one a pass and child), and each application before the
+        plan's first kept one runs under ``remat.checkpoint`` (its input
+        and its attention kernels' outputs are held, the rest is run
+        again while it is differentiated); the last never is, and a plan
+        with a budget keeps whole applications from the end backward
+        while their estimate fits (none where the budget is unknown: True
         alone, or a device that reports no limit)."""
-        calls = [functools.partial(lyr.apply, train=train)
-                 for lyr in self.layers]
+        own = [lyr.applications(params[i], state[i], train=train)
+               for i, lyr in enumerate(self.layers)]
+        calls = [[a.call for a in of_child] for of_child in own]
         if remat:
             from . import remat as plans
             plan = remat if isinstance(remat, plans.Plan) else plans.Plan()
-            # every child's key has ``rng``'s shape: it stands in for them
-            first_kept = plan.first_kept_of(calls, params, state, x, rng)
-            calls = [plans.checkpoint(call) if i < first_kept else call
-                     for i, call in enumerate(calls)]
+            flat = [a for of_child in own for a in of_child]
+            # every application's key has ``rng``'s shape: it stands in
+            first_kept = plan.first_kept_of(flat, x, rng)
+            wrapped = iter([plans.checkpoint(a.call) if i < first_kept
+                            else a.call for i, a in enumerate(flat)])
+            calls = [[next(wrapped) for _ in of_child] for of_child in own]
         new_state = []
         for i, lyr in enumerate(self.layers):
             sub = None
             if rng is not None:
                 rng, sub = jax.random.split(rng)
             with _scope(lyr):
-                x, s = calls[i](params[i], state[i], x, rng=sub)
+                x, s = lyr.apply_calls(calls[i], params[i], state[i], x,
+                                       rng=sub)
             new_state.append(s)
         return x, new_state
+
+    def decode_logits(self, out):
+        return self.layers[-1].decode_logits(out)
 
     def init_cache(self, batch, in_shape):
         caches, shape = [], tuple(in_shape)
@@ -862,3 +895,208 @@ class Sequential(Layer):
     def from_config(cls, cfg):
         return cls([layer_from_config(c) for c in cfg["layers"]],
                    input_shape=cfg.get("input_shape"))
+
+
+@jax.custom_vjp
+def _handed_on(x):
+    """A pass's output as the next pass's input: the same values under a
+    variable of their own.  The output is read twice (by what follows the
+    loop and by the next pass), and a backward adds a variable's
+    cotangents in the order it meets them; with this the next pass's are
+    added up on their own, whether its first application is differentiated
+    under a checkpoint or in the open, so a recompute plan moves no bit of
+    a gradient."""
+    return x
+
+
+_handed_on.defvjp(lambda x: (x, None), lambda _, ct: (ct,))
+
+
+@register
+class Looped(Layer):
+    """A stack of layers run ``steps`` times over ONE set of parameters
+    (Dehghani et al. 2019, Universal Transformers; the looped language
+    models of arXiv:2510.25741): pass t applies ``body``'s children in
+    order and then ``closing`` to pass t - 1's output (the first pass to
+    the layer's input), so input and output shapes are equal.
+
+    ``params = {"body": [...], "closing": ...}``, held once whatever
+    ``steps`` is; a parameter's gradient is the sum over the passes.
+    ``apply`` returns a TUPLE of the ``steps`` passes' outputs, the last
+    pass's last (a tuple and not a stacked array: what follows reads each
+    pass where it lies).  The passes are unrolled, each under
+    ``jax.named_scope("pass_<t>")``, so a recompute plan decides on every
+    (pass, child) application on its own (:meth:`applications`).  A
+    child's state is threaded through the passes."""
+
+    #: no decode rule yet (a K/V cache a (pass, layer): ROADMAP R7), so
+    #: generation recomputes the whole context
+    time_mixing = True
+
+    def __init__(self, body: Sequence[Layer], steps: int, closing: Layer):
+        if int(steps) < 1:
+            raise ValueError(f"steps must be >= 1, got {steps}")
+        self.body = list(body)
+        self.steps = int(steps)
+        self.closing = closing
+
+    @property
+    def _children(self):
+        return [*self.body, self.closing]
+
+    @staticmethod
+    def _flat(tree):
+        """``{"body": [...], "closing": c}`` in ``_children``'s order."""
+        return [*tree["body"], tree["closing"]]
+
+    def init(self, rng, in_shape):
+        # a key a child, split as ``Sequential`` splits them
+        params, state, shape = [], [], tuple(in_shape)
+        for lyr in self._children:
+            rng, sub = jax.random.split(rng)
+            p, s, shape = lyr.init(sub, shape)
+            params.append(p)
+            state.append(s)
+        if self.steps > 1 and tuple(shape) != tuple(in_shape):
+            raise ValueError(f"a pass maps {tuple(in_shape)} to "
+                             f"{tuple(shape)}: it cannot be run again")
+        return ({"body": params[:-1], "closing": params[-1]},
+                {"body": state[:-1], "closing": state[-1]},
+                (tuple(shape),) * self.steps)
+
+    def out_shape(self, in_shape):
+        shape = tuple(in_shape)
+        for lyr in self._children:
+            shape = lyr.out_shape(shape)
+        return (shape,) * self.steps
+
+    def applications(self, params, state, *, train=False):
+        from .remat import Application
+        one_pass = [Application(functools.partial(lyr.apply, train=train),
+                                p, s)
+                    for lyr, p, s in zip(self._children, self._flat(params),
+                                         self._flat(state))]
+        return one_pass * (self.steps - 1) + one_pass[:-1] + [
+            one_pass[-1]._replace(fan_out=self.steps)]
+
+    def apply_calls(self, calls, params, state, x, *, rng=None):
+        # counted where the loop is unrolled into a program, as the
+        # kernels count their tiles
+        registry = default_registry()
+        registry.counter("loop.passes").inc(self.steps)
+        registry.counter("loop.applications").inc(
+            self.steps * len(self.body))
+        n = len(self.body) + 1
+        params, state, outs = self._flat(params), self._flat(state), []
+        for t in range(self.steps):
+            with jax.named_scope(f"pass_{t}"):
+                if t:
+                    x = _handed_on(x)
+                for i, lyr in enumerate(self._children):
+                    sub = None
+                    if rng is not None:
+                        rng, sub = jax.random.split(rng)
+                    with _scope(lyr):
+                        x, state[i] = calls[t * n + i](params[i], state[i],
+                                                       x, rng=sub)
+            outs.append(x)
+        return tuple(outs), {"body": state[:-1], "closing": state[-1]}
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        calls = [a.call for a in self.applications(params, state,
+                                                   train=train)]
+        return self.apply_calls(calls, params, state, x, rng=rng)
+
+    def get_config(self):
+        return {"body": [l.config() for l in self.body],
+                "steps": self.steps, "closing": self.closing.config()}
+
+    @classmethod
+    def from_config(cls, cfg):
+        return cls([layer_from_config(c) for c in cfg["body"]],
+                   cfg["steps"], layer_from_config(cfg["closing"]))
+
+
+@register
+class ExitHeads(Layer):
+    """What follows a ``Looped`` stack in a language model that may answer
+    after any pass: ONE head (``units`` logits, no bias) and ONE exit gate
+    (a ``Dense(1)`` with bias, in float32: ``parallel.sync.FLOAT32_KEYS``
+    names its key) applied to every pass's output.
+
+    ``apply`` takes the tuple of passes and returns ``{"logits": a tuple
+    of (B, T, units), one a pass, "exit_gate": (B, T, steps) float32}``,
+    in training and in prediction alike; ``ops.losses``'s
+    ``exit_weighted_crossentropy`` reads it whole.  A pass's gate value g
+    gives the chance sigmoid(g) of answering there if no earlier pass
+    did; the last pass takes what is left (``ops.losses.exit_log_probs``).
+    :meth:`decode_logits` gives each token the logits of the first pass
+    at which the exit chances add up to ``threshold`` (at 1.0, the
+    last).  In training the state's ``exit_share`` holds the step's mean
+    chance of each pass (``loop.exit_share.<t>``, set by the trainer when
+    the variables are back on the host)."""
+
+    def __init__(self, units: int, threshold: float = 1.0):
+        self.units = int(units)
+        self.threshold = float(threshold)
+
+    def init(self, rng, in_shape):
+        steps, (*_, d) = len(in_shape), in_shape[0]
+        k1, k2 = jax.random.split(rng)
+        params = {"head": {"kernel": glorot_uniform(k1, (d, self.units))},
+                  "exit_gate": {"kernel": glorot_uniform(k2, (d, 1)),
+                                "bias": jnp.zeros((1,))}}
+        return params, {"exit_share": jnp.zeros((steps,))}, \
+            self.out_shape(in_shape)
+
+    def out_shape(self, in_shape):
+        return {"logits": tuple((*s[:-1], self.units) for s in in_shape),
+                "exit_gate": (*in_shape[0][:-1], len(in_shape))}
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        from ..ops.losses import exit_log_probs
+        head, gate = params["head"]["kernel"], params["exit_gate"]
+        logits, values = [], []
+        for t, h in enumerate(x):
+            with jax.named_scope(f"pass_{t}"):
+                logits.append(h @ head.astype(h.dtype))
+                with jax.named_scope("exit_gate"):
+                    # float32 from the pass's output on: the last pass's
+                    # chance is a product of the earlier ones' complements
+                    values.append(jnp.dot(
+                        h.astype(jnp.float32),
+                        gate["kernel"].astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+                        + gate["bias"].astype(jnp.float32))
+        values = jnp.concatenate(values, axis=-1)
+        if train:
+            share = jnp.exp(exit_log_probs(lax.stop_gradient(values)))
+            state = {"exit_share": jnp.mean(
+                share.reshape(-1, share.shape[-1]), axis=0)}
+        return {"logits": tuple(logits), "exit_gate": values}, state
+
+    def decode_logits(self, out):
+        from ..ops.losses import exit_log_probs
+        logits = out["logits"]
+        mass = jnp.cumsum(jnp.exp(exit_log_probs(out["exit_gate"])),
+                          axis=-1)[..., :-1] >= self.threshold
+        # the first pass that reaches the threshold; none: the last
+        first = jnp.where(jnp.any(mass, axis=-1), jnp.argmax(mass, axis=-1),
+                          len(logits) - 1)[..., None]
+        picked = logits[-1]
+        for t in reversed(range(len(logits) - 1)):
+            picked = jnp.where(first == t, logits[t], picked)
+        return picked
+
+    def get_config(self):
+        return {"units": self.units, "threshold": self.threshold}
+
+
+def state_leaves(state, key: str) -> list:
+    """Every leaf of a variables-state tree that sits under the dict key
+    ``key`` (a routed layer's ``aux_loss``, an ``ExitHeads``'
+    ``exit_share``), in the tree's order."""
+    from jax.tree_util import DictKey, tree_flatten_with_path
+    return [leaf for path, leaf in tree_flatten_with_path(state)[0]
+            if path and isinstance(path[-1], DictKey)
+            and path[-1].key == key]

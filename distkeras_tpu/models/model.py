@@ -53,6 +53,14 @@ class Model:
             return y
         return fn
 
+    def decode_logits(self, out):
+        """The logits a user decodes from, of what ``apply`` or
+        ``predict_fn`` gave: ``out`` itself for a model with one answer; for
+        one that may answer after any pass (``layers.ExitHeads``) each
+        token's logits at the first pass whose exit chances add up to its
+        threshold."""
+        return self.layer.decode_logits(out)
+
     # -- serde --------------------------------------------------------------
     def config(self) -> dict:
         return {"name": self.name, "input_shape": list(self.input_shape),

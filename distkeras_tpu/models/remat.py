@@ -10,14 +10,18 @@ what the code can observe (shapes, dtypes, the device's memory limit):
    ``ops.pallas_ssm`` tag them with :func:`name_kernel_outputs`): a recomputed
    child rebuilds the kernels' inputs and reads ``out`` / ``lse`` (``y``
    and the chunks' states) as kept, so the forward kernels run once;
-2. the last child of a ``Sequential`` is never wrapped: its backward
-   begins where its forward ends, a checkpoint there buys no memory;
-3. whole children are kept, from the last one backward, while an estimate
-   of the backward's peak fits the budget (:func:`keep_from_end`,
-   :func:`peak`).  Late children's residuals are freed first in the
-   backward, while the gradients are still few.  Where the device reports
-   no limit (the CPU) no child but the last is kept, so a program does not
-   depend on the host's memory.
+2. the unit that is kept or run again is an APPLICATION
+   (:class:`Application`): a child of the model's ``Sequential`` where it
+   runs once, a (pass, child) of a ``Looped`` stack, which runs its
+   children several times over one set of parameters.  The last
+   application is never wrapped: its backward begins where its forward
+   ends, a checkpoint there buys no memory;
+3. whole applications are kept, from the last one backward, while an
+   estimate of the backward's peak fits the budget
+   (:func:`keep_from_end`, :func:`peak`).  Late applications' residuals
+   are freed first in the backward, while the gradients are still few.
+   Where the device reports no limit (the CPU) no application but the
+   last is kept, so a program does not depend on the host's memory.
 
 The estimate is coarse and errs on the side of recompute; the compiled
 program is the judge (:meth:`Plan.judge`, called by the trainer after the
@@ -28,7 +32,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Optional, Sequence
+from typing import Any, Callable, NamedTuple, Optional, Sequence
 
 import jax
 import numpy as np
@@ -48,8 +52,20 @@ KERNEL_OUTPUTS = tuple(n for names in KERNEL_OUTPUT_NAMES.values()
                        for n in names)
 #: the share of the device's limit that the estimate may fill
 FILL = 0.92
-#: a compiled program over this share of the limit: one child back
+#: a compiled program over this share of the limit: one application back
 REFUSE = 0.95
+
+
+class Application(NamedTuple):
+    """One run of one layer in a step: ``call(params, state, x, rng=rng)
+    -> (y, state)``.  The applications of a model are chained in the
+    order they run, each on the one before's output; ``fan_out``: what is
+    handed on is a tuple of that many outputs of this shape (the last
+    application of a ``Looped`` stack: every pass's output)."""
+    call: Callable
+    params: Any
+    state: Any
+    fan_out: Optional[int] = None
 
 
 _SIZING = threading.local()
@@ -177,12 +193,13 @@ class Plan:
     """The recompute plan of one step program.
 
     ``budget``: the bytes the forward's residuals and the gradients may
-    take at one time (None: unknown, keep no child but the last).
+    take at one time (None: unknown, keep no application but the last).
     ``make_local_step`` sets it from the device's limit less what the
     step holds throughout; a test passes its own.  After a trace
     ``first_kept`` / ``children`` / ``bytes_estimated`` say what was
-    decided, ``sizes`` the children's estimates; ``stepped_back`` counts
-    the children a judge took back."""
+    decided (``children`` counts applications: a child that runs four
+    times is four), ``sizes`` the applications' estimates;
+    ``stepped_back`` counts the applications a judge took back."""
 
     def __init__(self, budget: Optional[float] = None):
         self.budget = budget
@@ -204,16 +221,16 @@ class Plan:
         self.budget = None if limit is None else FILL * limit - held
 
     # -- the decision, at trace time ------------------------------------------
-    def first_kept_of(self, calls, params, state, x, rng) -> int:
-        """Index of the first child of a ``Sequential`` left unwrapped
-        (``calls[i](params[i], state[i], x, rng=rng)`` applies child
-        ``i``); counts the decision into the registry."""
-        n = len(calls)
+    def first_kept_of(self, applications, x, rng) -> int:
+        """Index of the first of a model's ``applications`` (in the order
+        they run, the first on ``x``) left unwrapped; counts the decision
+        into the registry."""
+        n = len(applications)
         span = (self.tracer or default_tracer()).span
         with span("train.remat_plan", children=n) as record:
             first, estimated = n - 1, 0
             if self.budget is not None and n > 1:
-                self.sizes = _children_sizes(calls, params, state, x, rng)
+                self.sizes = _children_sizes(applications, x, rng)
                 first = min(keep_from_end(self.sizes, self.budget)
                             + self.stepped_back, n - 1)
                 estimated = self.held + peak(self.sizes, first)
@@ -236,8 +253,8 @@ class Plan:
     # -- the judge, after the compile -------------------------------------------
     def judge(self, compiled_bytes: int) -> bool:
         """Records the compiled program's size (arguments + temporaries);
-        True where it passes ``REFUSE`` of the limit and a child can still
-        be taken back: the caller compiles again."""
+        True where it passes ``REFUSE`` of the limit and an application
+        can still be taken back: the caller compiles again."""
         self.bytes_compiled = int(compiled_bytes)
         default_registry().gauge("remat.bytes_compiled").set(
             self.bytes_compiled)
@@ -246,8 +263,8 @@ class Plan:
                 and self.step_back())
 
     def step_back(self) -> bool:
-        """One more child recomputed at the next trace; False where none
-        is left to take back."""
+        """One more application recomputed at the next trace; False where
+        none is left to take back."""
         if self.first_kept >= self.children - 1:
             return False
         self.stepped_back += 1
@@ -262,18 +279,19 @@ class Plan:
                 "remat_bytes_compiled": self.bytes_compiled}
 
 
-def _children_sizes(calls, params, state, x, rng) -> list:
-    """For each child the bytes :func:`peak` reads: ``saved`` (its input
-    and its named kernel outputs: what a checkpoint holds), ``whole`` (what
-    its backward holds where it is kept) and ``grads`` (a float32 gradient
-    a parameter).  Children of equal call, shapes and dtypes are traced
-    once."""
+def _children_sizes(applications, x, rng) -> list:
+    """For each application the bytes :func:`peak` reads: ``saved`` (its
+    input and its named kernel outputs: what a checkpoint holds),
+    ``whole`` (what its backward holds where it is kept) and ``grads`` (a
+    float32 gradient a parameter; parameters applied several times count
+    at their last application, where the backward first meets them).
+    Applications of equal call, shapes and dtypes are traced once."""
     shaped = lambda tree: jax.tree_util.tree_map(  # noqa: E731
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
     x, rng = shaped(x), shaped(rng)
     known, sizes = {}, []
-    for i, call in enumerate(calls):
-        args = (shaped(params[i]), shaped(state[i]), x, rng)
+    for call, params, state, fan_out in applications:
+        args = (shaped(params), shaped(state), x, rng)
         key = (_call_key(call), str(jax.tree_util.tree_structure(args)),
                tuple((a.shape, str(a.dtype))
                      for a in jax.tree_util.tree_leaves(args)))
@@ -282,10 +300,14 @@ def _children_sizes(calls, params, state, x, rng) -> list:
                 known[key] = _trace_child(call, *args)
         out, whole, named = known[key]
         saved = tree_bytes(x) + named
-        sizes.append({"saved": saved, "whole": max(whole, saved),
-                      "grads": 4 * sum(a.size for a in
-                                       jax.tree_util.tree_leaves(params[i]))})
-        x = out
+        sizes.append({"saved": saved, "whole": max(whole, saved)})
+        x = out if fan_out is None else (out,) * fan_out
+    met = set()
+    for size, application in zip(reversed(sizes), reversed(applications)):
+        leaves = [a for a in jax.tree_util.tree_leaves(application.params)
+                  if id(a) not in met]
+        met.update(id(a) for a in leaves)
+        size["grads"] = 4 * sum(a.size for a in leaves)
     return sizes
 
 

@@ -280,6 +280,8 @@ def decoder_lm(vocab_size: int, hidden_size: int, num_hidden_layers: int,
                moe_routed_scaling_factor: float = 1.0,
                norm_topk_prob: bool = True,
                experts_held: Optional[int] = None, first_expert: int = 0,
+               total_ut_steps: int = 1, early_exit_threshold: float = 1.0,
+               sandwich_norm: bool = False,
                attention_impl: str = "dense") -> Model:
     """Decoder-only language model of the current kind, built from the
     per-layer lists a published ``config.json`` gives (the keyword names
@@ -304,12 +306,28 @@ def decoder_lm(vocab_size: int, hidden_size: int, num_hidden_layers: int,
     ``experts_held`` / ``first_expert``: one chip's share of an
     expert-parallel deployment — every sparse layer routes over all
     ``num_experts`` and holds (has parameters for, computes) only the
-    ``experts_held`` from ``first_expert``; default all."""
+    ``experts_held`` from ``first_expert``; default all.
+
+    ``sandwich_norm``: a second RMSNorm on each sublayer's output, before
+    it is added: ``h = x + norm(Attn(norm(x)))``, ``y = h + norm(FF(
+    norm(h)))``.  ``total_ut_steps`` > 1 (the ``ouro`` family's keys): the
+    layers and the final norm run that many times over ONE set of
+    parameters, each pass on the one before's normed output
+    (``layers.Looped``), and one head and one exit gate read every pass
+    (``layers.ExitHeads``): the output is ``{"logits": one array a pass,
+    "exit_gate": (B, T, passes)}`` (``loss="exit_weighted_crossentropy"``),
+    and ``Model.decode_logits`` gives each token the first pass whose
+    exit chances reach ``early_exit_threshold``."""
     from ..ops.attention import MultiHeadAttention
     from ..ops.moe import SparseMoE
-    from .layers import RMSNorm, SwiGLU
+    from .layers import ExitHeads, Looped, RMSNorm, SwiGLU
     rope_parameters = rope_parameters or {}
-    layers = [Embedding(vocab_size, hidden_size)]
+
+    def block(mixer):
+        after = [RMSNorm(rms_norm_eps)] if sandwich_norm else []
+        return Residual(Sequential([RMSNorm(rms_norm_eps), mixer, *after]))
+
+    layers = []
     for kind, heads, mlp in list(zip(layer_types,
                                      num_attention_heads_per_layer,
                                      mlp_layer_types))[:num_hidden_layers]:
@@ -336,10 +354,15 @@ def decoder_lm(vocab_size: int, hidden_size: int, num_hidden_layers: int,
                            first_expert=first_expert)
         else:
             raise ValueError(f"unknown mlp layer type {mlp!r}")
-        layers.append(Residual(Sequential([RMSNorm(rms_norm_eps),
-                                           attention])))
-        layers.append(Residual(Sequential([RMSNorm(rms_norm_eps), ff])))
-    layers += [RMSNorm(rms_norm_eps), Dense(vocab_size, use_bias=False)]
+        layers += [block(attention), block(ff)]
+    embedding, norm = Embedding(vocab_size, hidden_size), \
+        RMSNorm(rms_norm_eps)
+    if total_ut_steps > 1:
+        layers = [embedding, Looped(layers, total_ut_steps, norm),
+                  ExitHeads(vocab_size, early_exit_threshold)]
+    else:
+        layers = [embedding, *layers, norm,
+                  Dense(vocab_size, use_bias=False)]
     return Model(Sequential(layers), input_shape=(seq_len,),
                  name="decoder_lm")
 

@@ -51,6 +51,44 @@ def mean_absolute_error(preds, targets):
     return jnp.mean(jnp.abs(preds - targets))
 
 
+def exit_log_probs(gate):
+    """``log p`` (..., steps), float32, of the pass a token answers from,
+    from its exit gate's values ``g`` (..., steps): with ``λ_t =
+    sigmoid(g_t)`` the chance of answering at pass t if no earlier pass
+    did, ``p_t = λ_t Π_{j<t} (1 - λ_j)`` and the last pass takes what is
+    left, ``p_steps = Π_{j<steps} (1 - λ_j)``, so the chances add up to
+    one.  Sums of ``log_sigmoid``, never the log of a product."""
+    gate = gate.astype(jnp.float32)
+    stay = jax.nn.log_sigmoid(-gate)                  # log (1 - λ_t)
+    before = jnp.cumsum(stay, axis=-1) - stay         # Σ_{j<t} log (1 - λ_j)
+    return jnp.concatenate(
+        [(before + jax.nn.log_sigmoid(gate))[..., :-1], before[..., -1:]],
+        axis=-1)
+
+
+def exit_weighted_crossentropy(out, targets, beta: float = 0.1):
+    """The first-stage loss of a looped language model that may answer
+    after any pass (arXiv:2510.25741, uniform prior): the mean over
+    tokens of ``Σ_t p_t nll_t - beta H(p)``, with ``nll_t`` the
+    cross-entropy of pass t's logits, ``p`` the exit distribution of
+    :func:`exit_log_probs` and ``H`` its entropy.  ``out`` is what
+    ``models.layers.ExitHeads`` gives: ``{"logits": one (B, T, V) a
+    pass, "exit_gate": (B, T, steps)}``; ``targets`` int ids (B, T).
+    Float32 throughout, whatever the logits' dtype."""
+    log_p = exit_log_probs(out["exit_gate"])
+    p = jnp.exp(log_p)
+    ids = targets.astype(jnp.int32)[..., None]
+    nll = []
+    for t, logits in enumerate(out["logits"]):
+        with jax.named_scope(f"pass_{t}"):
+            logits = logits.astype(jnp.float32)
+            nll.append(jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+                logits, ids, axis=-1)[..., 0])
+    expected = jnp.sum(p * jnp.stack(nll, axis=-1), axis=-1)
+    entropy = -jnp.sum(p * log_p, axis=-1)
+    return jnp.mean(expected - beta * entropy)
+
+
 LOSSES: dict[str, Callable] = {
     "categorical_crossentropy": categorical_crossentropy,
     "sparse_categorical_crossentropy": sparse_categorical_crossentropy,
@@ -59,6 +97,7 @@ LOSSES: dict[str, Callable] = {
     "mse": mean_squared_error,
     "mean_absolute_error": mean_absolute_error,
     "mae": mean_absolute_error,
+    "exit_weighted_crossentropy": exit_weighted_crossentropy,
 }
 
 

@@ -33,7 +33,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models import remat as remat_plans
-from ..models.layers import Sequential
+from ..models.layers import Sequential, state_leaves
 from .mesh import make_mesh, shard_map
 
 Tree = Any
@@ -90,18 +90,17 @@ def _expand0(tree):
 def aux_losses(state: Tree) -> list:
     """Collect every ``aux_loss`` leaf from a variables-state tree (each
     ``MoEDense`` writes its router load-balance scalar there)."""
-    from jax.tree_util import DictKey, tree_flatten_with_path
-    return [leaf for path, leaf in tree_flatten_with_path(state)[0]
-            if path and isinstance(path[-1], DictKey)
-            and path[-1].key == "aux_loss"]
+    return state_leaves(state, "aux_loss")
 
 
 #: parameter keys whose leaves (and everything under them) a mixed-precision
 #: step hands to the forward uncast, as the float32 master weights: a
 #: routed layer scores its experts in float32 (``ops.moe.route_top_k``:
 #: ``router``), and a state-space mixer's decay rates, step bias and skip
-#: are float32 in the published layer (``ops.ssm.Mamba2Mixer``)
-FLOAT32_KEYS = frozenset({"router", "A_log", "dt_bias", "D"})
+#: are float32 in the published layer (``ops.ssm.Mamba2Mixer``); an exit
+#: gate's values become chances that are multiplied pass over pass
+#: (``models.layers.ExitHeads``: ``exit_gate``)
+FLOAT32_KEYS = frozenset({"router", "A_log", "dt_bias", "D", "exit_gate"})
 
 
 def make_local_step(model, loss_fn: Callable,
@@ -120,13 +119,16 @@ def make_local_step(model, loss_fn: Callable,
     activation footprint, not weights, is what OOMs.  How much is the
     step's ``models.remat.Plan`` (``step.remat_plan``), decided at trace
     time from the shapes and the device's memory limit: a ``Sequential``
-    model is checkpointed child by child, the attention kernels' outputs
-    are kept, the last child is not wrapped, and whole children are kept
-    from the end backward while the backward's estimated peak (residuals
-    and gradients) fits the limit less the step's arguments and cast
-    copies (none where the device reports no limit); any other layer is
-    wrapped whole under the same policy.  ``remat=False``: the forward is
-    called as it is.
+    model is checkpointed application by application (a child that runs
+    once is one, a ``Looped`` child one a pass and child of its body),
+    the attention and scan kernels' outputs are kept, the last
+    application is not wrapped, and whole applications are kept from the
+    end backward while the backward's estimated peak (residuals and
+    gradients) fits the limit less the step's arguments and cast copies
+    (none where the device reports no limit); any other layer is wrapped
+    whole under the same policy.  ``remat=False``: the forward is called
+    as it is.  ``loss_fn`` gets the model's output as the model gave it,
+    an array or a structure of them.
 
     ``aux_weight > 0`` folds ``aux_weight * Σ state['aux_loss']`` (the
     MoE router load-balance losses) into the objective — the opt-in

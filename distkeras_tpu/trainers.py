@@ -34,7 +34,7 @@ import numpy as np
 import optax
 
 from .data.dataset import Dataset
-from .models.layers import Activation, Dense, Sequential
+from .models.layers import Activation, Dense, Sequential, state_leaves
 from .models.model import Model
 from .obs import SpanTracer, get_logger
 from .obs import profile as obs_profile
@@ -380,6 +380,14 @@ class Trainer:
             registry.counter("moe.rows_run").inc(stats["rows_run"])
             registry.gauge("moe.expert_load_max_over_mean").set(
                 stats["load_max_over_mean"])
+        # and an exit gate's: the mean chance of answering at each pass
+        for shares in state_leaves(self.trained_variables["state"],
+                                   "exit_share"):
+            shares = np.asarray(shares)  # (steps,), or one row a worker
+            for t, share in enumerate(
+                    shares.reshape(-1, shares.shape[-1]).mean(axis=0)):
+                default_registry().gauge(f"loop.exit_share.{t}").set(
+                    float(share))
         return self.model
 
     def train(self, dataset: Dataset, shuffle: bool = False,

@@ -185,6 +185,21 @@ def test_children_of_equal_shape_are_estimated_once(built, monkeypatch):
     assert all(whole >= saved for saved, whole, _ in by)
     assert by[4][2] == 4 * sum(a.size for a in jax.tree_util.tree_leaves(
         variables["params"][4]))
+    # a child run several times is still one estimate: the blocks as the
+    # body of a loop of 3 passes are 3 x 6 + 3 applications more, and the
+    # final norm (now the loop's) is the only layer not traced before
+    del traced[:]
+    looped = zoo.decoder_lm(**SIZES, attention_impl="flash",
+                            total_ut_steps=3)
+    shapes = jax.eval_shape(looped.init)
+    plan = remat.Plan(budget=0)
+    jax.eval_shape(lambda p: looped.layer.apply(
+        p, shapes["state"], tokens(1), train=True, remat=plan)[0],
+        shapes["params"])
+    assert plan.children == 1 + 3 * 7 + 1
+    assert [type(layer).__name__ for layer in traced] == [
+        "Embedding", "Residual", "Residual", "Residual", "Residual",
+        "RMSNorm", "ExitHeads"]
 
 
 def test_a_trainer_counts_its_plan_and_judges_the_compiled_step():
