@@ -178,8 +178,10 @@ def switch_moe_sharded(mesh: Mesh, params: Tree, x, *, axis: str = "ep",
 
 class DispatchPlan(NamedTuple):
     """Where every (token, choice) assignment of one routed layer goes in
-    the experts' row buffer (``ops.pallas_moe``'s layout).  N tokens, k
-    choices a token, R buffer rows, ``held`` experts here."""
+    the experts' row layout (``ops.pallas_moe``'s).  N tokens, k choices
+    a token, ``held`` experts here, R rows: a row for every assignment
+    that COULD land here.  These integers are all that is R long: the
+    rows themselves exist a :class:`Round` at a time."""
     dest: Any         # (N, k) int32: the assignment's row (0 if not here)
     here: Any         # (N, k) bool: its expert is held here
     row_assign: Any   # (R,) int32: the row's assignment n * k + j (0 if none)
@@ -189,13 +191,39 @@ class DispatchPlan(NamedTuple):
     counts: Any       # (held,) int32: assignments of each expert here
 
 
+#: A round's room over the rows EXPECTED here (N·k·held / experts).  What
+#: arrives is the sum of the held experts' loads: a router kept in balance
+#: holds ONE expert within about twice its mean (the capacity factors of
+#: the layers that drop: 1.25 in Switch, 2 in GShard) and a sum over
+#: several experts spreads less, so at 2 a second round is a rare step,
+#: and a rare step only costs its rounds.
+ROUND_HEADROOM = 2.0
+
+
+def round_rows(n: int, k: int, experts_held: int, num_experts: int,
+               tile_rows: int) -> int:
+    """R_c, the rows one round of :func:`routed_experts` holds, from the
+    shapes alone: ``ROUND_HEADROOM`` times the rows expected here in
+    whole tiles, one tile more an expert held (its last tile's padding),
+    and never more than the layout's worst case, every assignment landing
+    here (N·k in whole tiles + a tile an expert), which is what a layer
+    holding every expert gets."""
+    worst = -(-n * k // tile_rows) + experts_held
+    expected = n * k * experts_held / num_experts
+    tiles = math.ceil(ROUND_HEADROOM * expected / tile_rows) + experts_held
+    return min(worst, tiles) * tile_rows
+
+
 def dispatch_plan(expert_idx, first_expert: int, experts_held: int,
-                  tile_rows: int) -> DispatchPlan:
+                  tile_rows: int, round_rows: Optional[int] = None
+                  ) -> DispatchPlan:
     """Sort the assignments whose expert is one of the ``experts_held``
     from ``first_expert`` by expert, token order kept inside an expert,
     each expert's stretch rounded up to whole tiles (one tile at least).
-    Nothing is dropped: the buffer has room for every assignment landing
-    here (R = N·k rounded up to tiles + one tile an expert)."""
+    Nothing is dropped: the layout has a row for every assignment that
+    could land here (R = N·k rounded up to tiles + one tile an expert,
+    then up to whole rounds of ``round_rows``).  Only these integers are
+    R long; the rows themselves are gathered a round at a time."""
     n, k = expert_idx.shape
     local = expert_idx.reshape(n * k).astype(jnp.int32) - first_expert
     here = (local >= 0) & (local < experts_held)
@@ -208,6 +236,8 @@ def dispatch_plan(expert_idx, first_expert: int, experts_held: int,
     ends = jnp.cumsum(tiles) * tile_rows
     starts = ends - tiles * tile_rows
     rows = -(-n * k // tile_rows) * tile_rows + experts_held * tile_rows
+    if round_rows is not None:
+        rows = -(-rows // round_rows) * round_rows
     dest = jnp.where(here, starts[jnp.minimum(group, experts_held - 1)]
                      + rank, rows)  # elsewhere: past the buffer, dropped
     row_assign = jnp.full((rows,), -1, jnp.int32).at[dest].set(
@@ -226,30 +256,194 @@ def _rows(src, index):
     return src.at[index].get(mode="promise_in_bounds")
 
 
-@jax.custom_vjp
-def _take_rows(src, index, mask, back_index, back_mask):
-    """``out[i] = src[index[i]]`` where ``mask[i]``, else 0, for an index
-    (in bounds everywhere) that reaches each row of ``src`` from at most
-    J places known beforehand: ``back_index`` (rows of src, J) names
-    them in the flattened output, so the backward is a gather too and no
-    scatter-add."""
-    return jnp.where(mask[..., None], _rows(src, index), 0)
+class Round(NamedTuple):
+    """Round r of a plan: the window of ``T_c`` tiles ``[r·T_c, (r+1)·T_c)``
+    of its layout, R_c = T_c · tile_rows rows.  What a grouped matmul
+    reads of a plan (``tile_expert``, ``num_tiles``) it reads of a round:
+    an expert's stretch may begin in the round before or end in the next,
+    and an expert may have no tile in it."""
+    assign: Any       # (R_c,) int32: the row's assignment n * k + j
+    token: Any        # (R_c,) int32: its token n (0 where the row is unused)
+    used: Any         # (R_c,) bool
+    tile_expert: Any  # (T_c,) int32
+    num_tiles: Any    # (1,) int32: the round's used tiles, T_c but in the last
 
 
-def _take_rows_fwd(src, index, mask, back_index, back_mask):
-    return _take_rows(src, index, mask, back_index, back_mask), \
-        (back_index, back_mask)
+def _round_of(plan: DispatchPlan, r, k: int, round_rows: int,
+              tile_rows: int) -> Round:
+    tiles = round_rows // tile_rows
+    assign = lax.dynamic_slice_in_dim(plan.row_assign, r * round_rows,
+                                      round_rows)
+    return Round(
+        assign=assign, token=assign // k,
+        used=lax.dynamic_slice_in_dim(plan.row_used, r * round_rows,
+                                      round_rows),
+        tile_expert=lax.dynamic_slice_in_dim(plan.tile_expert, r * tiles,
+                                             tiles),
+        num_tiles=jnp.minimum(plan.num_tiles - r * tiles, tiles))
 
 
-def _take_rows_bwd(res, g):
-    back_index, back_mask = res
-    flat = g.reshape(-1, g.shape[-1])
-    back = jnp.where(back_mask[..., None], _rows(flat, back_index), 0)
-    return (jnp.sum(back.astype(jnp.float32), axis=1).astype(g.dtype),
-            None, None, None, None)
+def _to_rows(src, rnd: Round):
+    """(N, D) -> (R_c, D): each used row its token's row of ``src``, the
+    others zero.  A gather of R_c rows."""
+    return jnp.where(rnd.used[:, None], _rows(src, rnd.token), 0)
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+def _to_tokens(rows, scale, rnd: Round, n: int):
+    """(R_c, D) -> (N, D) float32: each token the sum of ``scale[i] *
+    rows[i]`` over its rows in the round (``scale`` (R_c,) float32, 0
+    where a row is unused), products and sums in float32: the transpose
+    of :func:`_to_rows`.  ``ops.pallas_moe.rows_to_tokens`` adds the used
+    tiles' rows into a block of the sum's columns held in VMEM; where not
+    even a 128-column block of (N, D) fits there, XLA's scatter-add of
+    the R_c rows.  Neither builds the (N, k, D) gather of the layer that
+    had one buffer, which cost the same whether a choice was here or not
+    (``scripts/moe_token_side_bench.py`` ranks the candidates)."""
+    kernels = _pallas_moe()
+    block = kernels.rows_to_tokens_block(n, rows.shape[-1])
+    if block is None:
+        return _scatter_to_tokens(rows, scale, rnd, n)
+    return kernels.rows_to_tokens(
+        rows, scale, rnd.token, rnd.num_tiles, n=n, block=block,
+        tile_rows=rows.shape[0] // rnd.tile_expert.shape[0],
+        interpret=kernels._interpret())
+
+
+def _scatter_to_tokens(rows, scale, rnd: Round, n: int):
+    return jnp.zeros((n, rows.shape[-1]), jnp.float32).at[rnd.token].add(
+        scale[:, None] * rows.astype(jnp.float32),
+        mode="promise_in_bounds")
+
+
+def _row_weights(weights, rnd: Round, dtype):
+    """(R_c,) float32: each used row its assignment's routing weight as
+    the rows' dtype holds it (the combine multiplies in that dtype, as it
+    did), 0 where the row is unused."""
+    return jnp.where(rnd.used, _rows(weights.reshape(-1), rnd.assign),
+                     0).astype(dtype).astype(jnp.float32)
+
+
+def _rounds_walked(plan: DispatchPlan, round_rows: int, tile_rows: int):
+    """int32 scalar, >= 1: the rounds the plan's used tiles take."""
+    return -(-plan.num_tiles[0] // (round_rows // tile_rows))
+
+
+def _walk(plan: DispatchPlan, round_rows: int, tile_rows: int, one_round,
+          merge):
+    """``one_round(the plan's round r)`` for every round with a used tile
+    (at least one; how many is known on the device alone) folded by
+    ``merge(so far, round r's)``.  Round 0 runs outside the loop and is
+    the result as it stands where there is no other, so the step every
+    cell runs initialises no sum and converts nothing; the others run in
+    a ``lax.while_loop``, which a layout of one round does not build."""
+    k = plan.dest.shape[1]
+    total = one_round(_round_of(plan, 0, k, round_rows, tile_rows))
+    if plan.row_assign.shape[0] == round_rows:
+        return total
+
+    def another(carry):
+        r, total = carry
+        return r + 1, merge(total, one_round(
+            _round_of(plan, r, k, round_rows, tile_rows)))
+
+    rounds = _rounds_walked(plan, round_rows, tile_rows)
+    return lax.while_loop(lambda carry: carry[0] < rounds, another,
+                          (jnp.int32(1), total))[1]
+
+
+def _experts_present(rnd: Round, experts_held: int):
+    """(held,) bool: the expert has a used tile in the round."""
+    tiles = rnd.tile_expert.shape[0]
+    return jnp.any(
+        (rnd.tile_expert[None, :] == jnp.arange(experts_held)[:, None])
+        & (jnp.arange(tiles) < rnd.num_tiles[0])[None, :], axis=1)
+
+
+def _merge_by_expert(so_far, part, seen, present):
+    """The experts' parameter gradients after one more round.  A leaf is
+    (held, ...); a round's part of it is defined for the experts PRESENT
+    in the round alone (``grouped_matmul``'s backward leaves an absent
+    expert's block unwritten), so it is selected, never multiplied.  An
+    expert seen in an earlier round too (its stretch straddles the
+    boundary) gets the float32 sum of both parts."""
+    def merge(a, b):
+        shape = (-1,) + (1,) * (a.ndim - 1)
+        both = (a.astype(jnp.float32) + b.astype(jnp.float32)).astype(a.dtype)
+        return jnp.where((seen & present).reshape(shape), both,
+                         jnp.where(present.reshape(shape), b, a))
+    return jax.tree_util.tree_map(merge, so_far, part)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _rounds(expert_rows, round_rows: int, tile_rows: int, x, weights,
+            expert_params, plan: DispatchPlan):
+    """(N, D) float32: the held experts' part of the layer, a round of
+    ``round_rows`` rows at a time.  Reverse mode does not pass a
+    ``while_loop``: the backward walks the same rounds, rebuilding a
+    round's rows from ``x`` and the plan, so nothing a round long outlives
+    the forward (the residuals are the arguments)."""
+    n = x.shape[0]
+
+    def one_round(rnd: Round):
+        with jax.named_scope("dispatch"):
+            rows = _to_rows(x, rnd)
+        with jax.named_scope("experts"):
+            rows = expert_rows(expert_params, rows, rnd)
+        with jax.named_scope("combine"):
+            return _to_tokens(rows, _row_weights(weights, rnd, x.dtype),
+                              rnd, n)
+
+    return _walk(plan, round_rows, tile_rows, one_round, jnp.add)
+
+
+def _rounds_fwd(expert_rows, round_rows, tile_rows, x, weights,
+                expert_params, plan):
+    return _rounds(expert_rows, round_rows, tile_rows, x, weights,
+                   expert_params, plan), (x, weights, expert_params, plan)
+
+
+def _rounds_bwd(expert_rows, round_rows, tile_rows, res, g):
+    x, weights, expert_params, plan = res
+    n, k = plan.dest.shape
+    held = plan.counts.shape[0]
+
+    def one_round(rnd: Round):
+        """This round's part of (dx, the weights' gradient by assignment,
+        the experts' parameters' gradients), and the experts it held."""
+        with jax.named_scope("dispatch"):
+            rows = _to_rows(x, rnd)
+        with jax.named_scope("experts"):
+            y, back = jax.vjp(lambda p, rows: expert_rows(p, rows, rnd),
+                              expert_params, rows)
+        with jax.named_scope("combine"):
+            g_rows = _to_rows(g, rnd).astype(jnp.float32)
+            w = _row_weights(weights, rnd, x.dtype)
+            # an unused row's index is past the end, and dropped
+            d_weights = jnp.zeros((n * k,), jnp.float32).at[
+                jnp.where(rnd.used, rnd.assign,
+                          n * k + jnp.arange(round_rows))].set(
+                    jnp.sum(y.astype(jnp.float32) * g_rows, axis=-1),
+                    mode="drop", unique_indices=True)
+            dy = (w[:, None] * g_rows).astype(y.dtype)
+        with jax.named_scope("experts"):
+            d_params, d_rows = back(dy)
+        with jax.named_scope("dispatch"):
+            dx = _to_tokens(d_rows, rnd.used.astype(jnp.float32), rnd, n)
+        return dx, d_weights, d_params, _experts_present(rnd, held)
+
+    def merge(so_far, part):
+        dx, d_weights, d_params, seen = so_far
+        return (dx + part[0], d_weights + part[1],
+                _merge_by_expert(d_params, part[2], seen, part[3]),
+                seen | part[3])
+
+    dx, d_weights, d_params, _ = _walk(plan, round_rows, tile_rows,
+                                       one_round, merge)
+    return (dx.astype(x.dtype), d_weights.reshape(n, k).astype(weights.dtype),
+            d_params, None)
+
+
+_rounds.defvjp(_rounds_fwd, _rounds_bwd)
 
 
 def route_top_k(x, kernel, k: int, *, normalise: bool, scale: float,
@@ -285,28 +479,30 @@ def route_top_k(x, kernel, k: int, *, normalise: bool, scale: float,
         scores / jnp.sum(scores, axis=-1, keepdims=True)
 
 
-def routed_experts(x, idx, weights, expert_rows, *, first_expert: int,
-                   experts_held: int, tile_rows: int):
+def routed_experts(x, idx, weights, expert_rows, expert_params, *,
+                   first_expert: int, experts_held: int, num_experts: int,
+                   tile_rows: int):
     """``sum_j weights[n, j] * E_{idx[n, j]}(x[n])`` over the choices whose
     expert is held here; the others add nothing (another chip's part).
 
-    ``expert_rows(rows (R, D), plan) -> (R, D)`` applies each expert to
-    its own stretch of the row buffer (grouped matmuls).  Returns the
-    (N, D) result and the plan."""
-    plan = dispatch_plan(idx, first_expert, experts_held, tile_rows)
-    k = idx.shape[1]
-    with jax.named_scope("dispatch"):
-        rows = _take_rows(x, plan.row_assign // k, plan.row_used,
-                          plan.dest, plan.here)
-    with jax.named_scope("experts"):
-        rows = expert_rows(rows, plan)
-    with jax.named_scope("combine"):
-        picked = _take_rows(rows, plan.dest, plan.here,
-                            plan.row_assign[:, None],
-                            plan.row_used[:, None])           # (N, k, D)
-        out = jnp.einsum("nk,nkd->nd", weights.astype(x.dtype), picked,
-                         preferred_element_type=jnp.float32)
-    return out.astype(x.dtype), plan
+    ``expert_rows(expert_params, rows (R_c, D), round) -> (R_c, D)``
+    applies each expert to its own tiles of a round's rows (grouped
+    matmuls over ``round.tile_expert`` / ``round.num_tiles``); it is a
+    function of its arguments alone (the backward calls it again).  The
+    assignments that arrived are walked in rounds of R_c rows
+    (:func:`round_rows`), as many as their tiles take, counted on the
+    device: nothing is dropped at any load, and a step in which about the
+    expected number arrives, or a layer that holds every expert, is one
+    round.  Returns the (N, D) result, the plan, and what the walk leaves
+    in a layer's state: ``rounds`` and ``round_rows`` (R_c), float32."""
+    n, k = idx.shape
+    rows = round_rows(n, k, experts_held, num_experts, tile_rows)
+    plan = dispatch_plan(idx, first_expert, experts_held, tile_rows, rows)
+    out = _rounds(expert_rows, rows, tile_rows, x, weights, expert_params,
+                  plan)
+    return out.astype(x.dtype), plan, {
+        "rounds": _rounds_walked(plan, rows, tile_rows).astype(jnp.float32),
+        "round_rows": jnp.full((), rows, jnp.float32)}
 
 
 def routing_state(idx, probs, plan, tile_rows: int) -> dict:
@@ -327,9 +523,10 @@ def routing_state(idx, probs, plan, tile_rows: int) -> dict:
 
 
 def routing_stats(state: Tree):
-    """Sum of ``rows_needed`` / ``rows_run`` and the largest
-    ``load_max_over_mean`` over every routed layer's state in a
-    variables-state tree (host values), or None where there is none."""
+    """Sum of ``rows_needed`` / ``rows_run`` / ``rounds`` and the largest
+    ``round_rows`` and ``load_max_over_mean`` over every routed layer's
+    state in a variables-state tree (host values), or None where there is
+    none."""
     found = []
 
     def visit(node):
@@ -348,6 +545,9 @@ def routing_stats(state: Tree):
     return {"rows_needed": float(sum(np.sum(s["rows_needed"])
                                      for s in found)),
             "rows_run": float(sum(np.sum(s["rows_run"]) for s in found)),
+            "rounds": float(sum(np.sum(s["rounds"]) for s in found)),
+            "round_rows": float(max(np.max(s["round_rows"])
+                                    for s in found)),
             "load_max_over_mean": float(max(np.max(s["load_max_over_mean"])
                                             for s in found))}
 
@@ -366,6 +566,35 @@ def _pallas_moe():
     every ``import distkeras_tpu`` would pay (``setup_s``)."""
     from . import pallas_moe
     return pallas_moe
+
+
+def _relu_bias_rows(ex, rows, rnd):
+    """``MoEDense``'s experts over a round's rows: relu MLPs with biases."""
+    grouped_matmul = _pallas_moe().grouped_matmul
+    row_expert = jnp.repeat(rnd.tile_expert,
+                            rows.shape[0] // rnd.tile_expert.shape[0])
+    h = grouped_matmul(rows, ex["w1"].astype(rows.dtype), rnd.tile_expert,
+                       rnd.num_tiles)
+    h = jax.nn.relu(h + ex["b1"].astype(rows.dtype)[row_expert])
+    y = grouped_matmul(h, ex["w2"].astype(rows.dtype), rnd.tile_expert,
+                       rnd.num_tiles)
+    return y + ex["b2"].astype(rows.dtype)[row_expert]
+
+
+def _gated_rows(ex, rows, rnd):
+    """``SparseMoE``'s experts over a round's rows: SwiGLU where they hold
+    ``gate_up`` (gate and up side by side), else relu² over ``up``."""
+    grouped_matmul = _pallas_moe().grouped_matmul
+    if "gate_up" in ex:
+        h = grouped_matmul(rows, ex["gate_up"].astype(rows.dtype),
+                           rnd.tile_expert, rnd.num_tiles)
+        f = h.shape[-1] // 2
+        h = jax.nn.silu(h[:, :f]) * h[:, f:]
+    else:
+        h = relu2(grouped_matmul(rows, ex["up"].astype(rows.dtype),
+                                 rnd.tile_expert, rnd.num_tiles))
+    return grouped_matmul(h, ex["down"].astype(rows.dtype), rnd.tile_expert,
+                          rnd.num_tiles)
 
 
 @register
@@ -423,26 +652,15 @@ class MoEDense(Layer):
                 capacity_factor=self.capacity_factor)
             return out.reshape(x.shape), \
                 {"aux_loss": aux.astype(jnp.float32)}
-        ex = params["experts"]
-        grouped_matmul = _pallas_moe().grouped_matmul
         tile_rows = _pallas_moe().TILE_ROWS
-
-        def relu_experts(rows, plan):
-            row_expert = jnp.repeat(plan.tile_expert, tile_rows)
-            h = grouped_matmul(rows, ex["w1"].astype(rows.dtype),
-                               plan.tile_expert, plan.num_tiles)
-            h = jax.nn.relu(h + ex["b1"].astype(rows.dtype)[row_expert])
-            y = grouped_matmul(h, ex["w2"].astype(rows.dtype),
-                               plan.tile_expert, plan.num_tiles)
-            return y + ex["b2"].astype(rows.dtype)[row_expert]
-
         with jax.named_scope("router"):
             idx, weights, probs = route_top_k(
                 tokens, params["router"]["wg"], 1, normalise=False,
                 scale=1.0)
-        out, plan = routed_experts(
-            tokens, idx, weights, relu_experts, first_expert=0,
-            experts_held=self.num_experts, tile_rows=tile_rows)
+        out, plan, _ = routed_experts(
+            tokens, idx, weights, _relu_bias_rows, params["experts"],
+            first_expert=0, experts_held=self.num_experts,
+            num_experts=self.num_experts, tile_rows=tile_rows)
         stats = routing_state(idx, probs, plan, tile_rows)
         return out.reshape(x.shape), {"aux_loss": stats["aux_loss"]}
 
@@ -463,7 +681,10 @@ class SparseMoE(Layer):
     computes.  No capacity, no dropped token, and nothing stands in for
     the experts held elsewhere: with ``experts_held = num_experts`` (the
     default) it is the whole layer, and the parts of all shares add up to
-    it (shared expert counted once).
+    it (shared expert counted once).  A share works on the rows that
+    arrive, in rounds of a size read from the shapes
+    (:func:`routed_experts`, :func:`round_rows`): one round in a step
+    that brings about its expected load, more where more arrives.
 
     ``expert_activation``: ``"swiglu"`` (``(silu(x W_gate) * x W_up)
     W_down``, gate and up side by side) or ``"relu2"`` (``relu(x W_up)^2
@@ -479,7 +700,8 @@ class SparseMoE(Layer):
     ``shared.gate_up`` or ``shared.up``, and ``shared.down``, where
     ``shared_hidden > 0``.  State each step: ``aux_loss`` (the switch
     load-balance loss), ``rows_needed`` / ``rows_run`` (rows the grouped
-    matmuls needed and ran) and ``load_max_over_mean``."""
+    matmuls needed and ran), ``rounds`` / ``round_rows`` (the rounds they
+    ran in, and a round's rows) and ``load_max_over_mean``."""
 
     def __init__(self, num_experts: int, experts_per_token: int,
                  d_hidden: int, shared_hidden: int = 0,
@@ -539,43 +761,30 @@ class SparseMoE(Layer):
                 "down": glorot_uniform(ksd, (fs, d))}
         # one buffer a leaf: the trainers donate the state
         state = {name: jnp.zeros((), jnp.float32) for name in (
-            "aux_loss", "rows_needed", "rows_run", "load_max_over_mean")}
+            "aux_loss", "rows_needed", "rows_run", "rounds", "round_rows",
+            "load_max_over_mean")}
         return params, state, in_shape
 
     def apply(self, params, state, x, *, train=False, rng=None):
         tokens = x.reshape(-1, x.shape[-1])
-        ex = params["experts"]
-        grouped_matmul = _pallas_moe().grouped_matmul
         tile_rows = _pallas_moe().TILE_ROWS
         up, _ = self._up
-
-        def experts(rows, plan):
-            h = grouped_matmul(rows, ex[up].astype(rows.dtype),
-                               plan.tile_expert, plan.num_tiles)
-            if up == "gate_up":
-                f = h.shape[-1] // 2
-                h = jax.nn.silu(h[:, :f]) * h[:, f:]
-            else:
-                h = relu2(h)
-            return grouped_matmul(h, ex["down"].astype(rows.dtype),
-                                  plan.tile_expert, plan.num_tiles)
-
         with jax.named_scope("router"):
             idx, weights, probs = route_top_k(
                 tokens, params["router"]["kernel"], self.experts_per_token,
                 normalise=self.normalise, scale=self.routed_scale,
                 bias=params["router"].get("bias"))
-        out, plan = routed_experts(
-            tokens, idx, weights, experts,
+        out, plan, walked = routed_experts(
+            tokens, idx, weights, _gated_rows, params["experts"],
             first_expert=self.first_expert, experts_held=self.experts_held,
-            tile_rows=tile_rows)
+            num_experts=self.num_experts, tile_rows=tile_rows)
         if self.shared_hidden:
             with jax.named_scope("shared_expert"):
                 shared = swiglu if up == "gate_up" else relu2_mlp
                 out = out + shared(tokens, params["shared"][up],
                                    params["shared"]["down"])
-        return out.reshape(x.shape), routing_state(idx, probs, plan,
-                                                   tile_rows)
+        return out.reshape(x.shape), dict(
+            routing_state(idx, probs, plan, tile_rows), **walked)
 
     def get_config(self):
         return {"num_experts": self.num_experts,

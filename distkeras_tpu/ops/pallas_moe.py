@@ -2,14 +2,18 @@
 
 A routed layer sorts its (token, expert) assignments by expert and then
 multiplies each expert's rows by that expert's matrix.  How many rows an
-expert gets is only known on the device, so the rows live in a buffer of
-static size, laid out by ``ops.moe.dispatch_plan``: every expert held
-here owns a stretch of whole ``tile_rows``-row tiles (its last tile
-padded with zero rows, at least one tile even with no row), the
-stretches follow each other, and the tiles past the last stretch are
-unused.  A tile therefore belongs to ONE expert, named by the
-scalar-prefetched ``tile_expert``; ``num_tiles`` says where the used
-tiles end.
+expert gets is only known on the device, so ``ops.moe.dispatch_plan`` lays
+them out in whole ``tile_rows``-row tiles: every expert held here owns a
+stretch of tiles (its last tile padded with zero rows, at least one tile
+even with no row) and the stretches follow each other.  A tile therefore
+belongs to ONE expert.  The kernels never see the whole layout: the layer
+walks it in ROUNDS of ``T_c`` tiles (``ops.moe.routed_experts``), a size
+read from the shapes that holds a step's expected rows twice over, and
+hands a kernel one round's rows with its ``tile_expert`` (scalar-
+prefetched) and ``num_tiles``, the round's used tiles: all ``T_c`` but in
+the last round, where the tiles after them are unused.  An expert's
+stretch may begin in the round before or end in the next, and an expert
+may have no tile in a round.
 
 * ``grouped_matmul(lhs (R, C), rhs (E, C, N)) -> (R, N)``: tile i times
   ``rhs[tile_expert[i]]``.  Grid (N tiles, row tiles), rows innermost:
@@ -24,7 +28,12 @@ tiles end.
 * its backward: the same kernel with ``rhs`` transposed for the rows'
   gradient, and ``_tgmm`` (``lhs^T @ dy`` summed over each expert's tiles
   into a float32 accumulator, a block of ``lhs``'s columns at a time
-  where a (C, N tile) accumulator does not fit) for the matrices'.
+  where a (C, N tile) accumulator does not fit) for the matrices': this
+  round's part of them, undefined for an expert with no tile in it (the
+  layer merges the rounds' parts by the experts present in each).
+* ``rows_to_tokens(rows (R, D), scale, token) -> (N, D)`` float32, kernel
+  ``moe_rows_to_tokens``: every token the sum of its weighted rows in the
+  round.  The layer's combine and, in the backward, the tokens' gradient.
 
 Precision follows ``pallas_attention._dot``: float32 operands multiply at
 HIGHEST, bf16 at the MXU's rate into float32.  Off the TPU the kernels
@@ -202,8 +211,10 @@ def _gmm(lhs, rhs, tile_expert, num_tiles, *, transpose_rhs, tile_rows,
                                              "interpret"))
 def _tgmm(lhs, dy, tile_expert, num_tiles, *, num_experts, tile_rows,
           interpret):
-    """(E, C, N): for every expert ``lhs_e^T @ dy_e`` over its tiles.
-    Every expert owns at least one tile, so every block is written."""
+    """(E, C, N): for every expert with a used tile ``lhs_e^T @ dy_e`` over
+    its used tiles.  An expert with none (a round may hold no tile of
+    it) has its block never written: ``ops.moe._merge_by_expert`` takes a
+    round's blocks for the experts present in it alone."""
     rows, c = lhs.shape
     n = dy.shape[1]
     tn = _col_tile(n, c, 4)  # the accumulator is float32
@@ -242,8 +253,12 @@ def grouped_matmul(lhs, rhs, tile_expert, num_tiles,
     ``num_tiles[0]`` tiles of ``tile_rows`` rows, zeros after them.
 
     ``lhs`` (R, C); ``rhs`` (E, C, N); ``tile_expert`` (R / tile_rows,)
-    int32, non-decreasing over the used tiles, every expert present, and
-    past them equal to its last used entry; ``num_tiles`` (1,) int32."""
+    int32, non-decreasing over the used tiles and past them equal to its
+    last used entry; ``num_tiles`` (1,) int32, at least 1.  The tiles may
+    be a round of a longer layout (``ops.moe.Round``): an expert's tiles
+    may be few of its stretch, or none, and its matrix's gradient is then
+    its part from these tiles, or UNDEFINED (an expert with no used tile:
+    the block is never written; select it away, do not multiply)."""
     return _gmm(lhs, rhs, tile_expert, num_tiles, transpose_rhs=False,
                 tile_rows=tile_rows, interpret=_interpret())
 
@@ -265,3 +280,80 @@ def _grouped_bwd(tile_rows, res, g):
 
 
 grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+# ---------------------------------------------------------------------------
+# rows -> tokens: each token the weighted sum of its rows
+# ---------------------------------------------------------------------------
+
+#: what the (N, column block) float32 sum, twice buffered, may take of
+#: VMEM; v5e has 128 MiB, and the call raises its scoped limit to fit
+_SUM_BUDGET = 48 * 2**20
+
+
+def rows_to_tokens_block(n: int, d: int):
+    """Columns of :func:`rows_to_tokens`' resident sum over ``n`` tokens:
+    ``_tile_of``'s widest block of ``d`` whose two float32 buffers fit
+    ``_SUM_BUDGET``, or None where none does (the caller keeps XLA's
+    scatter-add)."""
+    fits = lambda cb: 2 * n * cb * 4 <= _SUM_BUDGET  # noqa: E731
+    block = _tile_of(d, fits)
+    return block if fits(block) else None
+
+
+def _to_tokens_kernel(token, num_tiles, rows_ref, scale_ref, out_ref,
+                      scaled_ref, *, tile_rows: int):
+    i = pl.program_id(1)
+
+    @pl.when(i == 0)
+    def _first_tile():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(i < num_tiles[0])
+    def _run():
+        scaled_ref[...] = rows_ref[...].astype(jnp.float32) * scale_ref[...]
+
+        def add_rows(group, carry):  # 8 rows a step, unrolled by hand
+            for r in range(8):
+                r = group * 8 + r
+                t = token[i * tile_rows + r]
+                out_ref[pl.ds(t, 1), :] += scaled_ref[pl.ds(r, 1), :]
+            return carry
+
+        lax.fori_loop(0, tile_rows // 8, add_rows, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "block", "tile_rows",
+                                             "interpret"))
+def rows_to_tokens(rows, scale, token, num_tiles, *, n: int, block: int,
+                   tile_rows: int = TILE_ROWS, interpret: bool = False):
+    """(N, D) float32: ``out[token[i]] += scale[i] * rows[i]`` over the
+    rows of the first ``num_tiles[0]`` tiles, in row order, float32
+    products and sums.
+
+    ``rows`` (R, D); ``scale`` (R,) float32, 0 where a row holds nothing;
+    ``token`` (R,) int32 in [0, N), scalar-prefetched.  Grid (column
+    blocks, row tiles), rows innermost: a block of ``block`` columns of
+    the WHOLE sum stays in VMEM while the tiles' rows are added to it one
+    by one (a row's token is only known on the device, so its place is a
+    dynamic sublane), and is written once.  A tile past ``num_tiles``
+    moves nothing."""
+    r, d = rows.shape
+    return pl.pallas_call(
+        functools.partial(_to_tokens_kernel, tile_rows=tile_rows),
+        out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(d // block, r // tile_rows),
+            in_specs=[pl.BlockSpec((tile_rows, block),
+                                   lambda j, i, tok, nt: (_used(i, nt), j)),
+                      pl.BlockSpec((tile_rows, 1),
+                                   lambda j, i, tok, nt: (_used(i, nt), 0))],
+            out_specs=pl.BlockSpec((n, block), lambda j, i, tok, nt: (0, j)),
+            scratch_shapes=[pltpu.VMEM((tile_rows, block), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_SUM_BUDGET + 16 * 2**20),
+        interpret=interpret,
+        name="moe_rows_to_tokens",
+    )(token, num_tiles, rows, scale.reshape(r, 1))

@@ -378,6 +378,9 @@ class Trainer:
             registry = default_registry()
             registry.counter("moe.rows_needed").inc(stats["rows_needed"])
             registry.counter("moe.rows_run").inc(stats["rows_run"])
+            # rounds ÷ routed layers = 1.0: every layer ran one round
+            registry.counter("moe.rounds").inc(stats["rounds"])
+            registry.gauge("moe.round_rows").set(stats["round_rows"])
             registry.gauge("moe.expert_load_max_over_mean").set(
                 stats["load_max_over_mean"])
         # and an exit gate's: the mean chance of answering at each pass

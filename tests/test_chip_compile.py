@@ -197,6 +197,32 @@ def test_grouped_matmuls_compile_at_widths_128_does_not_divide(
     assert "moe_gmm" in text and ("moe_tgmm" in text) == (mode != "fwd")
 
 
+@pytest.mark.parametrize("tokens,width,rows,dtype", [
+    (8192, 2688, 56 * 128, "bfloat16"), (8192, 2048, 160 * 128, "bfloat16"),
+    (16384, 2688, 104 * 128, "float32")],
+    ids=["nemotron-train", "laguna-train", "nemotron-check-f32"])
+def test_rows_to_tokens_compiles(one_chip, mosaic, tokens, width, rows,
+                                 dtype):
+    """A round's rows added into the tokens' float32 sum, a column block
+    of the WHOLE sum resident in VMEM (384 or 512 columns of 8,192 or
+    16,384 tokens: 12.6 to 25 MB, twice buffered, over the scoped default
+    that the call raises) and a dynamic sublane a row."""
+    block = pallas_moe.rows_to_tokens_block(tokens, width)
+    assert block == {2688: 384, 2048: 512}[width]
+
+    def shape(*dims, dt=dtype):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dt), sharding=one_chip)
+
+    text = _compile(
+        lambda r, s, t, n: pallas_moe.rows_to_tokens(
+            r, s, t, n, n=tokens, block=block),
+        shape(rows, width), shape(rows, dt="float32"),
+        shape(rows, dt="int32"), shape(1, dt="int32"))
+    assert "moe_rows_to_tokens" in text
+    # a sum that no 128-column block of fits: the caller's scatter-add
+    assert pallas_moe.rows_to_tokens_block(65536, 2048) is None
+
+
 @pytest.mark.parametrize("dtype,batch,mode", [
     ("bfloat16", 1, "fwd_bwd"), ("float32", 2, "fwd")],
     ids=["train-bf16", "check-f32"])

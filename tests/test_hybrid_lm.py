@@ -177,6 +177,8 @@ def test_a_bf16_trainer_keeps_the_scan_kernels_outputs_and_learns():
     registry = default_registry()
     chunks = registry.counter("ssm.chunks")
     before = chunks.value
+    rounds = registry.counter("moe.rounds")
+    rounds_before = rounds.value
     train = load_lm_corpus(n_train=8, seq_len=64, vocab_size=64, seed=2)[0]
     trainer = dk.SingleTrainer(
         zoo.hybrid_lm(**SIZES, attention_impl="flash", ssm_impl="pallas"),
@@ -191,7 +193,14 @@ def test_a_bf16_trainer_keeps_the_scan_kernels_outputs_and_learns():
     assert (chunks.value - before) % 8 == 0 and chunks.value > before
     mixer = model.variables["params"][1]["inner"][1]
     assert all(mixer[k].dtype == jnp.float32 for k in ("A_log", "D"))
-    assert model.variables["state"][2]["inner"][1]["rows_needed"] > 0
+    routed = model.variables["state"][2]["inner"][1]
+    assert routed["rows_needed"] > 0
+    # the two routed layers' last step: a round each, of R_c rows
+    from distkeras_tpu.ops.moe import round_rows
+    assert routed["rounds"] == 1
+    assert rounds.value - rounds_before == 2
+    assert registry.gauge("moe.round_rows").value == routed["round_rows"] \
+        == round_rows(2 * 64, 3, 4, 8, 128) == 7 * 128
 
 
 @pytest.mark.parametrize("layer", [

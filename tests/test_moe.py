@@ -269,15 +269,19 @@ def test_trainer_aux_weight_folds_balance_loss():
 # the plain reference ``benchmark/reference/nemotron_h.py``
 # ---------------------------------------------------------------------------
 
-def _nemotron_reference():
+def _reference(name):
+    import importlib
     import os
     import sys
     bench = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark")
     if bench not in sys.path:
         sys.path.insert(0, bench)
-    from reference import nemotron_h
-    return nemotron_h
+    return importlib.import_module(f"reference.{name}")
+
+
+def _nemotron_reference():
+    return _reference("nemotron_h")
 
 
 ROUTED = dict(n_routed_experts=16, num_experts_per_tok=3,
@@ -374,6 +378,188 @@ def test_sparse_moe_refuses_unknown_options():
         SparseMoE(4, 2, 8, expert_activation="gelu")
     with pytest.raises(ValueError, match="scoring"):
         SparseMoE(4, 2, 8, scoring="tanh")
+
+
+# ---------------------------------------------------------------------------
+# rounds: a share of the experts walks the rows that arrive, R_c at a time
+# ---------------------------------------------------------------------------
+
+#: 1,024 tokens take 3 of 64 experts, 4 held from expert 8: 192 rows are
+#: expected here, so a round is 2 x 192 in tiles + a tile an expert = 7
+#: tiles, and the layout's 24 + 4 tiles are 4 rounds
+WALK = dict(tokens=1024, width=32, experts=64, k=3, held=4, first=8,
+            hidden=24, shared=40, round_tiles=7)
+
+
+def walk_layer(activation, **kw):
+    from distkeras_tpu.ops.moe import SparseMoE
+    kw = dict(dict(experts_held=WALK["held"], first_expert=WALK["first"]),
+              **kw)
+    return SparseMoE(
+        WALK["experts"], WALK["k"], WALK["hidden"],
+        shared_hidden=WALK["shared"], routed_scale=2.5,
+        expert_activation=activation,
+        scoring="sigmoid" if activation == "relu2" else "softmax", **kw)
+
+
+def walk_setup(activation, forced, dtype, **kw):
+    """The layer, its parameters and tokens of which the share ``forced``
+    send all their choices to experts 8, 9, 10 (their first feature is 1
+    and the router's first row favours the three; the others' is 0)."""
+    layer = walk_layer(activation, **kw)
+    n, d = WALK["tokens"], WALK["width"]
+    params, state, _ = layer.init(jax.random.PRNGKey(11), (n, d))
+    kernel = 0.05 * jax.random.normal(jax.random.PRNGKey(12),
+                                      (d, WALK["experts"]))
+    params["router"]["kernel"] = kernel.at[0].set(0.0).at[0, 8:11].set(
+        jnp.asarray([6.0, 5.0, 4.0]))
+    u = np.random.default_rng(13).normal(size=(1, n, d)).astype(np.float32)
+    u[0, :, 0] = np.arange(n) % 10 < round(10 * forced)
+    cast = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: a.astype(dtype), t)
+    # a bf16 step leaves the router its float32 master weights
+    return layer, dict(cast(params), router=params["router"]), state, \
+        cast(jnp.asarray(u))
+
+
+def walk_reference(activation, params, u):
+    first = {"first_expert": WALK["first"]} \
+        if params["experts"]["down"].shape[0] < WALK["experts"] else {}
+    if activation == "relu2":
+        return _reference("nemotron_h").sparse_ff(params, u, dict(
+            n_routed_experts=WALK["experts"], num_experts_per_tok=WALK["k"],
+            routed_scaling_factor=2.5, norm_topk_prob=True, **first))[0]
+    return _reference("laguna").sparse_ff(params, u, dict(
+        num_experts_per_tok=WALK["k"], moe_routed_scaling_factor=2.5,
+        norm_topk_prob=True, **first))[0]
+
+
+def walk_compare(activation, layer, params, state, u, dtype):
+    """Values and gradients (tokens, router, expert and shared matrices)
+    against the plain reference on the same numbers in float32; the
+    layer's state."""
+    w = jax.random.normal(jax.random.PRNGKey(14), u.shape)
+
+    def mine(p, u):
+        out, st = layer.apply(p, state, u)
+        return jnp.sum(w * out.astype(jnp.float32)), (out, st)
+
+    (_, (out, st)), got = jax.jit(jax.value_and_grad(
+        mine, argnums=(0, 1), has_aux=True))(params, u)
+    wide = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
+                                  (params, u))
+    want_out, want = jax.jit(lambda p, u: (
+        walk_reference(activation, p, u),
+        jax.grad(lambda p, u: jnp.sum(w * walk_reference(activation, p, u)),
+                 argnums=(0, 1))(p, u)))(*wide)
+    exact = dtype == jnp.float32
+    flat, _ = jax.tree_util.tree_flatten_with_path(((out,) + got))
+    for (path, a), b in zip(flat, jax.tree_util.tree_leaves(
+            (want_out,) + want), strict=True):
+        assert a.dtype == (jnp.float32 if "router" in jax.tree_util.keystr(
+            path) else dtype)
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), b, rtol=1e-4 if exact else 0.05,
+            atol=(1e-5 if exact else 0.02) * scale + 1e-7,
+            err_msg=jax.tree_util.keystr(path))
+    return {name: float(v) for name, v in st.items()}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("activation", ["swiglu", "relu2"])
+@pytest.mark.parametrize("forced,rounds", [(0.0, 1), (0.5, 3), (1.0, 4)],
+                         ids=["expected", "triple", "worst"])
+def test_a_share_walks_what_arrives_in_rounds(forced, rounds, activation,
+                                              dtype):
+    """As many rows as expected: one round.  Every other token forced
+    here: three rounds, experts 9 and 10 straddle their boundaries (tiles
+    5-9 and 10-14 over rounds of 7), expert 8 has no tile in the second
+    and third and expert 11 none in the first two.  Every choice of every
+    token here, the layout's worst case: all four.  Nothing is dropped at
+    any load."""
+    from distkeras_tpu.ops.moe import route_top_k
+    from distkeras_tpu.ops.pallas_moe import TILE_ROWS
+    layer, params, state, u = walk_setup(activation, forced, dtype)
+    st = walk_compare(activation, layer, params, state, u, dtype)
+    idx = np.asarray(route_top_k(
+        u[0], params["router"]["kernel"], WALK["k"], normalise=True,
+        scale=2.5, bias=params["router"].get("bias"))[0])
+    here = (idx >= WALK["first"]) & (idx < WALK["first"] + WALK["held"])
+    assert st["rows_needed"] == here.sum()   # counted on the host
+    assert st["round_rows"] == WALK["round_tiles"] * TILE_ROWS
+    assert st["rounds"] == rounds
+    counts = [(idx == WALK["first"] + e).sum() for e in range(WALK["held"])]
+    tiles = sum(max(-(-c // TILE_ROWS), 1) for c in counts)
+    assert st["rows_run"] == tiles * TILE_ROWS
+    assert rounds == -(-tiles // WALK["round_tiles"])
+    if forced == 0.5:
+        assert [max(-(-c // TILE_ROWS), 1) for c in counts] == [5, 5, 5, 1]
+    if forced == 1.0:  # and every one of them landed here
+        assert here.all() and st["rows_needed"] == 3 * WALK["tokens"]
+
+
+def test_a_layer_holding_every_expert_is_one_round_of_all_its_rows():
+    """``experts_held == num_experts``: R_c is the whole layout (N·k in
+    tiles + a tile an expert), whatever arrives is one round, and no loop
+    is built."""
+    from distkeras_tpu.ops.moe import round_rows
+    from distkeras_tpu.ops.pallas_moe import TILE_ROWS
+    layer, params, state, u = walk_setup(
+        "swiglu", 0.5, jnp.float32, experts_held=None, first_expert=0)
+    st = walk_compare("swiglu", layer, params, state, u, jnp.float32)
+    whole = (WALK["tokens"] * WALK["k"] // TILE_ROWS + WALK["experts"]) \
+        * TILE_ROWS
+    assert st["round_rows"] == whole == round_rows(
+        WALK["tokens"], WALK["k"], WALK["experts"], WALK["experts"],
+        TILE_ROWS)
+    assert st["rounds"] == 1 and st["rows_needed"] == 3 * WALK["tokens"]
+    text = str(jax.make_jaxpr(lambda p, u: jax.grad(lambda p: jnp.sum(
+        layer.apply(p, state, u)[0]))(p))(params, u))
+    assert "while" not in text and "moe_tgmm" in text
+    # Nemotron's and Laguna's shares: 56 tiles of 392, 160 of 544
+    assert round_rows(8192, 6, 8, 128, 128) == 56 * 128
+    assert round_rows(8192, 8, 32, 256, 128) == 160 * 128
+
+
+def test_a_sum_too_long_for_vmem_is_scattered_to_the_same_numbers(
+        monkeypatch):
+    """Where no column block of the (N, D) float32 sum fits the kernel's
+    VMEM budget the round's rows are scatter-added by XLA: the same
+    tokens' sums, unused rows adding nothing."""
+    from distkeras_tpu.ops import moe, pallas_moe
+    n, k, tile = 300, 2, pallas_moe.TILE_ROWS
+    idx = jax.random.randint(jax.random.PRNGKey(1), (n, k), 0, 6)
+    plan = moe.dispatch_plan(idx, 1, 3, tile)
+    rnd = moe._round_of(plan, 0, k, plan.row_assign.shape[0], tile)
+    rows = jax.random.normal(jax.random.PRNGKey(2), (rnd.used.shape[0], 32))
+    scale = jnp.where(rnd.used, 0.5, 0.0)
+    kernel = moe._to_tokens(rows, scale, rnd, n)
+    monkeypatch.setattr(pallas_moe, "_SUM_BUDGET", 1024)
+    assert pallas_moe.rows_to_tokens_block(n, 32) is None
+    np.testing.assert_allclose(moe._to_tokens(rows, scale, rnd, n), kernel,
+                               rtol=1e-6, atol=1e-6)
+    assert float(jnp.abs(kernel).sum()) > 0
+
+
+def test_a_rounds_gradients_are_taken_for_its_present_experts_alone():
+    """``moe_tgmm`` never writes the block of an expert with no tile in
+    the round: whatever is there (NaN here) is selected away.  An expert
+    seen before and present again straddles the boundary: the float32 sum
+    of both parts, rounded once."""
+    from distkeras_tpu.ops.moe import _merge_by_expert
+    nan = float("nan")
+    so_far = {"w": jnp.asarray([[1.0, 1.0], [2.0, 258.0], [nan, nan]],
+                               jnp.bfloat16)}
+    part = {"w": jnp.asarray([[nan, nan], [10.0, 1.0], [5.0, 5.0]],
+                             jnp.bfloat16)}
+    got = _merge_by_expert(so_far, part, jnp.asarray([True, True, False]),
+                           jnp.asarray([False, True, True]))["w"]
+    assert got.dtype == jnp.bfloat16
+    # 258 + 1 = 259 in float32, which bfloat16 rounds to 260
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32), [[1.0, 1.0], [12.0, 260.0], [5.0, 5.0]])
 
 
 # ---------------------------------------------------------------------------

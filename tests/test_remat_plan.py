@@ -65,7 +65,7 @@ def loss_of(built, plan):
 
 PLANS = {"every_child_recomputed": lambda: "bare",
          "frugal": lambda: True,
-         "mixed": lambda: remat.Plan(budget=4e6),
+         "mixed": lambda: remat.Plan(budget=2e6),
          "every_child_kept": lambda: remat.Plan(budget=1e12)}
 
 
@@ -87,7 +87,7 @@ def test_loss_and_gradients_equal_the_plain_steps_bitwise(built, plain, name):
         np.testing.assert_array_equal(
             np.asarray(a), np.asarray(b), err_msg=jax.tree_util.keystr(path))
     if isinstance(plan, remat.Plan):
-        first = {"mixed": 5, "every_child_kept": 0}[name]
+        first = {"mixed": 4, "every_child_kept": 0}[name]
         assert (plan.first_kept, plan.children) == (first, 9)
         assert plan.bytes_estimated > 0
 
@@ -119,8 +119,11 @@ def test_the_forward_kernels_run_once_under_the_frugal_plan(built):
     for name, n in (("flash_fwd", 2), ("window_attn_fwd", 1)):
         assert plain[name] == frugal[name] == n
         assert bare_[name] == 2 * n
-    # what is not kept is run again: the routed experts' forward
-    assert frugal["moe_gmm"] > plain["moe_gmm"]
+    # a routed child's backward rebuilds its rounds' rows from the child's
+    # input, kept or not: recomputing it runs no grouped matmul more (its
+    # recomputed forward is its residuals, which are its arguments)
+    assert frugal["moe_gmm"] == plain["moe_gmm"] == 2 * (2 + 4)
+    assert bare_["moe_gmm"] == plain["moe_gmm"]
     assert all(frugal[k] == plain[k] for k in plain if "bwd" in k)
 
 
@@ -323,3 +326,32 @@ def test_a_grouped_attention_child_is_sized_at_its_own_kv_heads(
     _, whole_repeated, named_repeated = remat._trace_child(call, *args)
     assert named == named_repeated > 0
     assert whole_repeated - whole == 2 * (heads - kv) * b * t * dh * 4
+
+
+def test_a_routed_child_holds_nothing_a_round_or_a_layout_long():
+    """What a kept share of the experts holds for its backward: its
+    input, the router's scores, the plan's integers and the shared
+    expert's intermediates.  No row buffer (R = 3,072 rows in the layout,
+    R_c = 1,536 a round), no (N·k, D) gather: the rounds' rows are built
+    again in the backward, and a loop's temporaries are not residuals."""
+    from distkeras_tpu.ops.moe import SparseMoE, round_rows
+    (b, t, d), k = (2, 256, 32), 4
+    layer = SparseMoE(16, k, 24, shared_hidden=24, experts_held=4,
+                      first_expert=4)
+    params, state, _ = layer.init(jax.random.PRNGKey(0), (t, d))
+    args = (params, state, jax.ShapeDtypeStruct((b, t, d), jnp.float32), None)
+    call = functools.partial(layer.apply, train=True)
+    assert round_rows(b * t, k, 4, 16, 128) == 1536
+
+    def residuals(p, s, x, rng):
+        y, vjp, _ = jax.vjp(lambda p, x: call(p, s, x, rng=rng), p, x,
+                            has_aux=True)
+        return y, jax.tree_util.tree_leaves(vjp)
+
+    held = [v.aval for v in jax.make_jaxpr(residuals)(*args).jaxpr.outvars[1:]]
+    wide = [a for a in held if jnp.issubdtype(a.dtype, jnp.floating)
+            and a.ndim >= 2 and a.shape[-1] > 1]
+    assert wide and all(a.shape[0] in (b * t, 4, d, 24) for a in wide)
+    _, whole, named = remat._trace_child(call, *args)
+    # the layer with one buffer for every row held 2,206,292 here
+    assert (whole, named) == (387188, 0)
