@@ -52,6 +52,9 @@ KERNEL_OUTPUTS = tuple(n for names in KERNEL_OUTPUT_NAMES.values()
                        for n in names)
 #: the share of the device's limit that the estimate may fill
 FILL = 0.92
+#: what a plan's estimate is the sum of (``Plan.estimate``'s keys; on the
+#: record ``remat_<part>_bytes``)
+ESTIMATE_PARTS = ("held", "residual", "grads")
 #: a compiled program over this share of the limit: one application back
 REFUSE = 0.95
 
@@ -115,21 +118,27 @@ def tree_bytes(tree) -> int:
                for a in jax.tree_util.tree_leaves(tree))
 
 
-def peak(sizes: Sequence[dict], first_kept: int) -> int:
-    """The most the forward's residuals and the gradients take at one
-    time, in a backward that goes from the last child to the first.  A
+def peak_parts(sizes: Sequence[dict], first_kept: int) -> tuple:
+    """``(residuals, gradients)`` bytes at the moment their sum is the
+    most, in a backward that goes from the last child to the first.  A
     child's ``sizes`` entry: ``saved`` bytes held from its forward where
     it is checkpointed (its input, its named kernel outputs), ``whole``
     (no less) where it is kept and, kept or not, while it is
     differentiated, ``grads`` its parameters' gradients.  While child ``i`` is
     differentiated the earlier children hold what their forward left,
-    child ``i`` holds all of its own, and the gradients of ``i`` and the
-    later ones are there."""
+    child ``i`` holds all of its own (the residuals), and the gradients
+    of ``i`` and the later ones are there."""
     held = [s["whole"] if i >= first_kept else s["saved"]
             for i, s in enumerate(sizes)]
-    return max((sum(held[:i]) + s["whole"]
-                + sum(t["grads"] for t in sizes[i:])
-                for i, s in enumerate(sizes)), default=0)
+    return max(((sum(held[:i]) + s["whole"],
+                 sum(t["grads"] for t in sizes[i:]))
+                for i, s in enumerate(sizes)), key=sum, default=(0, 0))
+
+
+def peak(sizes: Sequence[dict], first_kept: int) -> int:
+    """The most the forward's residuals and the gradients take at one
+    time: the sum of :func:`peak_parts`."""
+    return sum(peak_parts(sizes, first_kept))
 
 
 def keep_from_end(sizes: Sequence[dict], budget: Optional[float]) -> int:
@@ -198,8 +207,10 @@ class Plan:
     step holds throughout; a test passes its own.  After a trace
     ``first_kept`` / ``children`` / ``bytes_estimated`` say what was
     decided (``children`` counts applications: a child that runs four
-    times is four), ``sizes`` the applications' estimates;
-    ``stepped_back`` counts the applications a judge took back."""
+    times is four), ``sizes`` the applications' estimates, ``estimate``
+    the three parts ``bytes_estimated`` is the sum of (all 0 where
+    nothing was estimated); ``stepped_back`` counts the applications a
+    judge took back."""
 
     def __init__(self, budget: Optional[float] = None):
         self.budget = budget
@@ -209,7 +220,7 @@ class Plan:
         self.children = 0
         self.first_kept = 0
         self.sizes: list = []
-        self.bytes_estimated = 0
+        self.estimate = dict.fromkeys(ESTIMATE_PARTS, 0)
         self.bytes_compiled = 0
         self.tracer = None     # the trainer's, for ``train.remat_plan``
 
@@ -228,36 +239,60 @@ class Plan:
         n = len(applications)
         span = (self.tracer or default_tracer()).span
         with span("train.remat_plan", children=n) as record:
-            first, estimated = n - 1, 0
+            first, parts = n - 1, (0, 0, 0)
             if self.budget is not None and n > 1:
                 self.sizes = _children_sizes(applications, x, rng)
                 first = min(keep_from_end(self.sizes, self.budget)
                             + self.stepped_back, n - 1)
-                estimated = self.held + peak(self.sizes, first)
-            self._decided(n, first, estimated)
-            record.update(self.record())
+                parts = (self.held, *peak_parts(self.sizes, first))
+            self._decided(n, first, parts)
+            record.update(self.record(), by_kind=self.by_kind())
         return first
 
     def whole_forward(self) -> None:
         """A model that is no ``Sequential``: one checkpoint around it."""
-        self._decided(1, 1, 0)
+        self._decided(1, 1)
 
-    def _decided(self, children: int, first_kept: int, estimated) -> None:
+    def _decided(self, children: int, first_kept: int,
+                 parts=(0, 0, 0)) -> None:
+        """``parts``: the estimate's, in ``ESTIMATE_PARTS``' order; zeros
+        where nothing was estimated."""
         self.children, self.first_kept = children, first_kept
-        self.bytes_estimated = int(estimated)
+        self.estimate = dict(zip(ESTIMATE_PARTS, map(int, parts),
+                                 strict=True))
         registry = default_registry()
         registry.counter("remat.children_kept").inc(children - first_kept)
         registry.counter("remat.children_recomputed").inc(first_kept)
-        registry.gauge("remat.bytes_estimated").set(self.bytes_estimated)
+
+    @property
+    def bytes_estimated(self) -> int:
+        return sum(self.estimate.values())
+
+    def by_kind(self) -> list:
+        """The applications by kind, in the order a kind first runs: for
+        each its name (the classes of the layer and of what it holds, as
+        the step's scopes spell them), the index of its first
+        application, how many there are and how many of them are kept,
+        and an application's ``whole`` and ``saved`` bytes.  Read off
+        ``sizes``: empty where nothing was sized."""
+        rows = {}
+        for i, size in enumerate(self.sizes):
+            row = rows.setdefault(
+                (size["kind"], size["whole"], size["saved"]),
+                {"kind": size["kind"], "first": i, "count": 0, "kept": 0,
+                 "whole": size["whole"], "saved": size["saved"]})
+            row["count"] += 1
+            row["kept"] += i >= self.first_kept
+        return list(rows.values())
 
     # -- the judge, after the compile -------------------------------------------
     def judge(self, compiled_bytes: int) -> bool:
-        """Records the compiled program's size (arguments + temporaries);
-        True where it passes ``REFUSE`` of the limit and an application
-        can still be taken back: the caller compiles again."""
+        """Records the compiled program's size (``obs.profile
+        .program_memory``'s ``program_bytes``: arguments + outputs −
+        aliased + temporaries, as the compiler counts them); True where
+        it passes ``REFUSE`` of the limit and an application can still be
+        taken back: the caller compiles again."""
         self.bytes_compiled = int(compiled_bytes)
-        default_registry().gauge("remat.bytes_compiled").set(
-            self.bytes_compiled)
         return (self.limit is not None
                 and compiled_bytes > REFUSE * self.limit
                 and self.step_back())
@@ -272,10 +307,16 @@ class Plan:
         return True
 
     def record(self) -> dict:
-        """The four numbers, as the ``jit_compile`` record carries them."""
+        """What was decided and what it came to, as the ``jit_compile``
+        record carries it: the counts, the estimate and the three parts
+        it is the sum of (what the step holds throughout; at the
+        application where :func:`peak` is reached the residuals and the
+        gradients), and the compiled program's size."""
         return {"remat_children_kept": self.children - self.first_kept,
                 "remat_children_recomputed": self.first_kept,
                 "remat_bytes_estimated": self.bytes_estimated,
+                **{f"remat_{part}_bytes": n
+                   for part, n in self.estimate.items()},
                 "remat_bytes_compiled": self.bytes_compiled}
 
 
@@ -300,7 +341,8 @@ def _children_sizes(applications, x, rng) -> list:
                 known[key] = _trace_child(call, *args)
         out, whole, named = known[key]
         saved = tree_bytes(x) + named
-        sizes.append({"saved": saved, "whole": max(whole, saved)})
+        sizes.append({"saved": saved, "whole": max(whole, saved),
+                      "kind": _kind(call)})
         x = out if fan_out is None else (out,) * fan_out
     met = set()
     for size, application in zip(reversed(sizes), reversed(applications)):
@@ -311,14 +353,30 @@ def _children_sizes(applications, x, rng) -> list:
     return sizes
 
 
+def _layer_of(call):
+    """The layer whose bound ``apply`` ``call`` is a partial of, or None."""
+    return getattr(getattr(call, "func", None), "__self__", None)
+
+
 def _call_key(call):
     """A child's identity for the cache: its layer's configuration where
     ``call`` is a partial of a bound ``apply``, else the call itself."""
-    layer = getattr(getattr(call, "func", None), "__self__", None)
+    layer = _layer_of(call)
     if layer is None:
         return id(call)
     return (type(layer).__name__, repr(layer.config()),
             repr(sorted(getattr(call, "keywords", {}).items())))
+
+
+def _kind(call) -> str:
+    """A child's name in ``Plan.by_kind``: its layer's class and those of
+    the layers inside it, each once, as the step's scopes spell them
+    (``residual/sequential/rmsnorm/multiheadattention``)."""
+    layer = _layer_of(call)
+    if layer is None:
+        return getattr(call, "__name__", type(call).__name__)
+    return "/".join(dict.fromkeys(type(sub).__name__.lower()
+                                  for sub in layer.iter_layers()))
 
 
 def _trace_child(call, p, s, x, rng):

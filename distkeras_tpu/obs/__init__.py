@@ -28,7 +28,9 @@ The profiling layer (ISSUE 6): ``profile`` adds the recompilation
 sentinel (``jit.compiles``/``jit.retraces``, drift-gated), memory
 watermarks (``mem.*`` gauges sampled at the heartbeat points), the
 compile ledger (what a ``jit_compile`` span spent on tracing, lowering
-and the backend, and whether the persistent cache was hit), and the one
+and the backend, and whether the persistent cache was hit), the memory
+account of the program a cold call compiled (``program_*`` bytes on the
+same record, as the compiler counts them), and the one
 sanctioned ``jax.profiler`` capture seam, in whose host plane every span
 appears (``spans`` holds a ``TraceAnnotation``); ``export`` renders the
 span/heartbeat JSONL as a Chrome/Perfetto trace

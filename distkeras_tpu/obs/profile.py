@@ -3,8 +3,8 @@
 PR 2 gave the stack metrics and spans, PR 5 a drift gate — but none of it
 answers the three questions a perf regression actually raises: *did
 something recompile*, *where did the HBM go*, and *is the step host-bound
-or device-bound*.  Four instruments, all feeding the existing registries
-so ``obs.drift`` gates them like any other metric:
+or device-bound*.  Five instruments, feeding the existing registries (so
+``obs.drift`` gates them like any other metric) and the spans' records:
 
 * **Recompilation sentinel** (``RetraceSentinel``) — tracks the arg
   signature (pytree structure + per-leaf shape/dtype) of every jit entry
@@ -25,6 +25,14 @@ so ``obs.drift`` gates them like any other metric:
   is a compile that was written to the cache, ``cache_hits`` 1 a load
   from it, both 0 a compile the cache never saw (no cache directory, or
   a program under ``jax_persistent_cache_min_compile_time_secs``).
+* **Program memory** (``program_memory``, ISSUE 38) — the memory account
+  of a compiled program, taken where the executable is born: the
+  compiler's own bytes of arguments, outputs, aliased (donated) outputs,
+  temporaries and code, their sum ``program_bytes``, and beside them the
+  device's limit and the fullest device's ``bytes_in_use``.  The
+  trainers compile a cold call's program first and put the account on
+  its ``jit_compile`` (``SpmdTrainer``: ``aot_compile``) record; nothing
+  runs on a warm call.
 * **Memory watermarks** (``memory_snapshot`` / ``observe_memory``) —
   live device-array bytes (``jax.live_arrays()``), array count, a
   max-tracked ``mem.peak_live_bytes`` gauge, and the backend allocator's
@@ -269,6 +277,58 @@ def compile_spent(before: dict) -> dict:
     (a :func:`compile_totals` reading): the five fields of a
     ``jit_compile`` record."""
     return {k: v - before[k] for k, v in compile_totals().items()}
+
+
+# ---------------------------------------------------------------------------
+# the memory account of a compiled program
+# ---------------------------------------------------------------------------
+
+#: ``compiled.memory_analysis()``'s counts -> the field each becomes
+_PROGRAM_FIELD = {
+    "argument_size_in_bytes": "program_argument_bytes",
+    "output_size_in_bytes": "program_output_bytes",
+    "alias_size_in_bytes": "program_alias_bytes",
+    "temp_size_in_bytes": "program_temp_bytes",
+    "generated_code_size_in_bytes": "program_code_bytes",
+}
+
+
+def program_memory(compiled) -> dict:
+    """The memory account of one executable (``jit(f).lower(...)
+    .compile()``), as a cold call's ``jit_compile`` / ``aot_compile``
+    record carries it.  From the compiler's own ``memory_analysis()``:
+    ``program_argument_bytes``, ``program_output_bytes``,
+    ``program_alias_bytes`` (outputs written over donated arguments),
+    ``program_temp_bytes`` (what the program takes while it runs, beside
+    its arguments and outputs), ``program_code_bytes``, and the one sum
+    that says whether it fits: ``program_bytes`` = arguments + outputs −
+    aliased + temporaries.  Of a program over a mesh the analysis is ONE
+    device's share (a sharded argument counts at its shard's size), which
+    is what a device's limit is held against.  Beside them
+    ``device_bytes_limit`` (``models.remat.device_limit``) and
+    ``device_bytes_in_use``, the allocator's ``bytes_in_use`` on the
+    fullest local device (a max over devices, never a sum) at the moment
+    of this call: both None where the backend reports none (the CPU).  An
+    executable that reports no analysis gives an empty dict."""
+    import jax
+    from ..models.remat import device_limit
+    try:
+        analysis = compiled.memory_analysis()
+    except (RuntimeError, NotImplementedError, AttributeError):
+        analysis = None
+    if analysis is None:
+        return {}
+    account = {field: int(getattr(analysis, name))
+               for name, field in _PROGRAM_FIELD.items()}
+    account["program_bytes"] = (
+        account["program_argument_bytes"] + account["program_output_bytes"]
+        - account["program_alias_bytes"] + account["program_temp_bytes"])
+    in_use = [stats["bytes_in_use"] for stats in
+              (d.memory_stats() for d in jax.local_devices())
+              if stats and stats.get("bytes_in_use") is not None]
+    account["device_bytes_limit"] = device_limit()
+    account["device_bytes_in_use"] = max(map(int, in_use), default=None)
+    return account
 
 
 # ---------------------------------------------------------------------------

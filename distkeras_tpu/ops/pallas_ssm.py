@@ -18,7 +18,7 @@ chunk (L positions) of one group's heads, on a grid (batch, group, chunk):
   ``S_c`` that ``S_in`` is made from, so a carry left to XLA would cost two
   kernels a pass and the states' round trip through HBM;
 * the forward writes each chunk's ``S_in`` (what the backward needs beside
-  the inputs: ``ssm.state_bytes``) and tags it and ``y`` with
+  the inputs: float32, (B, chunks, H, P, N)) and tags it and ``y`` with
   ``remat.name_kernel_outputs``, so a recomputed layer reads them as kept
   and runs no forward kernel again; everything else the backward needs it
   computes again in VMEM.

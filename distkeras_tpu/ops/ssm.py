@@ -88,10 +88,7 @@ def ssd(x, dt, a, b, c, *, chunk: int, impl: str = "chunked"):
         jnp.triu(jnp.ones((chunk, chunk), jnp.float32)),
         precision=lax.Precision.HIGHEST)
     if not sizing():  # the recompute plan's own trace of a child
-        registry = default_registry()
-        registry.counter("ssm.chunks").inc((t + pad) // chunk)
-        registry.counter("ssm.state_bytes").inc(
-            bsz * ((t + pad) // chunk) * h * p * n * 4)
+        default_registry().counter("ssm.chunks").inc((t + pad) // chunk)
     if impl == "pallas":
         from .pallas_ssm import ssd_chunks as run
     else:

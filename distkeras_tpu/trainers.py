@@ -272,7 +272,16 @@ class Trainer:
         (XLA's compile, or the persistent cache's read and load at a
         hit), ``cache_hits`` / ``cache_misses`` — from JAX's own timers
         (``obs.profile.compile_totals``); what is left of ``seconds`` is
-        the dispatch."""
+        the dispatch.
+
+        ISSUE 38: and what the program takes.  A ``run`` that can be
+        lowered (a ``jax.jit``) is compiled before it is called
+        (``_fit_remat``: the call then finds trace, lowering and
+        executable cached), and once the call is dispatched the
+        executable's memory account (``obs.profile.program_memory``: the
+        ``program_*`` bytes, the device's limit and its ``bytes_in_use``)
+        goes on the record.  A plain wrapper records none and runs as it
+        is; a warm call does nothing of this."""
         key = (kind, self._config_key())
         sentinel = self._sentinels.get(key)
         if sentinel is None:
@@ -290,42 +299,46 @@ class Trainer:
                                      if state == "retrace" else {})
                                   ) as record:
                 before = obs_profile.compile_totals()
+                compiled = None
                 try:
-                    self._fit_remat(run, args, record)
+                    if hasattr(run, "lower"):
+                        compiled = self._fit_remat(run, args, record)
                     return run(*args)
                 finally:
+                    if compiled is not None:
+                        record.update(obs_profile.program_memory(compiled))
                     record.update(obs_profile.compile_spent(before))
         return wrapped
 
-    def _fit_remat(self, run, args, record: dict) -> None:
-        """The judge of a program that may recompute (``run.remat_plan``,
-        ``models.remat``): the cold call compiles it before it runs it
-        (the call then finds trace, lowering and executable cached: no
-        second of any), puts the program's own size (arguments + outputs
-        − aliased + temporaries, as the compiler counts them) beside the
-        plan's estimate on the ``jit_compile`` record, and where that
-        passes ``remat.REFUSE`` of the device's limit, or XLA refuses the
-        program for memory, has the plan recompute one child more and
-        compiles again."""
+    def _fit_remat(self, run, args, record: dict):
+        """Compiles the cold call's program before the call runs it (the
+        call then finds trace, lowering and executable cached: no second
+        of any) and returns the executable, whose memory account the
+        record carries.  Of a program that may recompute
+        (``run.remat_plan``, ``models.remat``) it is the judge besides:
+        it puts the program's own size (``program_memory``'s
+        ``program_bytes``) beside the plan's estimate on the
+        ``jit_compile`` record, and where that passes ``remat.REFUSE`` of
+        the device's limit, or XLA refuses the program for memory, has
+        the plan recompute one child more and compiles again."""
         plan = getattr(run, "remat_plan", None)
         if plan is None:
-            return
+            return run.lower(*args).compile()
         plan.tracer = self.tracer
         while True:
             try:
-                mem = run.lower(*args).compile().memory_analysis()
+                compiled = run.lower(*args).compile()
             except jax.errors.JaxRuntimeError as e:
                 if "RESOURCE_EXHAUSTED" not in str(e) \
                         or not plan.step_back():
                     raise
                 why = f"XLA refused the program: {str(e)[:200]}"
             else:
-                size = 0 if mem is None else (
-                    mem.argument_size_in_bytes + mem.output_size_in_bytes
-                    - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+                size = obs_profile.program_memory(compiled).get(
+                    "program_bytes", 0)
                 if not plan.judge(size):
                     record.update(plan.record())
-                    return
+                    return compiled
                 why = f"the program is {size} B of the device's {plan.limit}"
             get_logger("trainers").warning(
                 "remat: %s; compiling again with %d of %d children "
@@ -1165,10 +1178,13 @@ class SpmdTrainer(Trainer):
             with self.tracer.span("aot_compile",
                                   trainer=type(self).__name__,
                                   **({"retrace": True}
-                                     if state == "retrace" else {})):
+                                     if state == "retrace" else {})
+                                  ) as record:
                 self._aot_cache = (akey,
                                    pinned.lower(variables, opt_state, rng,
                                                 xs, ys).compile())
+                record.update(
+                    obs_profile.program_memory(self._aot_cache[1]))
         compiled = self.compiled_step = self._aot_cache[1]
         samples = int(xs.shape[0]) * self.batch_size
         pipe = _EpochPipeline(self, samples)

@@ -220,12 +220,12 @@ def test_a_trainer_counts_its_plan_and_judges_the_compiled_step():
     trainer.train(train)
     # no limit on the CPU: the frugal plan, the last of 7 children kept
     assert [c.value - b for c, b in zip(counters, before)] == [1, 6]
-    assert registry.gauge("remat.bytes_estimated").value == 0
-    compiled = registry.gauge("remat.bytes_compiled").value
-    assert compiled > 0
     spans = {r["name"]: r for r in trainer.metrics.records
              if r["event"] == "span"}
     record = spans["jit_compile"]
+    assert record["remat_bytes_estimated"] == 0
+    compiled = record["remat_bytes_compiled"]
+    assert compiled > 0 and compiled == record["program_bytes"]
     assert (record["remat_children_kept"], record["remat_children_recomputed"],
             record["remat_bytes_estimated"], record["remat_bytes_compiled"]) \
         == (1, 6, 0, compiled)
