@@ -338,8 +338,9 @@ class MultiHeadAttention(Layer):
         (``impl="dense"``), the sequence-parallel ring (its hops rotate
         K/V blocks of the query's head count) and ``apply_prefill``.
         The flash kernels of ``apply`` read K and V at their own head
-        count (``pallas_attention.flash_attention``), and the decode
-        CACHE stays KV-sized."""
+        count with no repeat (``pallas_attention.flash_attention``: in
+        the projected layout for equal heads of 64, on transposes for
+        any other shape), and the decode CACHE stays KV-sized."""
         g = self.num_heads // self._kv
         return k if g == 1 else jnp.repeat(k, g, axis=2)
 

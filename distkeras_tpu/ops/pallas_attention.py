@@ -16,25 +16,41 @@ it can see (``causal``, the two lengths, T, Dh, the dtype):
   (``_walk`` builds them, ``_step`` tells a body where it is):
   - a causal call — explicit ``block_q``/``block_k``, or a length whose
     whole-sequence operands would not fit ``_CAUSAL_VMEM_BUDGET``
-    (T = 4,096 and up at bf16) — runs a (batch·head, step) grid over
+    (T = 4,096 and up at bf16) — runs a (batch, head, step) grid over
     ``_walk_table``: a static table of the block pairs with work,
     scalar-prefetched, that every index map reads; a pair past the
     diagonal is no step and no DMA, and the mask is built only in the
     blocks the diagonal crosses.  At T = 8,192 in 512-blocks 136 of 256
     pairs are steps, 16 of them masked;
-  - a sliding window runs (batch·head, query block, band): the key
+  - a sliding window runs (batch, head, query block, band): the key
     blocks the band touches alone (2 of 16), by arithmetic index maps —
     with one idle step in 32 the table's dearer step does not pay;
   - a non-causal call (every rectangular one: the zigzag ring's hops)
-    runs the dense (batch·head, block, block) grid: it has no pair to
+    runs the dense (batch, head, block, block) grid: it has no pair to
     skip.
+  ("head" is a block of heads of the layout below.)
 * the IN-KERNEL causal walk (default blocks, causal, Tq == Tk, 256 <= T
-  within the budget): one grid step a batch·head with q, k, v (and dO)
+  within the budget): one grid step a (batch, head) with q, k, v (and dO)
   resident as whole-sequence blocks; the kernel body walks the static
   ``_causal_schedule`` — per tile ONE matmul over the tiles at or before
   the diagonal, the mask built only on the tile the diagonal crosses,
   no grid step and no DMA for a tile past it.  At T = 1,024 (256 tiles)
   10 of 16 tile pairs run, 4 of them masked.
+
+The layout: heads of 64 (``_pack``, from the shapes: as many K/V heads
+as query heads, an even count of them) are read and written as the
+projections make them, (B, T, heads·64), with no transpose around any
+kernel.  A block is (rows, 128) columns of that array, two heads told
+apart by lane masks, its index map picks the head pair's column block,
+and every grid leads with (batch, head pair).  S of head j is q masked
+to j's lanes against K (the same MXU passes as a 64-wide contraction),
+and P_j V and dS_j K keep j's lanes alone, so the two heads' results
+add exactly.  The per-row statistics ``lse`` / ``dvec`` are (B, heads ÷
+2, 2, T), a block's heads as rows on lanes.  Every other shape takes the
+same kernels on transposed operands, (B·heads, T, Dh), one head a block
+and a (batch·head, 1) lead: the counters
+``flash.layout_native_kernels`` / ``flash.layout_transposed_kernels``
+say which a program built.
 
 Grouped queries: K and V come at their own head count, (B, T, KV, Dh)
 with KV dividing H, and the grid walk never makes them H heads wide.
@@ -176,10 +192,13 @@ def _step(refs, walk, block_q: int, block_k: int):
     * ``("group", dense or band)``: dK/dV of a K/V head that several
       query heads share, one grid axis more between the row and its
       steps; the row begins at the first head's first step and ends at
-      the last head's last.  (On the table the group is in the rows.)"""
+      the last head's last.  (On the table the group is in the rows.)
+
+    Every grid leads with two axes, (batch, head block), that no body
+    reads."""
     if walk is not None and walk[0] == "table":
         (qi_ref, kb_ref, flag_ref), refs = refs[:3], refs[walk[3]:]
-        s = pl.program_id(1)
+        s = pl.program_id(2)
         flags = flag_ref[s]
         masked = (flags & _MASKED) != 0
         runs = [(jnp.logical_not(masked), False), (masked, True)]
@@ -190,13 +209,13 @@ def _step(refs, walk, block_q: int, block_k: int):
     grouped = walk is not None and walk[0] == "group"
     if grouped:
         walk = walk[1]
-    minor = 3 if grouped else 2
-    row, j, n = pl.program_id(1), pl.program_id(minor), pl.num_programs(minor)
+    minor = 4 if grouped else 3
+    row, j, n = pl.program_id(2), pl.program_id(minor), pl.num_programs(minor)
 
     def ends():
         if not grouped:
             return j == 0, j == n - 1
-        g, last_g = pl.program_id(2), pl.num_programs(2) - 1
+        g, last_g = pl.program_id(3), pl.num_programs(3) - 1
         return (g == 0) & (j == 0), (g == last_g) & (j == n - 1)
 
     if walk is None:
@@ -243,19 +262,45 @@ def _lanes(x, n: int):
     return x if n == 128 else pltpu.repeat(x, n // 128, axis=1)
 
 
+def _heads(x, pack: int):
+    """A block of ``pack`` heads side by side in its lanes as ``pack``
+    blocks, each one head's lanes with the others zero (one head: the
+    block itself)."""
+    if pack == 1:
+        return [x]
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    width = x.shape[1] // pack
+    return [jnp.where((lane >= j * width) & (lane < (j + 1) * width), x,
+                      jnp.zeros_like(x)) for j in range(pack)]
+
+
+def _joined(parts):
+    """One block from one a head: head j's lanes from ``parts[j]``."""
+    if len(parts) == 1:
+        return parts[0]
+    lane = lax.broadcasted_iota(jnp.int32, parts[0].shape, 1)
+    width = parts[0].shape[1] // len(parts)
+    out = parts[-1]
+    for j in range(len(parts) - 2, -1, -1):
+        out = jnp.where(lane < (j + 1) * width, parts[j], out)
+    return out
+
+
 def _fwd_kernel(*refs, scale: float, block_q: int, block_k: int, window,
-                walk):
+                walk, pack: int):
     """One (q block, K/V block) pair a grid step; the accumulators
     persist across a row's steps (TPU executes the grid sequentially,
-    minor-most last).  Grid (bh, q blocks, k blocks) for a non-causal
-    call; (bh, steps of ``_walk_table``) for a causal one.  The running
-    max and sum are kept REPLICATED over the 128 lanes of their scratch:
-    a lane reduction leaves its result that way, so a step loads,
-    updates and stores them as whole vregs; kept as one column they cost
-    a lane gather and a rotate a vreg a step (451 + 448 in the lowered
-    body), a third of the forward's time at 512² blocks (v5e)."""
+    minor-most last).  Grid (batch, head, q blocks, k blocks) for a
+    non-causal call; (batch, head, steps of ``_walk_table``) for a causal
+    one.  The running max and sum, one (rows, 128) scratch a head of
+    the block, are kept REPLICATED over its lanes: a lane reduction
+    leaves its result that way, so a step loads, updates and stores them
+    as whole vregs; kept as one column they cost a lane gather and a
+    rotate a vreg a step (451 + 448 in the lowered body), a third of the
+    forward's time at 512² blocks (v5e)."""
     offset, first, last, runs, refs = _step(refs, walk, block_q, block_k)
     q_ref, k_ref, v_ref, o_ref, lse_ref, o_acc, m_acc, l_acc = refs
+    width = o_acc.shape[1]
 
     @pl.when(first)
     def _init():
@@ -266,51 +311,107 @@ def _fwd_kernel(*refs, scale: float, block_q: int, block_k: int, window,
     def _compute(with_mask):
         # matmuls in the input dtype (f32 → HIGHEST, bf16 → full MXU
         # rate with f32 accumulation); softmax statistics always f32
-        s = _dot_t(q_ref[0], k_ref[0]) * scale
+        k, v = k_ref[0], v_ref[0]
         if with_mask:
-            mask = _pair_mask(offset, s.shape, window)
-            s = jnp.where(mask, s, _NEG)
-        m_prev = m_acc[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - _lanes(m_new, s.shape[1]))
-        if with_mask:
-            p = jnp.where(mask, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_acc[:] = l_acc[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        o_acc[:] = o_acc[:] * _lanes(corr, o_acc.shape[1]) + _dot(
-            p.astype(v_ref.dtype), v_ref[0])
-        m_acc[:] = m_new
+            mask = _pair_mask(offset, (block_q, block_k), window)
+        corrs, pvs = [], []
+        for j, q in enumerate(_heads(q_ref[0], pack)):
+            s = _dot_t(q, k) * scale
+            if with_mask:
+                s = jnp.where(mask, s, _NEG)
+            m_prev = m_acc[j]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, s.shape[1]))
+            if with_mask:
+                p = jnp.where(mask, p, 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_acc[j] = l_acc[j] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            m_acc[j] = m_new
+            corrs.append(_lanes(corr, width))
+            pvs.append(_dot(p.astype(v.dtype), v))
+        o_acc[:] = o_acc[:] * _joined(corrs) + _joined(pvs)
 
     _run(runs, _compute)
 
     @pl.when(last)
     def _finalize():
-        l = l_acc[:]
-        o_ref[0] = (o_acc[:] / _lanes(l, o_acc.shape[1])).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_acc[:] + jnp.log(l))[:, 0]
+        l = [l_acc[j] for j in range(pack)]
+        o_ref[0] = (o_acc[:] / _joined([_lanes(x, width) for x in l])
+                    ).astype(o_ref.dtype)
+        for j in range(pack):
+            lse_ref[0, 0, j] = (m_acc[j] + jnp.log(l[j]))[:, 0]
 
 
 # ---------------------------------------------------------------------------
 # backward (Dao 2022 recurrence; P recomputed blockwise from L)
 # ---------------------------------------------------------------------------
 
+def _rowsums(do, o, pack: int, g=None):
+    """D = rowsum(dO ∘ O) of each head of a block, the softmax-grad
+    correction term, less ``g`` (the ``lse`` cotangent's block, (pack,
+    rows) on lanes) where there is one: ∂lse_i/∂s_ij = P_ij lands where
+    D_i enters dS = P∘(dP − D).  For each head a float32 (rows, 128)
+    block of D replicated over its lanes, and D as a (1, rows) row.
+
+    The sums run on the MXU, against ones, giving the row and the
+    replicated column directly, where a lane reduction and the relayout
+    of its column to a row would load the vector units (PERF.md §6).  A
+    product of two bf16 numbers has 16 significant bits, so it is two
+    bf16 parts that sum to it exactly, each multiplied at full rate into
+    float32; float32 operands multiply at HIGHEST (``_dot``)."""
+    o = o.astype(jnp.float32)
+    width = o.shape[1]
+    sums = []
+    for j, do_j in enumerate(_heads(do, pack)):
+        x = do_j.astype(jnp.float32) * o       # head j's lanes alone
+        parts = [x]
+        if do.dtype != jnp.float32:
+            high = x.astype(do.dtype)
+            parts = [high, (x - high.astype(jnp.float32)).astype(do.dtype)]
+        col = sum(_dot(part, jnp.ones((width, 128), part.dtype))
+                  for part in parts)
+        row = sum(_dot_t(jnp.ones((8, width), part.dtype), part)
+                  for part in parts)[:1]
+        if g is not None:
+            col, row = col - g[j][:, None], row - g[j:j + 1]
+        sums.append((col, row))
+    return sums
+
+
 def _bwd_dq_kernel(*refs, scale: float, block_q: int, block_k: int, window,
-                   walk):
+                   walk, pack: int, corrected: bool):
+    """dQ of a query block over its key blocks; at a row's first step
+    the block's D (``_rowsums``) is computed once, kept lane-replicated
+    in scratch for the row's steps and written out for dK/dV."""
     offset, first, last, runs, refs = _step(refs, walk, block_q, block_k)
-    q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref, dq_ref, dq_acc = refs
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *refs = refs
+    g_ref = refs.pop(0) if corrected else None
+    dq_ref, dvec_ref, dq_acc, dvec_acc = refs
 
     @pl.when(first)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+        for j, (col, row) in enumerate(_rowsums(
+                do_ref[0], o_ref[0], pack,
+                None if g_ref is None else g_ref[0, 0])):
+            dvec_acc[j] = col
+            dvec_ref[0, 0, j:j + 1] = row
 
     def _compute(with_mask):
-        s = _dot_t(q_ref[0], k_ref[0]) * scale
-        p = jnp.exp(s - lse_ref[0, 0][:, None])
+        k, v = k_ref[0], v_ref[0]
         if with_mask:
-            p = jnp.where(_pair_mask(offset, s.shape, window), p, 0.0)
-        dp = _dot_t(do_ref[0], v_ref[0])
-        ds = p * (dp - dvec_ref[0, 0][:, None]) * scale
-        dq_acc[:] = dq_acc[:] + _dot(ds.astype(k_ref.dtype), k_ref[0])
+            mask = _pair_mask(offset, (block_q, block_k), window)
+        parts = []
+        for j, (q, do) in enumerate(zip(_heads(q_ref[0], pack),
+                                        _heads(do_ref[0], pack))):
+            s = _dot_t(q, k) * scale
+            p = jnp.exp(s - lse_ref[0, 0, j][:, None])
+            if with_mask:
+                p = jnp.where(mask, p, 0.0)
+            dp = _dot_t(do, v)
+            ds = p * (dp - _lanes(dvec_acc[j], block_k)) * scale
+            parts.append(_dot(ds.astype(k.dtype), k))
+        dq_acc[:] = dq_acc[:] + _joined(parts)
 
     _run(runs, _compute)
 
@@ -320,13 +421,14 @@ def _bwd_dq_kernel(*refs, scale: float, block_q: int, block_k: int, window,
 
 
 def _bwd_dkv_kernel(*refs, scale: float, block_q: int, block_k: int, window,
-                    walk):
+                    walk, pack: int):
     """A row is a K/V block and its query blocks: every one on the dense
     grid, from the diagonal's on down the causal walk.  S and dP are
     built TRANSPOSED (keys on sublanes), as the in-kernel walk builds
     them: P^T and dS^T feed the dV and dK matmuls as they are (no 512²
     transpose a step), and ``lse`` / ``dvec`` broadcast from the (1, bq)
-    lane layout they arrive in."""
+    lane layout they arrive in.  Of a block of heads, K and V are the
+    side masked to a head's lanes."""
     offset, first, last, runs, refs = _step(refs, walk, block_q, block_k)
     (k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref, dk_ref, dv_ref, dk_acc,
      dv_acc) = refs
@@ -337,16 +439,24 @@ def _bwd_dkv_kernel(*refs, scale: float, block_q: int, block_k: int, window,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     def _compute(with_mask):
-        st = _dot_t(k_ref[0], q_ref[0]) * scale       # (BK, BQ)
-        pt = jnp.exp(st - lse_ref[0])
+        q, do = q_ref[0], do_ref[0]
         if with_mask:
-            pt = jnp.where(_pair_mask(offset, st.shape, window,
-                                      keys_first=True), pt, 0.0)
-        # dV += P^T dO ; dS = P∘(dO V^T − D) ; dK += dS^T Q
-        dv_acc[:] = dv_acc[:] + _dot(pt.astype(do_ref.dtype), do_ref[0])
-        dpt = _dot_t(v_ref[0], do_ref[0])
-        dst = pt * (dpt - dvec_ref[0]) * scale
-        dk_acc[:] = dk_acc[:] + _dot(dst.astype(q_ref.dtype), q_ref[0])
+            mask = _pair_mask(offset, (block_k, block_q), window,
+                              keys_first=True)
+        dks, dvs = [], []
+        for j, (k, v) in enumerate(zip(_heads(k_ref[0], pack),
+                                       _heads(v_ref[0], pack))):
+            st = _dot_t(k, q) * scale                     # (BK, BQ)
+            pt = jnp.exp(st - lse_ref[0, 0, j:j + 1])
+            if with_mask:
+                pt = jnp.where(mask, pt, 0.0)
+            # dV += P^T dO ; dS = P∘(dO V^T − D) ; dK += dS^T Q
+            dvs.append(_dot(pt.astype(do.dtype), do))
+            dpt = _dot_t(v, do)
+            dst = pt * (dpt - dvec_ref[0, 0, j:j + 1]) * scale
+            dks.append(_dot(dst.astype(q.dtype), q))
+        dv_acc[:] = dv_acc[:] + _joined(dvs)
+        dk_acc[:] = dk_acc[:] + _joined(dks)
 
     _run(runs, _compute)
 
@@ -419,50 +529,69 @@ def _to_row(col):
 
 
 def _fwd_causal_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
-                       tile: int):
-    """All q tiles of one batch·head; a q tile's row of S is whole in
-    VMEM, so its softmax is plain: one max, one sum a row."""
+                       tile: int, pack: int):
+    """All q tiles of one (batch, head block); a q tile's row of S is
+    whole in VMEM, so its softmax is plain: one max, one sum a row."""
     for qs, lo, hi in _causal_schedule(q_ref.shape[1], tile):
-        s = _dot_t(q_ref[0, qs:qs + tile, :], k_ref[0, lo:hi, :]) * scale
-        s = _mask_diag(s, qs - lo, tile, _NEG)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m)
-        l = jnp.sum(p, axis=-1, keepdims=True)
-        acc = _dot(p.astype(v_ref.dtype), v_ref[0, lo:hi, :])
-        o_ref[0, qs:qs + tile, :] = (acc / l).astype(o_ref.dtype)
-        lse_ref[0, :, qs:qs + tile] = _to_row(m + jnp.log(l))
+        k, v = k_ref[0, lo:hi, :], v_ref[0, lo:hi, :]
+        outs = []
+        for j, q in enumerate(_heads(q_ref[0, qs:qs + tile, :], pack)):
+            s = _mask_diag(_dot_t(q, k) * scale, qs - lo, tile, _NEG)
+            m = jnp.max(s, axis=-1, keepdims=True)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=-1, keepdims=True)
+            outs.append(_dot(p.astype(v.dtype), v) / l)
+            lse_ref[0, 0, j:j + 1, qs:qs + tile] = _to_row(m + jnp.log(l))
+        o_ref[0, qs:qs + tile, :] = _joined(outs).astype(o_ref.dtype)
 
 
-def _bwd_dq_causal_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
-                          dq_ref, *, scale: float, tile: int):
+def _bwd_dq_causal_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *refs,
+                          scale: float, tile: int, pack: int,
+                          corrected: bool):
+    """dQ of all q tiles of one (batch, head block), and their D
+    (``_rowsums``), written out for dK/dV."""
+    g_ref = refs[0] if corrected else None
+    dq_ref, dvec_ref = refs[-2:]
     for qs, lo, hi in _causal_schedule(q_ref.shape[1], tile):
-        k = k_ref[0, lo:hi, :]
-        s = _dot_t(q_ref[0, qs:qs + tile, :], k) * scale
-        p = jnp.exp(s - lse_ref[0, 0, qs:qs + tile][:, None])
-        p = _mask_diag(p, qs - lo, tile, 0.0)
-        dp = _dot_t(do_ref[0, qs:qs + tile, :], v_ref[0, lo:hi, :])
-        ds = p * (dp - dvec_ref[0, 0, qs:qs + tile][:, None]) * scale
-        dq_ref[0, qs:qs + tile, :] = _dot(ds.astype(k.dtype),
-                                          k).astype(dq_ref.dtype)
+        k, v = k_ref[0, lo:hi, :], v_ref[0, lo:hi, :]
+        rows = slice(qs, qs + tile)
+        dvecs = _rowsums(do_ref[0, rows, :], o_ref[0, rows, :], pack,
+                         None if g_ref is None else g_ref[0, 0, :, rows])
+        parts = []
+        for j, (q, do) in enumerate(zip(_heads(q_ref[0, rows, :], pack),
+                                        _heads(do_ref[0, rows, :], pack))):
+            s = _dot_t(q, k) * scale
+            p = jnp.exp(s - lse_ref[0, 0, j, rows][:, None])
+            p = _mask_diag(p, qs - lo, tile, 0.0)
+            dp = _dot_t(do, v)
+            ds = p * (dp - _lanes(dvecs[j][0], hi - lo)) * scale
+            parts.append(_dot(ds.astype(k.dtype), k))
+            dvec_ref[0, 0, j:j + 1, rows] = dvecs[j][1]
+        dq_ref[0, rows, :] = _joined(parts).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_causal_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref,
-                           dk_ref, dv_ref, *, scale: float, tile: int):
-    """All k tiles of one batch·head, S and dP built TRANSPOSED (keys on
-    sublanes): P^T and dS^T feed the dV and dK matmuls as they are, and
-    the row statistics broadcast from their (1, T) lane layout."""
+                           dk_ref, dv_ref, *, scale: float, tile: int,
+                           pack: int):
+    """All k tiles of one (batch, head block), S and dP built TRANSPOSED
+    (keys on sublanes): P^T and dS^T feed the dV and dK matmuls as they
+    are, and the row statistics broadcast from their (1, T) lane
+    layout."""
     for ks, lo, hi in _causal_schedule(q_ref.shape[1], tile, by_keys=True):
-        q = q_ref[0, lo:hi, :]
-        do = do_ref[0, lo:hi, :]
-        st = _dot_t(k_ref[0, ks:ks + tile, :], q) * scale
-        pt = jnp.exp(st - lse_ref[0, :, lo:hi])
-        pt = _mask_diag(pt, ks - lo, tile, 0.0, keys_first=True)
-        dv_ref[0, ks:ks + tile, :] = _dot(pt.astype(do.dtype),
-                                          do).astype(dv_ref.dtype)
-        dpt = _dot_t(v_ref[0, ks:ks + tile, :], do)
-        dst = pt * (dpt - dvec_ref[0, :, lo:hi]) * scale
-        dk_ref[0, ks:ks + tile, :] = _dot(dst.astype(q.dtype),
-                                          q).astype(dk_ref.dtype)
+        q, do = q_ref[0, lo:hi, :], do_ref[0, lo:hi, :]
+        dks, dvs = [], []
+        for j, (k, v) in enumerate(zip(
+                _heads(k_ref[0, ks:ks + tile, :], pack),
+                _heads(v_ref[0, ks:ks + tile, :], pack))):
+            st = _dot_t(k, q) * scale
+            pt = jnp.exp(st - lse_ref[0, 0, j:j + 1, lo:hi])
+            pt = _mask_diag(pt, ks - lo, tile, 0.0, keys_first=True)
+            dvs.append(_dot(pt.astype(do.dtype), do))
+            dpt = _dot_t(v, do)
+            dst = pt * (dpt - dvec_ref[0, 0, j:j + 1, lo:hi]) * scale
+            dks.append(_dot(dst.astype(q.dtype), q))
+        dv_ref[0, ks:ks + tile, :] = _joined(dvs).astype(dv_ref.dtype)
+        dk_ref[0, ks:ks + tile, :] = _joined(dks).astype(dk_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -470,7 +599,7 @@ def _bwd_dkv_causal_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dvec_ref,
 # ---------------------------------------------------------------------------
 
 def _count_tiles(causal, tq, tk, bq, bk, tile, kernels: int,
-                 window=None, group: int = 1) -> None:
+                 window=None, group: int = 1, pack=None) -> None:
     """The schedule is static, so it is counted where it is built: a
     causal call adds, once for each of the ``kernels`` it puts into the
     program, to the default registry's ``flash.causal_tiles_executed`` /
@@ -486,10 +615,16 @@ def _count_tiles(causal, tq, tk, bq, bk, tile, kernels: int,
     head, causal or not, adds its kernels to ``flash.kv_native_kernels``
     where they read K and V at their own head count (the grid walk), to
     ``flash.kv_expanded_kernels`` where they run on K and V repeated to
-    the query heads (the in-kernel walk, ``tile``)."""
+    the query heads (the in-kernel walk, ``tile``).  Every call adds its
+    kernels to ``flash.layout_native_kernels`` where they read and write
+    the projected layout in place (``pack``, ``_pack``), to
+    ``flash.layout_transposed_kernels`` where they run on transposed
+    operands (``pack`` None)."""
     if sizing():  # the recompute plan's own trace of a child
         return
     registry = default_registry()
+    registry.counter("flash.layout_transposed_kernels" if pack is None
+                     else "flash.layout_native_kernels").inc(kernels)
     if group > 1:
         registry.counter("flash.kv_native_kernels" if tile is None
                          else "flash.kv_expanded_kernels").inc(kernels)
@@ -517,22 +652,22 @@ def _count_tiles(causal, tq, tk, bq, bk, tile, kernels: int,
     registry.counter("flash.causal_tiles_masked").inc(kernels * masked)
 
 
-def _whole(t, dh):
-    return pl.BlockSpec((1, t, dh), lambda b: (b, 0, 0))
+def _whole(t, width):
+    return pl.BlockSpec((1, t, width), lambda n, c: (n, 0, c))
 
 
-def _whole_row(t):
-    return pl.BlockSpec((1, 1, t), lambda b: (b, 0, 0))
+def _whole_row(t, pack):
+    return pl.BlockSpec((1, 1, pack, t), lambda n, c: (n, c, 0, 0))
 
 
 def _walk(causal, tq, tk, bq, bk, window, by_keys=False, group=1):
     """The grid a grid-walk kernel runs on, from what the launcher can
     see: ``(walk, grid, at, prefetch)`` — what ``_step`` reads in the
-    kernel, the grid's axes after its leading one, the index maps of a
-    block on the query side (``"q"``), on the key side (``"k"``) and of
-    a query block of ``lse`` / ``dvec`` on lanes (``"row"``), and the
-    scalar-prefetched operands.  A row of the grid is a query block, or
-    ``by_keys`` (the dK/dV kernel) a key block.
+    kernel, the grid's axes after its leading two (batch, head block),
+    the index maps of a block on the query side (``"q"``), on the key
+    side (``"k"``) and of a query block of ``lse`` / ``dvec`` on lanes
+    (``"row"``), and the scalar-prefetched operands.  A row of the grid
+    is a query block, or ``by_keys`` (the dK/dV kernel) a key block.
 
     * a causal call: (steps,) over ``_walk_table``, whose columns are
       prefetched and read by every index map;
@@ -541,38 +676,49 @@ def _walk(causal, tq, tk, bq, bk, window, by_keys=False, group=1):
       kernel skips the step; the block is already there);
     * any other call: the dense (rows, blocks).
 
-    ``group`` query heads share a K/V head (``head = kv·group + g``, so
-    row ``bh`` of the (B·H, T, Dh) arrays reads row ``bh // group`` of
-    the (B·KV, T, Dh) ones).  The leading axis of a query-row grid is
-    batch·head and its key-side maps read ``b // group``; of a
-    ``by_keys`` grid it is batch·K/V head, a key block's row walks the
+    A block's position is (row ``n``, column block ``c``) of the
+    leading two axes.  ``group`` query heads share a K/V head (``head =
+    kv·group + g``; transposed operands alone, so row ``n`` of the
+    (B·H, T, Dh) arrays reads row ``n // group`` of the (B·KV, T, Dh)
+    ones).  The leading axes of a query-row grid are the query side's;
+    of a ``by_keys`` grid the K/V side's, a key block's row walks the
     group's heads in turn (the table's ``g`` column, or one more axis
-    between the rows and their steps) and the query-side maps read head
-    ``b·group + g``: the key block and its accumulators stay put over
-    the group.  ``group`` = 1 builds what it built before there was
-    one: no division, no axis, no column."""
+    between the rows and their steps) and the query-side maps read row
+    ``n·group + g``: the key block and its accumulators stay put over
+    the group.  ``group`` = 1 builds what it built before there was one:
+    no division, no axis, no column."""
     n_q, n_k = tq // bq, tk // bk
     grouped = by_keys and group > 1
 
-    def key_head(b):  # the K/V head of a grid's leading index
-        return b if by_keys or group == 1 else lax.div(b, group)
+    def query_head(n, g):  # of the grid's row n and a group's head g
+        return n * group + g if grouped else n
+
+    def key_head(n):
+        return n if by_keys or group == 1 else lax.div(n, group)
+
+    def maps(position):  # the grid's minor indices -> (qi, kb, g)
+        def q(n, c, *minor):
+            qi, _, g = position(*minor)
+            return query_head(n, g), qi, c
+
+        def k(n, c, *minor):
+            return key_head(n), position(*minor)[1], c
+
+        def row(n, c, *minor):
+            qi, _, g = position(*minor)
+            return query_head(n, g), c, 0, qi
+
+        return {"q": q, "k": k, "row": row}
 
     if causal and window is None:
         table = _walk_table(tq, tk, bq, bk, by_keys, group if by_keys else 1)
         kinds = {step[4] for step in table}
-
-        def q_head(b, s, g):  # ``g``: the table's fourth column, if any
-            return b * group + g[0][s] if grouped else b
-
-        at = {"q": lambda b, s, qi, kb, flags, *g: (q_head(b, s, g), qi[s],
-                                                    0),
-              "k": lambda b, s, qi, kb, flags, *g: (key_head(b), kb[s], 0),
-              "row": lambda b, s, qi, kb, flags, *g: (q_head(b, s, g), 0,
-                                                      qi[s])}
         prefetch = tuple(
             jnp.asarray(column, jnp.int32) for column in zip(*(
                 (qi, kb, first * _FIRST | last * _LAST | masked * _MASKED,
                  *g) for qi, kb, first, last, masked, *g in table)))
+        at = maps(lambda s, qi, kb, flags, *g: (qi[s], kb[s],
+                                                g[0][s] if g else 0))
         return (("table", False in kinds, True in kinds, len(prefetch)),
                 (len(table),), at, prefetch)
     walk, cols = None, n_q if by_keys else n_k
@@ -585,43 +731,42 @@ def _walk(causal, tq, tk, bq, bk, window, by_keys=False, group=1):
         return jnp.minimum(i + j, n_q - 1) if by_keys else jnp.maximum(
             i - (cols - 1) + j, 0)
 
-    def q_of(i, j): return walked(i, j) if by_keys else i
-    def k_of(i, j): return i if by_keys else walked(i, j)
+    def position(i, j, g=0):
+        return ((walked(i, j), i, g) if by_keys else (i, walked(i, j), g))
+
     if grouped:
-        at = {"q": lambda b, i, g, j: (b * group + g, q_of(i, j), 0),
-              "k": lambda b, i, g, j: (b, k_of(i, j), 0),
-              "row": lambda b, i, g, j: (b * group + g, 0, q_of(i, j))}
+        at = maps(lambda i, g, j: position(i, j, g))
         return ("group", walk), (n_k, group, cols), at, ()
-    at = {"q": lambda b, i, j: (b, q_of(i, j), 0),
-          "k": lambda b, i, j: (key_head(b), k_of(i, j), 0),
-          "row": lambda b, i, j: (b, 0, q_of(i, j))}
-    return walk, (n_k if by_keys else n_q, cols), at, ()
+    return walk, (n_k if by_keys else n_q, cols), maps(position), ()
 
 
 def _grid_walk(body, kernel, args, operands, outputs, out_shape, scratch, *,
-               causal, bq, bk, scale, window, interpret, by_keys=False):
+               causal, bq, bk, scale, window, interpret, pack, dh,
+               by_keys=False):
     """One grid-walk kernel, ``flash_<kernel>`` (``window_attn_<kernel>``
     with a window: the names a trace row reads), on the grid ``_walk``
     gives it.  ``operands`` and ``outputs`` name the kind of each block
-    of ``args`` and of the results: ``"q"`` / ``"k"`` a (block, Dh) tile
-    on the query / key side, ``"row"`` a query block of ``lse`` or
-    ``dvec`` on lanes.  The query side's leading dimension is batch·head
-    and the key side's batch·K/V head; their ratio is the group that
-    shares a K/V head, and a ``by_keys`` grid leads with the key
-    side's."""
+    of ``args`` and of the results: ``"q"`` / ``"k"`` a (block, pack·Dh)
+    tile on the query / key side, ``"row"`` a query block of ``lse`` or
+    ``dvec`` on lanes, (pack, block).  The query side's rows and the key
+    side's differ by the group that shares a K/V head (transposed
+    operands); a ``by_keys`` grid leads with the key side's."""
     sides = dict(zip(operands, args))
-    (bh, tq, dh), (bkv, tk, _) = sides["q"].shape, sides["k"].shape
+    width = pack * dh
+    (nq, tq, cq), (nk, tk, ck) = sides["q"].shape, sides["k"].shape
     walk, grid, at, prefetch = _walk(causal, tq, tk, bq, bk, window, by_keys,
-                                     bh // bkv)
-    shape = {"q": (1, bq, dh), "k": (1, bk, dh), "row": (1, 1, bq)}
+                                     nq // nk)
+    shape = {"q": (1, bq, width), "k": (1, bk, width),
+             "row": (1, 1, pack, bq)}
     in_specs, out_specs = ([pl.BlockSpec(shape[x], at[x]) for x in kinds]
                            for kinds in (operands, outputs))
+    lead = (nk, ck // width) if by_keys else (nq, cq // width)
     return pl.pallas_call(
         functools.partial(body, scale=scale, block_q=bq, block_k=bk,
-                          window=window, walk=walk),
+                          window=window, walk=walk, pack=pack),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=(bkv if by_keys else bh, *grid),
+            grid=(*lead, *grid),
             in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
         out_shape=out_shape,
         interpret=interpret,
@@ -641,34 +786,38 @@ def _grid_walk(body, kernel, args, operands, outputs, out_shape, scratch, *,
 #: beside them slow down: 48.28 against 49.88 samples/s on
 #: ``gpt2m-train``, 126.13 against 126.32 on ``gpt2s-train`` (v5e, PR 27)
 _LAUNCHER_STATICS = ("causal", "bq", "bk", "scale", "tile", "interpret",
-                     "window")
+                     "window", "pack", "dh")
 
 
 @functools.partial(jax.jit, static_argnames=_LAUNCHER_STATICS)
 def _flash_fwd_raw(qr, kr, vr, *, causal, bq, bk, scale, tile, interpret,
-                   window=None):
-    """(BH, Tq, D) + (B·KV, Tk, D) in → (out (BH,Tq,D), lse (BH,Tq)) via
-    the fused kernel.  Rectangular Tq ≠ Tk is the ring's half-block hop
+                   pack, dh, window=None):
+    """(N, Tq, C) + (N', Tk, C') in → (out (N, Tq, C), lse (N, C ÷
+    (pack·Dh), pack, Tq)) via the fused kernel: the projected layout, N
+    = B, C = H·Dh, ``pack`` heads a block, or transposed operands, N =
+    B·H (B·KV for K and V), C = Dh, ``pack`` 1.  Rectangular Tq ≠ Tk is the ring's half-block hop
     shape (zigzag schedule); causal requires Tq == Tk (diagonal
     alignment).  ``tile`` (``_causal_tile``) selects the in-kernel causal
     walk, which takes equal head counts."""
-    bh, tq, dh = qr.shape
+    n, tq, c = qr.shape
     tk = kr.shape[1]
+    width = pack * dh
     if causal and tq != tk:
         raise ValueError(f"causal flash needs equal q/k lengths, got "
                          f"{tq} vs {tk}")
     out_shape = [
-        jax.ShapeDtypeStruct((bh, tq, dh), qr.dtype),
-        # (bh, 1, t) layout so the block's last-two dims satisfy the
-        # TPU (8, 128) tiling rule (second-to-last == array dim == 1)
-        jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
+        jax.ShapeDtypeStruct(qr.shape, qr.dtype),
+        # rows on lanes: a block's second-to-last dim is the array's
+        # (``pack``), which satisfies the TPU (8, 128) tiling rule
+        jax.ShapeDtypeStruct((n, c // width, pack, tq), jnp.float32),
     ]
     if tile is not None:
         return pl.pallas_call(
-            functools.partial(_fwd_causal_kernel, scale=scale, tile=tile),
-            grid=(bh,),
-            in_specs=[_whole(tq, dh)] * 3,
-            out_specs=[_whole(tq, dh), _whole_row(tq)],
+            functools.partial(_fwd_causal_kernel, scale=scale, tile=tile,
+                              pack=pack),
+            grid=(n, c // width),
+            in_specs=[_whole(tq, width)] * 3,
+            out_specs=[_whole(tq, width), _whole_row(tq, pack)],
             out_shape=out_shape,
             interpret=interpret,
             name="flash_fwd",
@@ -676,55 +825,80 @@ def _flash_fwd_raw(qr, kr, vr, *, causal, bq, bk, scale, tile, interpret,
     return _grid_walk(
         _fwd_kernel, "fwd", (qr, kr, vr), ["q", "k", "k"], ["q", "row"],
         out_shape,
-        [pltpu.VMEM((bq, dh), jnp.float32),
-         pltpu.VMEM((bq, 128), jnp.float32),
-         pltpu.VMEM((bq, 128), jnp.float32)],
+        [pltpu.VMEM((bq, width), jnp.float32),
+         pltpu.VMEM((pack, bq, 128), jnp.float32),
+         pltpu.VMEM((pack, bq, 128), jnp.float32)],
         causal=causal, bq=bq, bk=bk, scale=scale, window=window,
-        interpret=interpret)
+        interpret=interpret, pack=pack, dh=dh)
 
 
 @functools.partial(jax.jit, static_argnames=_LAUNCHER_STATICS)
-def _flash_bwd_raw(qr, kr, vr, do, lse, dvec, *, causal, bq, bk, scale,
-                   tile, interpret, window=None):
-    bh, tq, dh = qr.shape
-    dq_shape = jax.ShapeDtypeStruct((bh, tq, dh), qr.dtype)
-    dkv_shape = [jax.ShapeDtypeStruct(kr.shape, kr.dtype),
-                 jax.ShapeDtypeStruct(vr.shape, vr.dtype)]
-    operands = (qr, kr, vr, do, lse, dvec)
+def _flash_bwd_raw(qr, kr, vr, do, out, lse, g_lse, *, causal, bq, bk,
+                   scale, tile, interpret, pack, dh, window=None):
+    """dQ, dK, dV of ``_flash_fwd_raw``'s call from dO, its output and
+    ``lse``: the dQ kernel also computes D = rowsum(dO ∘ O) − ``g_lse``
+    (the ``lse`` cotangent, in ``lse``'s layout, or None) and hands it
+    to the dK/dV kernel."""
+    n, tq, c = qr.shape
+    width = pack * dh
+    dq_shapes = [jax.ShapeDtypeStruct(qr.shape, qr.dtype),
+                 jax.ShapeDtypeStruct(lse.shape, jnp.float32)]
+    dkv_shapes = [jax.ShapeDtypeStruct(kr.shape, kr.dtype),
+                  jax.ShapeDtypeStruct(vr.shape, vr.dtype)]
+    corrected = g_lse is not None
+    dq_operands = (qr, kr, vr, do, out, lse) + ((g_lse,) if corrected
+                                                 else ())
 
     if tile is not None:
-        dq = pl.pallas_call(
-            functools.partial(_bwd_dq_causal_kernel, scale=scale, tile=tile),
-            grid=(bh,),
-            in_specs=[_whole(tq, dh)] * 4 + [_whole_row(tq)] * 2,
-            out_specs=_whole(tq, dh),
-            out_shape=dq_shape,
+        whole, row = _whole(tq, width), _whole_row(tq, pack)
+        dq, dvec = pl.pallas_call(
+            functools.partial(_bwd_dq_causal_kernel, scale=scale, tile=tile,
+                              pack=pack, corrected=corrected),
+            grid=(n, c // width),
+            in_specs=[whole] * 5 + [row] * (1 + corrected),
+            out_specs=[whole, row],
+            out_shape=dq_shapes,
             interpret=interpret,
             name="flash_bwd_dq",
-        )(*operands)
+        )(*dq_operands)
         dk, dv = pl.pallas_call(
             functools.partial(_bwd_dkv_causal_kernel, scale=scale,
-                              tile=tile),
-            grid=(bh,),
-            in_specs=[_whole(tq, dh)] * 4 + [_whole_row(tq)] * 2,
-            out_specs=[_whole(tq, dh)] * 2,
-            out_shape=dkv_shape,
+                              tile=tile, pack=pack),
+            grid=(n, c // width),
+            in_specs=[whole] * 4 + [row] * 2,
+            out_specs=[whole] * 2,
+            out_shape=dkv_shapes,
             interpret=interpret,
             name="flash_bwd_dkv",
         )(kr, vr, qr, do, lse, dvec)
         return dq, dk, dv
 
     statics = dict(causal=causal, bq=bq, bk=bk, scale=scale, window=window,
-                   interpret=interpret)
+                   interpret=interpret, pack=pack, dh=dh)
+    dq, dvec = _grid_walk(
+        functools.partial(_bwd_dq_kernel, corrected=corrected), "bwd_dq",
+        dq_operands, ["q", "k", "k", "q", "q", "row"] + ["row"] * corrected,
+        ["q", "row"], dq_shapes,
+        [pltpu.VMEM((bq, width), jnp.float32),
+         pltpu.VMEM((pack, bq, 128), jnp.float32)], **statics)
+    dk, dv = _grid_walk(
+        _bwd_dkv_kernel, "bwd_dkv", (kr, vr, qr, do, lse, dvec),
+        ["k", "k", "q", "q", "row", "row"], ["k", "k"], dkv_shapes,
+        [pltpu.VMEM((bk, width), jnp.float32),
+         pltpu.VMEM((bk, width), jnp.float32)], by_keys=True, **statics)
+    return dq, dk, dv
+
+    statics = dict(causal=causal, bq=bq, bk=bk, scale=scale, window=window,
+                   interpret=interpret, pack=pack, dh=dh)
     dq, = _grid_walk(
         _bwd_dq_kernel, "bwd_dq", operands,
         ["q", "k", "k", "q", "row", "row"], ["q"], [dq_shape],
-        [pltpu.VMEM((bq, dh), jnp.float32)], **statics)
+        [pltpu.VMEM((bq, width), jnp.float32)], **statics)
     dk, dv = _grid_walk(
         _bwd_dkv_kernel, "bwd_dkv", (kr, vr, qr, do, lse, dvec),
         ["k", "k", "q", "q", "row", "row"], ["k", "k"], dkv_shape,
-        [pltpu.VMEM((bk, dh), jnp.float32),
-         pltpu.VMEM((bk, dh), jnp.float32)], by_keys=True, **statics)
+        [pltpu.VMEM((bk, width), jnp.float32),
+         pltpu.VMEM((bk, width), jnp.float32)], by_keys=True, **statics)
     return dq, dk, dv
 
 
@@ -732,36 +906,58 @@ def _flash_bwd_raw(qr, kr, vr, do, lse, dvec, *, causal, bq, bk, scale,
 # public API
 # ---------------------------------------------------------------------------
 
-def _to_bh(x):
+def _pack(dh: int, h: int, kv: int):
+    """Heads to a 128-lane block of the projected layout, read from the
+    shapes: two of 64, where there are as many K/V heads as query heads
+    and an even count of them; None for any other shape, whose kernels
+    take transposed operands.  Heads of 128 stay transposed: read in
+    place, their forward and dK/dV kernels ran 3–4 % slower (rows of
+    256 bytes), and rotary embedding's slices of a head cost XLA
+    relayouts of q and k in place of the transposes (v5e, PERF.md §6)."""
+    if dh == 64 and h == kv and h % 2 == 0:
+        return 2
+    return None
+
+
+def _to_kernel(x, pack):
+    """(B, T, heads, Dh) as the kernels take it: (B, T, heads·Dh), a
+    reshape, in place; (B·heads, T, Dh), a transpose, where ``pack`` is
+    None."""
     b, t, h, dh = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b * h, t, dh)
+    if pack is None:
+        return x.transpose(0, 2, 1, 3).reshape(b * h, t, dh)
+    return x.reshape(b, t, h * dh)
 
 
-def _from_bh(x, b, h):
-    bh, t, dh = x.shape
-    return x.reshape(b, h, t, dh).transpose(0, 2, 1, 3)
+def _from_kernel(x, b: int, dh: int, pack):
+    """A kernel's operand or result back to (B, T, heads, Dh)."""
+    if pack is None:
+        rows, t, _ = x.shape
+        return x.reshape(b, rows // b, t, dh).transpose(0, 2, 1, 3)
+    return x.reshape(b, x.shape[1], -1, dh)
 
 
-def _kv_to_bh(k, v, group: int, tile):
-    """K and V as the kernels take them: (B·KV, T, Dh), their own head
-    count, for the grid walk; repeated to the ``group`` query heads of
-    each for the in-kernel causal walk (``tile``), whose whole-sequence
-    kernels take equal head counts."""
-    kr, vr = _to_bh(k), _to_bh(v)
+def _operands(q, k, v, pack, tile):
+    """q, k, v as the kernels take them.  The grid walk reads K and V at
+    their own head count; the in-kernel causal walk's whole-sequence
+    kernels take equal head counts, so for it alone K and V are repeated
+    to the query heads."""
+    group = q.shape[2] // k.shape[2]
     if group > 1 and tile is not None:
-        kr, vr = jnp.repeat(kr, group, axis=0), jnp.repeat(vr, group, axis=0)
-    return kr, vr
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    return tuple(_to_kernel(x, pack) for x in (q, k, v))
 
 
-def _kv_from_bh(x, b: int, kv: int):
-    """dK or dV (rows, T, Dh) → (B, T, KV, Dh).  Rows at the query head
-    count (the in-kernel walk ran on repeated heads) are first summed
-    over each group, in float32: the repeat's transpose."""
-    rows, t, dh = x.shape
-    if rows != b * kv:
-        x = x.reshape(b * kv, rows // (b * kv), t, dh).astype(
-            jnp.float32).sum(axis=1)
-    return _from_bh(x, b, kv)
+def _kv_from_kernel(x, b: int, kv: int, dh: int, pack):
+    """dK or dV from the kernels → (B, T, KV, Dh).  At the query heads'
+    count (the in-kernel walk ran on repeated heads) they are first
+    summed over each group, in float32: the repeat's transpose."""
+    x = _from_kernel(x, b, dh, pack)
+    heads = x.shape[2]
+    if heads != kv:
+        x = x.reshape(b, x.shape[1], kv, heads // kv, dh).astype(
+            jnp.float32).sum(axis=3)
+    return x
 
 
 def _tileable(t: int) -> bool:
@@ -845,6 +1041,16 @@ def flash_attention(q, k, v, causal: bool = False, block_q=None,
     """Pallas flash attention; q (B, T, H, Dh), k/v (B, T, KV, Dh) →
     (B, T, H, Dh).
 
+    Heads of 64, as many K/V heads as query heads and an even count of
+    them (``_pack``), are read and written in the projected layout, (B,
+    T, heads·64): merging the last two dimensions is a reshape, and
+    nothing around a kernel transposes; a block is 128 lanes of columns,
+    two heads told apart by lane masks.  Every other shape — heads of
+    128 among them, and heads of 64 under a group or an odd count of
+    them — runs the same kernels on (B·heads, T, Dh) transposes
+    (``flash.layout_native_kernels`` / ``flash.layout_transposed_kernels``
+    count the two).
+
     KV divides H: query head ``kv·G + g`` reads K/V head ``kv`` (G = H /
     KV, read from the shapes; ``MultiHeadAttention._expand_kv``'s order,
     which the weights and the decode cache assume).  The grid walk's
@@ -852,11 +1058,10 @@ def flash_attention(q, k, v, causal: bool = False, block_q=None,
     map is ``head // G``, and dK/dV runs over the K/V heads, a key
     block's row walking its query blocks once for each head of the
     group into one float32 accumulator — so nothing on the K/V side is
-    ever H heads wide: not the transposes around the kernels, not dK /
-    dV, not the K and V a checkpoint keeps for the backward.  The
-    in-kernel causal walk (whole-sequence operands) takes equal head
-    counts: for it alone K and V are repeated here, after the transpose,
-    and dK / dV summed over the group after the kernel
+    ever H heads wide: not dK / dV, not the K and V a checkpoint keeps
+    for the backward.  The in-kernel causal walk (whole-sequence
+    operands) takes equal head counts: for it alone K and V are repeated
+    here and dK / dV summed over the group after the kernel
     (``flash.kv_expanded_kernels`` counts those, ``flash.kv_native_kernels``
     the others).  G = 1 is the program it always was.
 
@@ -887,25 +1092,35 @@ def flash_attention(q, k, v, causal: bool = False, block_q=None,
     return out
 
 
+def _plan(q, k, causal, block_q, block_k, window, kernels: int):
+    """What a call's kernels are built with, counted once for each of
+    its ``kernels``: ``(bq, bk, tile, pack)``."""
+    b, t, h, dh = q.shape
+    kv = k.shape[2]
+    bq, bk, tile = _blocks(q, k, causal, block_q, block_k, window)
+    pack = _pack(dh, h, kv)
+    _count_tiles(causal, t, k.shape[1], bq, bk, tile, kernels=kernels,
+                 window=window, group=h // kv, pack=pack)
+    return bq, bk, tile, pack
+
+
 def _vjp_fwd(q, k, v, causal, block_q, block_k, window=None):
     b, t, h, dh = q.shape
-    bq, bk, tile = _blocks(q, k, causal, block_q, block_k, window)
-    group = h // k.shape[2]
-    _count_tiles(causal, t, k.shape[1], bq, bk, tile, kernels=1,
-                 window=window, group=group)
-    scale = 1.0 / math.sqrt(dh)
-    # "layout": the (B, T, H, Dh) <-> (BH, T, Dh) transposes around the
-    # kernels, named so a trace can charge their copies to attention
+    bq, bk, tile, pack = _plan(q, k, causal, block_q, block_k, window, 1)
+    # "layout": what is left around the kernels (the fallback's
+    # transposes, the in-kernel walk's K/V repeat), named so a trace can
+    # charge its copies to attention; in place it is reshapes alone
     with jax.named_scope("layout"):
-        qr, (kr, vr) = _to_bh(q), _kv_to_bh(k, v, group, tile)
+        qr, kr, vr = _operands(q, k, v, pack, tile)
     out, lse = _flash_fwd_raw(qr, kr, vr, causal=causal, bq=bq, bk=bk,
-                              scale=scale, tile=tile,
-                              interpret=_interpret(), window=window)
+                              scale=1.0 / math.sqrt(dh), tile=tile,
+                              interpret=_interpret(), pack=pack or 1, dh=dh,
+                              window=window)
     # what a checkpoint around this call keeps (``models.remat``): the
     # recomputed forward then needs no kernel; outside one, nothing
     out, lse = name_kernel_outputs(out, lse)
     with jax.named_scope("layout"):
-        out_bthd = _from_bh(out, b, h)
+        out_bthd = _from_kernel(out, b, dh, pack)
     return out_bthd, (q, k, v, out, lse)
 
 
@@ -913,31 +1128,26 @@ def _bwd_impl(causal, block_q, block_k, res, g_out, g_lse=None,
               window=None):
     """Shared backward: ``g_lse`` (the lse cotangent, (B, H, T)) folds
     into the softmax-grad correction term — ∂lse_i/∂s_ij = P_ij lands
-    exactly where D_i enters dS = P∘(dP − D), so ``dvec − g_lse`` covers
-    it with the kernels unchanged."""
-    q, k, v, out_bh, lse = res
+    exactly where D_i enters dS = P∘(dP − D), so the dQ kernel's
+    ``D − g_lse`` covers it with the kernels' walks unchanged."""
+    q, k, v, out, lse = res
     b, t, h, dh = q.shape
-    kv = k.shape[2]
-    bq, bk, tile = _blocks(q, k, causal, block_q, block_k, window)
-    _count_tiles(causal, t, k.shape[1], bq, bk, tile, kernels=2,
-                 window=window, group=h // kv)
-    scale = 1.0 / math.sqrt(dh)
+    bq, bk, tile, pack = _plan(q, k, causal, block_q, block_k, window, 2)
     with jax.named_scope("layout"):
-        do = _to_bh(g_out.astype(q.dtype))
-    # D_i = rowsum(dO_i ∘ O_i) — the softmax-grad correction term (f32)
-    dvec = jnp.sum(do.astype(jnp.float32) * out_bh.astype(jnp.float32),
-                   axis=-1)[:, None, :]
+        do = _to_kernel(g_out.astype(q.dtype), pack)
+        qr, kr, vr = _operands(q, k, v, pack, tile)
     if g_lse is not None:
-        dvec = dvec - g_lse.astype(jnp.float32).reshape(b * h, 1, t)
+        g_lse = g_lse.astype(jnp.float32).reshape(lse.shape)
+    dq, dk, dv = _flash_bwd_raw(qr, kr, vr, do, out, lse, g_lse,
+                                causal=causal, bq=bq, bk=bk,
+                                scale=1.0 / math.sqrt(dh), tile=tile,
+                                interpret=_interpret(), pack=pack or 1, dh=dh,
+                                window=window)
+    kv = k.shape[2]
     with jax.named_scope("layout"):
-        qr, (kr, vr) = _to_bh(q), _kv_to_bh(k, v, h // kv, tile)
-    dq, dk, dv = _flash_bwd_raw(qr, kr, vr, do, lse, dvec, causal=causal,
-                                bq=bq, bk=bk, scale=scale, tile=tile,
-                                interpret=_interpret(), window=window)
-    with jax.named_scope("layout"):
-        return (_from_bh(dq, b, h).astype(q.dtype),
-                _kv_from_bh(dk, b, kv).astype(k.dtype),
-                _kv_from_bh(dv, b, kv).astype(v.dtype))
+        return (_from_kernel(dq, b, dh, pack).astype(q.dtype),
+                _kv_from_kernel(dk, b, kv, dh, pack).astype(k.dtype),
+                _kv_from_kernel(dv, b, kv, dh, pack).astype(v.dtype))
 
 
 def _vjp_bwd(causal, block_q, block_k, window, res, g):
@@ -974,7 +1184,7 @@ def flash_attention_lse(q, k, v, causal: bool = False, block_q=None,
 def _vjp_lse_fwd(q, k, v, causal, block_q, block_k):
     out, res = _vjp_fwd(q, k, v, causal, block_q, block_k)
     b, t, h, dh = q.shape
-    lse = res[4].reshape(b, h, t)  # (BH, 1, T) -> (B, H, T), f32
+    lse = res[4].reshape(b, h, t)  # rows of (B, H ÷ pack, pack, T), f32
     return (out, lse), res
 
 

@@ -341,23 +341,39 @@ def _pallas_calls(fn, *args) -> dict:
         if e.primitive.name == "pallas_call"}
 
 
-def _grid_walk(bh, tq, tk, dh, bq, bk, steps=None, group=1):
+def _layout(b, kv, group, dh):
+    """How the kernels lay out (b, T, kv·group, dh) queries over kv K/V
+    heads: ``(query side's leading axes, K/V side's, block lanes,
+    pack)``.  In place — Dh = 64 with equal and even head counts — the
+    arrays are (B, T, heads·64) and the grid leads with (batch, head
+    pair), two heads a 128-lane block; any other shape runs on (B·heads,
+    T, Dh) transposes, one head a block."""
+    heads = kv * group
+    if dh == 64 and group == 1 and heads % 2 == 0:
+        return (b, heads // 2), (b, kv // 2), 128, 2
+    return (b * heads, 1), (b * kv, 1), dh, 1
+
+
+def _grid_walk(kv, tq, tk, dh, bq, bk, steps=None, group=1, with_lse=False):
     """What the three grid-walk kernels are built with, one block of each
-    operand a step: a (bh, q blocks, k blocks) grid, the dK/dV kernel's
-    the other way round (PR 26's tree); for a causal call a (bh,
-    ``steps``) grid, the block pairs with work alone.  ``bh`` counts the
-    K/V heads: with ``group`` query heads to each, the forward and dQ
-    lead with ``bh * group`` rows, and dK/dV stays at ``bh``, a key
-    block's row ``group`` times as long (one axis more off the table)."""
-    qb, kb, row = (1, bq, dh), (1, bk, dh), (1, 1, bq)
-    heads = bh * group
-    by_q = (heads, steps) if steps else (heads, tq // bq, tk // bk)
-    by_k = (bh, steps * group) if steps else (
-        (bh, tk // bk, tq // bq) if group == 1
-        else (bh, tk // bk, group, tq // bq))
+    operand a step: a (batch, head, q blocks, k blocks) grid, the dK/dV
+    kernel's the other way round (PR 26's tree); for a causal call a
+    (batch, head, ``steps``) grid, the block pairs with work alone.
+    ``kv`` counts the K/V heads: with ``group`` query heads to each, the
+    forward and dQ lead with ``kv * group`` heads, and dK/dV stays at
+    ``kv``, a key block's row ``group`` times as long (one axis more off
+    the table).  A head is a head block of ``_layout``'s.  dQ also reads
+    O (and ``with_lse`` the ``lse`` cotangent) and writes D for dK/dV."""
+    by_q_lead, by_k_lead, width, pack = _layout(1, kv, group, dh)
+    qb, kb, row = (1, bq, width), (1, bk, width), (1, 1, pack, bq)
+    by_q = (*by_q_lead, steps) if steps else (*by_q_lead, tq // bq, tk // bk)
+    by_k = (*by_k_lead, steps * group) if steps else (
+        (*by_k_lead, tk // bk, tq // bq) if group == 1
+        else (*by_k_lead, tk // bk, group, tq // bq))
     return {
         "flash_fwd": (by_q, [qb, kb, kb, qb, row]),
-        "flash_bwd_dq": (by_q, [qb, kb, kb, qb, row, row, qb]),
+        "flash_bwd_dq": (by_q, [qb, kb, kb, qb, qb, row] + [row] * with_lse
+                         + [qb, row]),
         "flash_bwd_dkv": (by_k, [kb, kb, qb, qb, row, row, kb, kb]),
     }
 
@@ -372,15 +388,19 @@ def _grid_walk(bh, tq, tk, dh, bq, bk, steps=None, group=1):
     (128, 128, 64, True, 128, 128, 1, 1),       # causal, one tile: nothing to skip
     (8192, 8192, 128, True, 512, 512, 136, 6),  # Laguna's: 48 heads over 8
     (384, 384, 32, False, 128, 128, None, 4),   # non-causal, 4 heads a K/V head
+    (384, 384, 64, False, 128, 128, None, 2),   # head 64 grouped: transposed
 ], ids=["noncausal", "rectangular", "noncausal-384", "causal-4096",
         "causal-4096-dh128", "causal-8192-dh128", "causal-128",
-        "causal-8192-dh128-group6", "noncausal-384-group4"])
+        "causal-8192-dh128-group6", "noncausal-384-group4",
+        "noncausal-384-dh64-group2"])
 def test_other_calls_keep_the_grid_walk(tq, tk, dh, causal, bq, bk, steps,
                                         group):
-    """Non-causal and rectangular calls build the ``pallas_call``s they
-    built before: same dense grids, same blocks.  Over-budget causal
-    calls keep the blocks, on a grid of the pairs with work alone.  K/V
-    heads shared by a group keep their own count in all three."""
+    """Non-causal and rectangular calls keep the dense grids and blocks;
+    over-budget causal calls keep the blocks, on a grid of the pairs
+    with work alone.  K/V heads shared by a group keep their own count
+    in all three.  Heads of 64 go two to a 128-lane block of the
+    projected layout; heads of 32 or 128, or of 64 under a group, one to
+    a block of transposed operands."""
     from distkeras_tpu.ops.pallas_attention import flash_attention_lse
     q = jnp.ones((1, tq, 2 * group, dh), jnp.bfloat16)
     kv = jnp.ones((1, tk, 2, dh), jnp.bfloat16)
@@ -390,7 +410,7 @@ def test_other_calls_keep_the_grid_walk(tq, tk, dh, causal, bq, bk, steps,
         return jnp.sum(o.astype(jnp.float32)) + jnp.sum(lse)
 
     assert _pallas_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) \
-        == _grid_walk(2, tq, tk, dh, bq, bk, steps, group)
+        == _grid_walk(2, tq, tk, dh, bq, bk, steps, group, with_lse=True)
 
 
 def test_explicit_blocks_keep_the_grid_walk_and_default_takes_the_kernel_walk():
@@ -403,15 +423,17 @@ def test_explicit_blocks_keep_the_grid_walk_and_default_takes_the_kernel_walk():
     grad = lambda *blocks: jax.grad(loss(*blocks), argnums=(0, 1, 2))  # noqa
     assert _pallas_calls(grad(256, 256), q, q, q) \
         == _grid_walk(2, 1024, 1024, 64, 256, 256, steps=10)
-    # four query heads over the two K/V heads: dK/dV's rows are twice as long
+    # four query heads over the two K/V heads: dK/dV's rows are twice as
+    # long (head 64 under a group: on transposed operands)
     q4 = jnp.ones((1, 1024, 4, 64), jnp.bfloat16)
     assert _pallas_calls(grad(256, 256), q4, q, q) \
         == _grid_walk(2, 1024, 1024, 64, 256, 256, steps=10, group=2)
-    whole, row = (1, 1024, 64), (1, 1, 1024)
+    # the two heads of 64 are one 128-lane block of the projected layout
+    whole, row = (1, 1024, 128), (1, 1, 2, 1024)
     assert _pallas_calls(grad(), q, q, q) == {
-        "flash_fwd": ((2,), [whole] * 4 + [row]),
-        "flash_bwd_dq": ((2,), [whole] * 4 + [row] * 2 + [whole]),
-        "flash_bwd_dkv": ((2,), [whole] * 4 + [row] * 2 + [whole] * 2),
+        "flash_fwd": ((1, 1), [whole] * 4 + [row]),
+        "flash_bwd_dq": ((1, 1), [whole] * 5 + [row] + [whole, row]),
+        "flash_bwd_dkv": ((1, 1), [whole] * 4 + [row] * 2 + [whole] * 2),
     }
 
 
@@ -592,15 +614,19 @@ def _kv_kernel_counts():
                  for kind in ("native", "expanded"))
 
 
+@pytest.mark.parametrize("dh", [32, 64])
 @pytest.mark.parametrize("walk", list(GROUP_WALKS))
-def test_registry_counts_the_kernels_on_native_and_on_repeated_heads(walk):
+def test_registry_counts_the_kernels_on_native_and_on_repeated_heads(walk,
+                                                                      dh):
     """``flash.kv_native_kernels`` / ``flash.kv_expanded_kernels``, once a
     kernel put into a program: the grid walk's three read K/V at their
     own head count; the in-kernel causal walk's three run on K/V
     repeated inside ``flash_attention`` (and keep the whole-sequence
-    blocks, a grid step a query head).  Equal head counts add nothing."""
+    blocks, a grid step a query head: a (batch·head, 1) grid, heads
+    shared by a group being transposed at any Dh).  Equal head counts
+    add nothing."""
     t, args = GROUP_WALKS[walk]
-    q, k, v, _ = _grouped(2, t, jnp.bfloat16)
+    q, k, v, _ = _grouped(2, t, jnp.bfloat16, dh=dh)
     grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
         q, k, v, *args).astype(jnp.float32)), argnums=(0, 1, 2))
     before = _kv_kernel_counts()
@@ -608,11 +634,11 @@ def test_registry_counts_the_kernels_on_native_and_on_repeated_heads(walk):
     native, expanded = (a - b for a, b in zip(_kv_kernel_counts(), before))
     if walk == "in-kernel-causal":
         assert (native, expanded) == (0, 3)
-        assert {grid for grid, _ in calls.values()} == {(2 * 4,)}
+        assert {grid for grid, _ in calls.values()} == {(2 * 4, 1)}
     else:
         assert (native, expanded) == (3, 0)
         assert calls[("window_attn" if args[3] else "flash")
-                     + "_bwd_dkv"][0][0] == 2 * 2
+                     + "_bwd_dkv"][0][:2] == (2 * 2, 1)
     jax.make_jaxpr(grad)(q, q, q)
     assert _kv_kernel_counts() == tuple(
         b + n for b, n in zip(before, (native, expanded)))
@@ -631,16 +657,18 @@ def _index_maps(fn, *args) -> dict:
         if e.primitive.name == "pallas_call"}
 
 
+@pytest.mark.parametrize("dh", [32, 64], ids=["transposed", "in-place"])
 @pytest.mark.parametrize("walk", list(GROUP_WALKS))
-def test_equal_head_counts_build_the_programs_they_always_built(walk):
+def test_equal_head_counts_build_the_programs_they_always_built(walk, dh):
     """What keeps the GPT-2 cells and the ring's hops still: with as many
     K/V heads as query heads no index map divides or multiplies a head
-    index, the table is three scalar-prefetched columns and no grid has
-    a group axis (the grids themselves are pinned above).  With a group
-    the forward and dQ divide (``head // G``) and dK/dV multiplies
-    (``kv·G + g``), on a fourth column or a fourth axis."""
+    index (transposed at Dh = 32, in place at 64), the table is three
+    scalar-prefetched columns and no grid has a group axis (the grids
+    themselves are pinned above).  With a group the forward and dQ
+    divide (``head // G``, the row of transposed operands) and dK/dV
+    multiplies (``kv·G + g``), on a fourth column or a fifth axis."""
     t, args = GROUP_WALKS[walk]
-    q, k, v, _ = _grouped(2, t, jnp.bfloat16)
+    q, k, v, _ = _grouped(2, t, jnp.bfloat16, dh=dh)
     grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
         q, k, v, *args).astype(jnp.float32)), argnums=(0, 1, 2))
     table = walk == "causal-table"
@@ -657,4 +685,156 @@ def test_equal_head_counts_build_the_programs_they_always_built(walk):
         assert ("mul" if dkv else "div") in primitives, (name, primitives)
     assert len(_pallas_calls(grad, q, k, v)[
         ("window_attn" if args[3] else "flash") + "_bwd_dkv"][0]) == (
-            2 if table else 4)
+            3 if table else 5)
+
+
+# ---------------------------------------------------------------------------
+# the projected layout: blocks of (B, T, heads·Dh) taken in place
+# ---------------------------------------------------------------------------
+
+def _layout_counts():
+    from distkeras_tpu.obs.registry import default_registry
+    return tuple(
+        default_registry().counter(f"flash.layout_{kind}_kernels").value
+        for kind in ("native", "transposed"))
+
+
+def _check_against_dense(q, k, v, args, dtype):
+    """Output and the three gradients of ``flash_attention(q, k, v,
+    *args)`` against ``dot_product_attention`` on K/V repeated to the
+    query heads, in float32: to the file's float32 tolerances, or its
+    bf16 ones with bf16 operands.  Returns the change in the two layout
+    counters over the call's trace."""
+    causal, window = args[0], args[3]
+    group = q.shape[2] // k.shape[2]
+    w = jnp.asarray(np.random.default_rng(1).normal(size=q.shape),
+                    jnp.float32)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v).astype(
+            jnp.float32) * w)
+
+    def dense(q, k, v):
+        return dot_product_attention(
+            q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
+            causal=causal, window=window)
+
+    xs = tuple(a.astype(dtype) for a in (q, k, v))
+    val, grad = (2e-5, 5e-4) if dtype == jnp.float32 else (0.06, 0.15)
+    before = _layout_counts()
+    out, g = jax.value_and_grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, *args)), argnums=(0, 1, 2))(*xs)
+    counted = tuple(a - b for a, b in zip(_layout_counts(), before))
+    out_r, g_r = jax.value_and_grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    o = flash_attention(*xs, *args)
+    assert o.dtype == dtype
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(dense(q, k, v)), rtol=val,
+                               atol=val)
+    for name, a, b in zip(("dq", "dk", "dv"), g, g_r):
+        assert a.shape == b.shape and a.dtype == dtype, name
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
+                                   rtol=grad, atol=max(grad / 10, 5e-5),
+                                   err_msg=name)
+    return counted
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("walk", list(GROUP_WALKS))
+@pytest.mark.parametrize("dh,group,counted", [
+    (64, 1, (3, 0)), (128, 1, (0, 3)), (128, 2, (0, 3))],
+    ids=["dh64-in-place", "dh128", "dh128-group2"])
+def test_each_layout_matches_dense(dh, group, counted, walk, dtype):
+    """Heads of 64 two to a 128-lane block in place; heads of 128, with
+    and without K/V heads shared by a group, on transposed operands: on
+    each walk (the in-kernel causal walk, the causal table, the window's
+    band, the dense grid) the output and dq, dk, dv against dense
+    attention, and the kernels counted in their layout."""
+    t, args = GROUP_WALKS[walk]
+    q, k, v, _ = _grouped(group, t, jnp.float32, dh=dh, seed=dh + group)
+    assert _check_against_dense(q, k, v, args, dtype) == counted
+
+
+@pytest.mark.parametrize("heads,kv,dh", [(2, 2, 96), (3, 3, 64), (4, 2, 64)],
+                         ids=["dh96", "dh64-odd-heads", "dh64-group2"])
+def test_shapes_the_blocks_do_not_fit_run_on_transposed_operands(heads, kv,
+                                                                 dh):
+    """Dh not 64, heads of 64 an odd count of them, heads of 64 under a
+    group: the same kernels on (B·heads, T, Dh) transposes, equal to
+    dense attention and counted transposed."""
+    rng = np.random.default_rng(heads + dh)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, 256, n, dh)), jnp.float32)
+               for n in (heads, kv, kv))
+    assert _check_against_dense(q, k, v, (True, None, None, None),
+                                jnp.float32) == (0, 3)
+
+
+def _blocks_at(fn, args, point) -> dict:
+    """{kernel name: the block index of each operand and output at a grid
+    index} of a function's ``pallas_call``s (grids without a table)."""
+    found = {}
+    for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+        if e.primitive.name == "pallas_call":
+            name = e.params["name"]
+            found[name] = [
+                tuple(int(i) for i in jax.core.eval_jaxpr(
+                    m.index_map_jaxpr.jaxpr, m.index_map_jaxpr.consts,
+                    *(jnp.int32(i) for i in point[name])))
+                for m in e.params["grid_mapping"].block_mappings]
+    return found
+
+
+@pytest.mark.parametrize("dh,heads,kv", [(64, 6, 6), (128, 4, 2)],
+                         ids=["dh64-in-place", "dh128-group2-transposed"])
+def test_blocks_are_column_blocks_of_the_projected_layout(dh, heads, kv):
+    """The index maps of the dense grid (batch, head, q blocks, k
+    blocks).  In place a query-side block is (batch, query block, head
+    pair), a K/V block (batch, key block, head pair), a row of ``lse`` /
+    ``dvec`` (batch, head pair, 0, query block).  On transposed operands
+    the row is batch·head (``// G`` on the K/V side) and the column block
+    0; dK/dV's grid (batch·K/V head, 0, key block, g, query block) reads
+    query row ``kv·G + g``."""
+    t, args = GROUP_WALKS["dense-noncausal"]
+    rng = np.random.default_rng(0)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, t, n, dh)), jnp.bfloat16)
+               for n in (heads, kv, kv))
+    grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, *args).astype(jnp.float32)), argnums=(0, 1, 2))
+    if dh == 64:  # (batch 1, head pair 2, q block 1, k block 0)
+        point = (1, 2, 1, 0)
+        qb, kb, row = (1, 1, 2), (1, 0, 2), (1, 2, 0, 1)
+        dkv, dkv_k, dkv_q, dkv_row = (1, 2, 0, 1), (1, 0, 2), (1, 1, 2), (
+            1, 2, 0, 1)
+    else:  # batch·head 7 reads K/V row 3; K/V row 3, g 1 reads query row 7
+        point = (7, 0, 1, 0)
+        qb, kb, row = (7, 1, 0), (3, 0, 0), (7, 0, 0, 1)
+        dkv, dkv_k, dkv_q, dkv_row = (3, 0, 0, 1, 1), (3, 0, 0), (7, 1, 0), (
+            7, 0, 0, 1)
+    assert _blocks_at(grad, (q, k, v), {
+        "flash_fwd": point, "flash_bwd_dq": point, "flash_bwd_dkv": dkv}) == {
+        "flash_fwd": [qb, kb, kb, qb, row],
+        "flash_bwd_dq": [qb, kb, kb, qb, qb, row, qb, row],
+        "flash_bwd_dkv": [dkv_k] * 2 + [dkv_q] * 2 + [dkv_row] * 2
+        + [dkv_k] * 2,
+    }
+
+
+def test_gpt2_small_attention_has_no_transpose_around_the_kernels():
+    """At GPT-2 small's shapes (12 heads of 64, T = 1,024) the gradient
+    of a flash ``MultiHeadAttention`` holds no transpose of an
+    activation — the two left are the weight matrices' (D, D) and
+    (3D, D) — and its three kernels count in place."""
+    from distkeras_tpu.ops.attention import MultiHeadAttention
+    layer = MultiHeadAttention(12, causal=True, impl="flash")
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), (1024, 768))[0])
+    x = jax.ShapeDtypeStruct((2, 1024, 768), jnp.bfloat16)
+    grad = jax.grad(lambda p, x: jnp.sum(layer.apply(p, {}, x)[0].astype(
+        jnp.float32)), argnums=(0, 1))
+    before = _layout_counts()
+    jaxpr = jax.make_jaxpr(grad)(params, x)
+    assert tuple(a - b for a, b in zip(_layout_counts(), before)) == (3, 0)
+    transposed = [e.invars[0].aval.shape for e in _eqns(jaxpr.jaxpr)
+                  if e.primitive.name == "transpose"]
+    assert sorted(transposed) == [(768, 768), (2304, 768)], transposed
