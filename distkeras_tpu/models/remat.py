@@ -44,10 +44,12 @@ from ..obs.spans import default_tracer
 
 #: the names the forward kernels' outputs carry out of their custom VJPs'
 #: forward rules, by kernel family (``pallas_attention._vjp_fwd``: the
-#: output and its row statistics; ``pallas_ssm._ssd_pallas_fwd``: the output and
-#: the chunks' incoming states); outside a checkpoint they lower to nothing
+#: output and its row statistics; ``pallas_ssm._ssd_pallas_fwd`` and
+#: ``pallas_gdn._gdn_pallas_fwd``: the output and the chunks' incoming
+#: states); outside a checkpoint they lower to nothing
 KERNEL_OUTPUT_NAMES = {"flash": ("flash_out", "flash_lse"),
-                       "ssd": ("ssd_out", "ssd_state")}
+                       "ssd": ("ssd_out", "ssd_state"),
+                       "gdn": ("gdn_out", "gdn_state")}
 KERNEL_OUTPUTS = tuple(n for names in KERNEL_OUTPUT_NAMES.values()
                        for n in names)
 #: the share of the device's limit that the estimate may fill

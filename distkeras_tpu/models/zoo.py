@@ -281,7 +281,15 @@ def decoder_lm(vocab_size: int, hidden_size: int, num_hidden_layers: int,
                norm_topk_prob: bool = True,
                experts_held: Optional[int] = None, first_expert: int = 0,
                total_ut_steps: int = 1, early_exit_threshold: float = 1.0,
-               sandwich_norm: bool = False,
+               sandwich_norm: bool = False, norm_placement: str = "pre",
+               qk_norm: bool = False,
+               linear_num_key_heads: int = 0,
+               linear_num_value_heads: int = 0,
+               linear_key_head_dim: int = 0,
+               linear_value_head_dim: int = 0,
+               linear_conv_kernel_dim: int = 4,
+               linear_allow_neg_eigval: bool = False,
+               linear_chunk_size: int = 64, linear_impl: str = "chunked",
                attention_impl: str = "dense") -> Model:
     """Decoder-only language model of the current kind, built from the
     per-layer lists a published ``config.json`` gives (the keyword names
@@ -295,8 +303,16 @@ def decoder_lm(vocab_size: int, hidden_size: int, num_hidden_layers: int,
     ``num_attention_heads_per_layer[l]`` query heads of ``head_dim`` over
     ``num_key_value_heads`` K/V heads, rotary as ``rope_parameters[kind]``
     says (``rope_theta``, ``partial_rotary_factor``, and for
-    ``rope_type: "yarn"`` the YaRN keys) and, with ``gating``, a per-head
-    sigmoid gate; ``mlp_layer_types[l]`` is ``"dense"`` (SwiGLU of width
+    ``rope_type: "yarn"`` the YaRN keys; a ``rope_theta`` of None: no
+    positional term at all), with ``qk_norm`` an RMSNorm over all of q's
+    and all of k's columns, and with ``gating`` a per-head sigmoid gate;
+    or ``"linear_attention"``, a Gated DeltaNet mixer
+    (``ops.gated_delta.GatedDeltaNet``: ``linear_num_key_heads`` heads,
+    as many value heads, keys of ``linear_key_head_dim`` and values of
+    ``linear_value_head_dim``, ``linear_conv_kernel_dim`` taps, beta in
+    (0, 2) with ``linear_allow_neg_eigval``, the rule in chunks of
+    ``linear_chunk_size`` by ``linear_impl``); ``mlp_layer_types[l]`` is
+    ``"dense"`` (SwiGLU of width
     ``intermediate_size``) or ``"sparse"`` (``ops.moe.SparseMoE``: top
     ``num_experts_per_tok`` of ``num_experts`` SwiGLU experts of width
     ``moe_intermediate_size``, weights normalised if ``norm_topk_prob``
@@ -310,7 +326,9 @@ def decoder_lm(vocab_size: int, hidden_size: int, num_hidden_layers: int,
 
     ``sandwich_norm``: a second RMSNorm on each sublayer's output, before
     it is added: ``h = x + norm(Attn(norm(x)))``, ``y = h + norm(FF(
-    norm(h)))``.  ``total_ut_steps`` > 1 (the ``ouro`` family's keys): the
+    norm(h)))``.  ``norm_placement="post"`` (the Olmo 2 family's): the
+    sublayer's output alone is normed, ``h = x + norm(Attn(x))``, ``y = h
+    + norm(FF(h))``.  ``total_ut_steps`` > 1 (the ``ouro`` family's keys): the
     layers and the final norm run that many times over ONE set of
     parameters, each pass on the one before's normed output
     (``layers.Looped``), and one head and one exit gate read every pass
@@ -322,26 +340,50 @@ def decoder_lm(vocab_size: int, hidden_size: int, num_hidden_layers: int,
     from ..ops.moe import SparseMoE
     from .layers import ExitHeads, Looped, RMSNorm, SwiGLU
     rope_parameters = rope_parameters or {}
+    if norm_placement not in ("pre", "post"):
+        raise ValueError(f"norm_placement must be 'pre' or 'post', got "
+                         f"{norm_placement!r}")
 
     def block(mixer):
+        if norm_placement == "post":
+            return Residual(Sequential([mixer, RMSNorm(rms_norm_eps)]))
         after = [RMSNorm(rms_norm_eps)] if sandwich_norm else []
         return Residual(Sequential([RMSNorm(rms_norm_eps), mixer, *after]))
+
+    def linear_attention():
+        from ..ops.gated_delta import GatedDeltaNet
+        if linear_num_value_heads != linear_num_key_heads:
+            raise ValueError(
+                f"{linear_num_value_heads} value heads over "
+                f"{linear_num_key_heads} key heads: only equal counts are "
+                f"built")
+        return GatedDeltaNet(
+            linear_num_key_heads, linear_key_head_dim, linear_value_head_dim,
+            conv_kernel=linear_conv_kernel_dim, chunk_size=linear_chunk_size,
+            norm_eps=rms_norm_eps, allow_neg_eigval=linear_allow_neg_eigval,
+            impl=linear_impl)
 
     layers = []
     for kind, heads, mlp in list(zip(layer_types,
                                      num_attention_heads_per_layer,
                                      mlp_layer_types))[:num_hidden_layers]:
-        if kind not in ("full_attention", "sliding_attention"):
+        if kind not in ("full_attention", "sliding_attention",
+                        "linear_attention"):
             raise ValueError(f"unknown layer type {kind!r}")
         rope = dict(rope_parameters.get(kind, {}))
-        attention = MultiHeadAttention(
-            heads, causal=True, impl=attention_impl,
-            num_kv_heads=num_key_value_heads, head_dim=head_dim, rope=True,
-            window=sliding_window if kind == "sliding_attention" else None,
-            rope_theta=rope.get("rope_theta", 10000.0),
-            rope_fraction=rope.get("partial_rotary_factor", 1.0),
-            rope_scaling=rope or None,
-            gate=gating)
+        positions = rope.get("rope_theta", 10000.0) is not None
+        attention = linear_attention() if kind == "linear_attention" \
+            else MultiHeadAttention(
+                heads, causal=True, impl=attention_impl,
+                num_kv_heads=num_key_value_heads, head_dim=head_dim,
+                rope=positions,
+                window=sliding_window if kind == "sliding_attention"
+                else None,
+                rope_theta=rope.get("rope_theta", 10000.0) if positions
+                else 10000.0,
+                rope_fraction=rope.get("partial_rotary_factor", 1.0),
+                rope_scaling=(rope or None) if positions else None,
+                gate=gating, qk_norm=qk_norm, norm_eps=rms_norm_eps)
         if mlp == "dense":
             ff = SwiGLU(intermediate_size)
         elif mlp == "sparse":

@@ -204,7 +204,10 @@ class MultiHeadAttention(Layer):
     that is rotated (the rest passes through), and a YaRN description
     (``rope_frequencies``); ``gate`` — a per-head sigmoid gate computed
     from the layer's input, ``(D, H)`` more parameters, on each head's
-    attention output before the output projection.  Window and gate are
+    attention output before the output projection; ``qk_norm`` — an
+    RMSNorm (``norm_eps``) over all H·Dh columns of q and one over all
+    KV·Dh of k, each with a weight of its width, before the heads are
+    split (the Olmo 2 family's).  Window and gate are
     training-path features: the decode cache does not know them yet.
     """
 
@@ -215,10 +218,12 @@ class MultiHeadAttention(Layer):
                  rope: bool = False, head_dim: Optional[int] = None,
                  window: Optional[int] = None, rope_theta: float = 10000.0,
                  rope_fraction: float = 1.0,
-                 rope_scaling: Optional[dict] = None, gate: bool = False):
+                 rope_scaling: Optional[dict] = None, gate: bool = False,
+                 qk_norm: bool = False, norm_eps: float = 1e-6):
         if impl not in ("dense", "flash"):
             raise ValueError(f"impl must be 'dense' or 'flash', got {impl!r}")
         self.num_heads = int(num_heads)
+        self.qk_norm, self.norm_eps = bool(qk_norm), float(norm_eps)
         self.head_dim = None if head_dim is None else int(head_dim)
         self.window = None if window is None else int(window)
         if self.window is not None and not causal:
@@ -297,6 +302,9 @@ class MultiHeadAttention(Layer):
         if self.gate:
             params["gate"] = glorot_uniform(jax.random.fold_in(rng, 2),
                                             (d, self.num_heads))
+        if self.qk_norm:
+            params["q_norm"] = jnp.ones((wide,))
+            params["k_norm"] = jnp.ones((self._kv * dh,))
         return params, {}, in_shape
 
     def _project(self, params, x):
@@ -308,10 +316,19 @@ class MultiHeadAttention(Layer):
         dh = self._dh(d)
         wide = h * dh
         qkv = x @ params["qkv"].astype(x.dtype)   # (B, T, (H + 2·KV)·Dh)
-        q = qkv[..., :wide].reshape(b, t, h, dh)
-        k = qkv[..., wide:wide + kv * dh].reshape(b, t, kv, dh)
+        norm = self._normed if self.qk_norm else lambda a, _: a
+        q = norm(qkv[..., :wide], params.get("q_norm")).reshape(b, t, h, dh)
+        k = norm(qkv[..., wide:wide + kv * dh], params.get("k_norm")) \
+            .reshape(b, t, kv, dh)
         v = qkv[..., wide + kv * dh:].reshape(b, t, kv, dh)
         return q, k, v
+
+    def _normed(self, x, scale):
+        """RMSNorm over all of ``x``'s columns, statistics in float32."""
+        xf = x.astype(jnp.float32)
+        return (xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                                   + self.norm_eps)
+                * scale.astype(jnp.float32)).astype(x.dtype)
 
     def _rotary_dim(self, dh: int) -> int:
         return int(round(dh * self.rope_fraction))
@@ -491,7 +508,8 @@ class MultiHeadAttention(Layer):
                 "rope": self.rope, "head_dim": self.head_dim,
                 "window": self.window, "rope_theta": self.rope_theta,
                 "rope_fraction": self.rope_fraction,
-                "rope_scaling": self.rope_scaling, "gate": self.gate}
+                "rope_scaling": self.rope_scaling, "gate": self.gate,
+                "qk_norm": self.qk_norm, "norm_eps": self.norm_eps}
 
 
 @register

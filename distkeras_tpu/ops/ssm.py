@@ -138,16 +138,18 @@ def _ssd_chunked(x, b, c, dt, s):
 # the layer
 # ---------------------------------------------------------------------------
 
-def causal_conv(x, kernel, bias):
+def causal_conv(x, kernel, bias=None):
     """Depthwise causal convolution over time: ``out[t] = bias + sum_k
     kernel[k] x[t - (K - 1) + k]`` (zeros before the start), summed in
-    float32.  ``x`` (B, T, C); ``kernel`` (K, C); ``bias`` (C,)."""
+    float32.  ``x`` (B, T, C); ``kernel`` (K, C); ``bias`` (C,) or
+    None."""
     taps, t = kernel.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    out = bias.astype(jnp.float32)
+    out = None if bias is None else bias.astype(jnp.float32)
     for k in range(taps):
-        out = out + kernel[k].astype(jnp.float32) \
+        term = kernel[k].astype(jnp.float32) \
             * padded[:, k:k + t].astype(jnp.float32)
+        out = term if out is None else out + term
     return out
 
 

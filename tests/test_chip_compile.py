@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from distkeras_tpu.ops import pallas_attention, pallas_moe, pallas_ssm, ssm
+from distkeras_tpu.ops import (gated_delta, pallas_attention, pallas_gdn,
+                               pallas_moe, pallas_ssm, ssm)
 
 
 @pytest.fixture(scope="module")
@@ -55,6 +56,7 @@ def mosaic(monkeypatch):
     monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
     monkeypatch.setattr(pallas_moe, "_interpret", lambda: False)
     monkeypatch.setattr(pallas_ssm, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_gdn, "_interpret", lambda: False)
 
 
 def _compile(fn, *shapes):
@@ -245,6 +247,31 @@ def test_scan_kernels_compile(one_chip, mosaic, dtype, batch, mode):
     # the instructions' names are what a trace's rows read
     assert "%ssd_chunk_fwd" in text
     assert ("%ssd_chunk_bwd" in text) == (mode != "fwd")
+
+
+@pytest.mark.parametrize("dtype,batch,mode", [
+    ("bfloat16", 1, "fwd_bwd"), ("float32", 2, "fwd")],
+    ids=["train-bf16", "check-f32"])
+def test_delta_rule_kernels_compile(one_chip, mosaic, dtype, batch, mode):
+    """The gated delta rule at the Olmo Hybrid cell's shape: 30 heads,
+    keys of 96 and values of 192 (neither a multiple of 128 lanes), 128
+    chunks of 64 a row of 8,192."""
+    def shape(*dims, dt=dtype):
+        return jax.ShapeDtypeStruct(dims, jnp.dtype(dt), sharding=one_chip)
+
+    def rule(*args):
+        return gated_delta.gated_delta_rule(*args, chunk=64, impl="pallas")
+
+    fn = rule if mode == "fwd" else jax.grad(
+        lambda *args: jnp.sum(rule(*args).astype(jnp.float32) ** 2),
+        argnums=(0, 1, 2, 3, 4))
+    text = _compile(fn, shape(batch, 8192, 30, 96), shape(batch, 8192, 30, 96),
+                    shape(batch, 8192, 30, 192),
+                    shape(batch, 8192, 30, dt="float32"),
+                    shape(batch, 8192, 30, dt="float32"))
+    # the instructions' names are what a trace's rows read
+    assert "%gdn_chunk_fwd" in text
+    assert ("%gdn_chunk_bwd" in text) == (mode != "fwd")
 
 
 def test_flash_lse_rectangular_hop_compiles(one_chip, mosaic):

@@ -173,7 +173,7 @@ def test_a_bf16_trainer_keeps_the_scan_kernels_outputs_and_learns():
     mixer runs no forward kernel again; the loss falls."""
     from distkeras_tpu.models.remat import KERNEL_OUTPUTS
     assert KERNEL_OUTPUTS == ("flash_out", "flash_lse", "ssd_out",
-                              "ssd_state")
+                              "ssd_state", "gdn_out", "gdn_state")
     registry = default_registry()
     chunks = registry.counter("ssm.chunks")
     before = chunks.value
